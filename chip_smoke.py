@@ -1,0 +1,427 @@
+#!/usr/bin/env python3
+"""One ceremony and one served request on the chip: the quickest proof
+that the system still starts there.
+
+Drives the main path through the entry points a user calls, in ONE
+process, on whatever accelerator JAX finds — and fails (non-zero, no
+result line) when that is not a TPU.  No CPU path, no caught phase.
+
+    python chip_smoke.py            # one chip: phases `ceremony`, `served`
+    python chip_smoke.py --mesh     # four chips: the sharded ceremony only
+
+* ``ceremony`` — ``BatchedCeremony("secp256k1", n=1024, t=341)`` from a
+  fixed seed (BASELINE.json config 3), run cold then warm; every batch
+  check passes, no complaints, and the master key equals the host
+  oracle (sum of the seeded constant coefficients times the generator,
+  big-int arithmetic in groups/host.py) bit for bit.
+* ``served`` — an in-process ``CeremonyScheduler`` over one
+  ``WarmRuntime`` (examples/serve.py's shape, one worker): three seeded
+  requests submitted, polled, fetched; each master compared with
+  ``engine.run_single_reference`` and the host oracle; then
+  ``service.sign`` (proved, the default) over four messages against
+  ``secret * H(m)``.  The requests use the ceremony's own (n, t): a
+  first call of any new shape costs minutes of tracing and compiling
+  (PR 22: 269 s for n=256 t=85), and the whole script must fit 1200 s
+  cold — at the ceremony's shape the served leg adds only the sign
+  programs.
+* ``--mesh`` — ``run_sharded_ceremony`` on a 4-device mesh against the
+  same seeded ``BatchedCeremony`` on device 0: master key, final shares
+  and qualified set bit for bit; fails unless every sharded input
+  really spans four devices.
+
+Each phase prints one JSON line; the LAST line is
+``{"ok": true, "device": {...}}`` and nothing else.  ``--rehearse`` is
+for the sandbox (forces the CPU backend, tiny ``--n/--t``, Pallas in
+interpret mode where forced on) and never prints that line.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import pathlib
+import random
+import sys
+import time
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
+
+CURVE = "secp256k1"
+SHARED = b"chip_smoke"
+
+
+_T0 = time.perf_counter()
+
+
+def _emit(obj: dict) -> None:
+    print(json.dumps(obj, sort_keys=True), flush=True)
+
+
+def _require(cond, msg: str) -> None:
+    """A failed check fails the script (an ``assert`` would vanish under -O)."""
+    if not cond:
+        raise RuntimeError(f"chip_smoke: {msg}")
+
+
+def _note(msg: str) -> None:
+    """Progress on stderr, so a run cut by its time limit shows where."""
+    print(f"[chip_smoke +{time.perf_counter() - _T0:7.1f}s] {msg}", file=sys.stderr, flush=True)
+
+
+def _compile_delta(before: dict, after: dict) -> dict:
+    """What the JAX runtime traced, lowered and compiled between two
+    runtimeobs snapshots (seconds per stage; ``backend_compile`` wraps
+    the persistent-cache lookup, so it is small on a hit; ``trace``
+    events nest, so their sum over-counts and can exceed the wall)."""
+    stages = {
+        k: round(v["sum_s"] - before["stages"].get(k, {"sum_s": 0.0})["sum_s"], 3)
+        for k, v in after["stages"].items()
+    }
+    return {
+        "compiles": after["compiles_total"] - before["compiles_total"],
+        "stages_s": stages,
+        "cache_hits": after["cache_hits"] - before["cache_hits"],
+        "cache_misses": after["cache_misses"] - before["cache_misses"],
+    }
+
+
+def _seeded_secret(fs, n: int, t: int, seed: int) -> int:
+    """Sum of the dealers' constant coefficients, re-drawn from the seed
+    in BatchedCeremony's order (all of a, row by row) — independent of
+    anything the device computed."""
+    rng = random.Random(seed)
+    total = 0
+    for _ in range(n):
+        row = [fs.rand_int(rng) for _ in range(t + 1)]
+        total += row[0]
+    return total % fs.modulus
+
+
+def _host_pubkey(curve: str, secret: int) -> bytes:
+    from dkg_tpu.groups import host as gh
+
+    group = gh.ALL_GROUPS[curve]
+    return group.encode(group.scalar_mul_vartime(secret, group.generator()))
+
+
+def _encode_point(cs, pt) -> bytes:
+    import numpy as np
+
+    from dkg_tpu.groups import device as gd
+
+    return gd.encode_batch(cs, np.asarray(pt)[None])[0].tobytes()
+
+
+def _path_facts(cs, table) -> dict:
+    """Which formulations the traced programs resolved to."""
+    from dkg_tpu import native
+    from dkg_tpu.fields import device as fd
+    from dkg_tpu.groups import device as gd
+    from dkg_tpu.ops import pallas_field as pf
+
+    fused, interpret = bool(fd.fused_kernels_active()), not fd._on_tpu()
+    return {
+        "fused_kernels_active": fused,
+        "fused_multi_active": bool(gd.fused_multi_active(cs)),
+        "pallas_interpret": interpret,
+        "kernel_mul_core": pf.rows_mul_dispatch(cs.field, interpret) if fused else None,
+        "xla_mul": fd.mul_dispatch_mode(cs.field),
+        "table_window_bits": int(math.log2(table.shape[1])),
+        "native_library": bool(native.available()),
+    }
+
+
+def phase_ceremony(args, dev) -> None:
+    import jax
+    import numpy as np
+
+    from dkg_tpu.dkg import ceremony as ce
+    from dkg_tpu.utils import runtimeobs
+    from dkg_tpu.utils.tracing import CeremonyTrace
+
+    n, t = args.n, args.t
+    snap0 = runtimeobs.snapshot()
+    t0 = time.perf_counter()
+    cer = ce.BatchedCeremony(CURVE, n, t, SHARED, random.Random(args.seed))
+    jax.block_until_ready((cer.g_table, cer.h_table, cer.coeffs_a, cer.coeffs_b))
+    setup_s = time.perf_counter() - t0
+    snap1 = runtimeobs.snapshot()
+    _note(f"ceremony set-up done: tables {cer.table_seconds:.1f}s, window table {cer.g_table.shape}")
+
+    first, warm = CeremonyTrace(), CeremonyTrace()
+    out = cer.run(trace=first)
+    snap2 = runtimeobs.snapshot()
+    _note(f"ceremony first call done: {first.timings_s}")
+    out_warm = cer.run(trace=warm)
+    snap3 = runtimeobs.snapshot()
+    _note(f"ceremony warm call done: {warm.timings_s}")
+
+    cs = cer.cfg.cs
+    want = _host_pubkey(CURVE, _seeded_secret(cs.scalar, n, t, args.seed))
+    got, got_warm = _encode_point(cs, out["master"]), _encode_point(cs, out_warm["master"])
+    stats = dev.memory_stats() or {}
+    phases = ("deal", "fiat_shamir", "verify", "finalise")
+    _emit(
+        {
+            "phase": "ceremony",
+            "curve": CURVE,
+            "n": n,
+            "t": t,
+            "setup_s": round(setup_s, 3),
+            "tables_s": round(cer.table_seconds, 3),
+            "table_cache": cer.table_stats,
+            "first_call_s": {p: round(first.timings_s.get(p, 0.0), 3) for p in phases},
+            "warm_s": {p: round(warm.timings_s.get(p, 0.0), 3) for p in phases},
+            "compile_setup": _compile_delta(snap0, snap1),
+            "compile_first_call": _compile_delta(snap1, snap2),
+            "compile_warm_call": _compile_delta(snap2, snap3),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+            "all_ok": bool(np.asarray(out["ok"]).all()),
+            "complaints": len(out["complaints"]),
+            "master": got.hex(),
+            "master_matches_host_oracle": got == want,
+            **_path_facts(cs, cer.g_table),
+        }
+    )
+    _require(
+        bool(np.asarray(out["ok"]).all()) and bool(np.asarray(out_warm["ok"]).all()),
+        "a recipient's batch check failed",
+    )
+    _require(out["complaints"] == [] and out_warm["complaints"] == [], "complaints were raised")
+    _require(got == want and got_warm == want, "master key differs from the host oracle")
+
+
+def phase_served(args, dev) -> None:
+    from dkg_tpu.groups import device as gd
+    from dkg_tpu.groups import host as gh
+    from dkg_tpu.service import CeremonyRequest, CeremonyScheduler, WarmRuntime, engine
+    from dkg_tpu.sign.hash2curve import hash_to_curve_host
+    from dkg_tpu.utils import runtimeobs
+
+    n, t = args.served_n or args.n, args.served_t or args.t
+    group = gh.ALL_GROUPS[CURVE]
+    reqs = [
+        CeremonyRequest(CURVE, n, t, shared_string=SHARED, seed=args.seed + 1 + i)
+        for i in range(args.served_requests)
+    ]
+    msgs = [b"chip_smoke message %d" % i for i in range(4)]
+    snap0 = runtimeobs.snapshot()
+    t0 = time.perf_counter()
+    lifecycle: list[str] = []
+    request_s = []
+    with CeremonyScheduler(
+        concurrency=1, queue_depth=8, batch_max=1, runtime=WarmRuntime()
+    ) as service:
+        cids = [service.submit(r) for r in reqs]
+        while (status := service.poll(cids[0])) not in ("done", "failed", "expired", "poisoned"):
+            if not lifecycle or lifecycle[-1] != status:
+                lifecycle.append(status)
+            time.sleep(0.05)
+        lifecycle.append(status)
+        outs = []
+        for cid in cids:
+            outs.append(service.result(cid, timeout=900))
+            request_s.append(round(time.perf_counter() - t0, 3))
+            _note(f"served {cid}: {outs[-1].status} after {request_s[-1]}s")
+        snap1 = runtimeobs.snapshot()
+        t1 = time.perf_counter()
+        sigs = service.sign(cids[0], msgs)
+        sign_s = time.perf_counter() - t1
+    snap2 = runtimeobs.snapshot()
+    _note(f"signed {len(sigs)} messages in {sign_s:.1f}s")
+
+    t2 = time.perf_counter()
+    refs = [engine.run_single_reference(r) for r in reqs]
+    reference_s = time.perf_counter() - t2
+    snap3 = runtimeobs.snapshot()
+    _note(f"{len(refs)} reference ceremonies in {reference_s:.1f}s")
+
+    fs = gd.ALL_CURVES[CURVE].scalar
+    secrets = [_seeded_secret(fs, n, t, r.seed) for r in reqs]
+    masters_ref = [o.master == ref for o, ref in zip(outs, refs)]
+    masters_host = [o.master == _host_pubkey(CURVE, s) for o, s in zip(outs, secrets)]
+    want_sigs = [
+        group.encode(group.scalar_mul_vartime(secrets[0], hash_to_curve_host(group, m)))
+        for m in msgs
+    ]
+    stats = dev.memory_stats() or {}
+    _emit(
+        {
+            "phase": "served",
+            "curve": CURVE,
+            "n": n,
+            "t": t,
+            "requests": len(reqs),
+            "lifecycle": lifecycle,
+            "statuses": [o.status for o in outs],
+            "fetched_after_s": request_s,
+            "engine_s": [round(o.seconds, 3) for o in outs],
+            "qualified": [int(sum(o.qualified)) for o in outs],
+            "sign_s": round(sign_s, 3),
+            "signatures": len(sigs),
+            "reference_s": round(reference_s, 3),
+            "compile_requests": _compile_delta(snap0, snap1),
+            "compile_sign": _compile_delta(snap1, snap2),
+            "compile_reference": _compile_delta(snap2, snap3),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+            "masters_match_reference": masters_ref,
+            "masters_match_host_oracle": masters_host,
+            "signatures_match_host_oracle": [g == w for g, w in zip(sigs, want_sigs)],
+        }
+    )
+    _require(all(o.status == "done" for o in outs), f"served request failed: {[o.error for o in outs]}")
+    _require(
+        all(sum(o.qualified) == n and not o.complaints for o in outs),
+        "a served ceremony disqualified a dealer",
+    )
+    _require(all(masters_ref) and all(masters_host), "served master differs from its reference")
+    _require(len(sigs) == len(msgs) and sigs == want_sigs, "signature differs from secret*H(m)")
+
+
+def phase_mesh(args, dev) -> None:
+    import jax
+    import numpy as np
+
+    from dkg_tpu.dkg import ceremony as ce
+    from dkg_tpu.parallel import mesh as pm
+    from dkg_tpu.utils import runtimeobs
+
+    n, t = args.n, args.t
+    _require(jax.device_count() == 4, f"--mesh needs 4 devices, found {jax.device_count()}")
+    mesh = pm.make_mesh(4)
+    cer = ce.BatchedCeremony(CURVE, n, t, SHARED, random.Random(args.seed))
+    cs = cer.cfg.cs
+
+    # place the inputs the way run_sharded_ceremony does and look at
+    # where they really landed: code that has only seen one chip may
+    # put everything on the first
+    placed = {
+        "coeffs_a": pm.place_sharded(mesh, cer.coeffs_a),
+        "coeffs_b": pm.place_sharded(mesh, cer.coeffs_b),
+        "g_table": pm.place_sharded(mesh, cer.g_table, pm.P()),
+        "h_table": pm.place_sharded(mesh, cer.h_table, pm.P()),
+    }
+    shard_devices = {
+        k: sorted(sh.device.id for sh in v.addressable_shards) for k, v in placed.items()
+    }
+    shard_rows = {k: [sh.data.shape[0] for sh in v.addressable_shards] for k, v in placed.items()}
+
+    _note(f"inputs placed: {shard_devices}")
+
+    def sharded() -> tuple[dict, float]:
+        t0 = time.perf_counter()
+        res = pm.run_sharded_ceremony(cer.cfg, mesh, *placed.values(), ceremony_id="chip_smoke")
+        jax.block_until_ready((res["master"], res["final_shares"]))
+        return res, time.perf_counter() - t0
+
+    snap0 = runtimeobs.snapshot()
+    res, first_s = sharded()
+    snap1 = runtimeobs.snapshot()
+    _note(f"sharded first call done in {first_s:.1f}s: {res['phases_s']}")
+    res, warm_s = sharded()
+    warm_phases = {k: round(v, 3) for k, v in res["phases_s"].items()}
+    snap2 = runtimeobs.snapshot()
+    want = _host_pubkey(CURVE, _seeded_secret(cs.scalar, n, t, args.seed))
+    _note(
+        f"sharded warm call done in {warm_s:.1f}s: {warm_phases}; master == host oracle: "
+        f"{_encode_point(cs, res['master']) == want}"
+    )
+
+    t0 = time.perf_counter()
+    ref = cer.run()
+    jax.block_until_ready(ref["master"])
+    reference_s = time.perf_counter() - t0
+
+    same_master = np.array_equal(np.asarray(ref["master"]), np.asarray(res["master"]))
+    same_shares = np.array_equal(np.asarray(ref["final_shares"]), np.asarray(res["final_shares"]))
+    same_qual = np.array_equal(np.asarray(ref["qualified"]), np.asarray(res["qualified"]))
+    out_devices = sorted(sh.device.id for sh in res["final_shares"].addressable_shards)
+    _emit(
+        {
+            "phase": "mesh",
+            "curve": CURVE,
+            "n": n,
+            "t": t,
+            "mesh_devices": [d.id for d in mesh.devices.flat],
+            "input_shard_device_ids": shard_devices,
+            "input_shard_rows": shard_rows,
+            "final_shares_device_ids": out_devices,
+            "first_call_s": round(first_s, 3),
+            "warm_s": round(warm_s, 3),
+            "warm_phases_s": warm_phases,
+            "single_device_reference_s": round(reference_s, 3),
+            "compile_first_call": _compile_delta(snap0, snap1),
+            "compile_warm_call": _compile_delta(snap1, snap2),
+            "peak_bytes_in_use": [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in jax.devices()],
+            "all_ok": bool(np.asarray(res["ok"]).all()),
+            "master_matches_single_device": bool(same_master),
+            "final_shares_match_single_device": bool(same_shares),
+            "qualified_matches_single_device": bool(same_qual),
+            "master_matches_host_oracle": _encode_point(cs, res["master"]) == want,
+            **_path_facts(cs, cer.g_table),
+        }
+    )
+    for k, ids in shard_devices.items():
+        _require(len(set(ids)) == 4, f"{k} is on devices {ids}, not spread over four")
+    _require(
+        all(r == n // 4 for r in shard_rows["coeffs_a"] + shard_rows["coeffs_b"]),
+        f"coefficients are not split in four equal blocks: {shard_rows}",
+    )
+    _require(len(set(out_devices)) == 4, f"final shares came back on devices {out_devices}")
+    _require(
+        bool(np.asarray(res["ok"]).all()) and bool(np.asarray(ref["ok"]).all()),
+        "a recipient's batch check failed",
+    )
+    _require(same_master and same_shares and same_qual, "sharded ceremony differs from device 0's")
+    _require(_encode_point(cs, res["master"]) == want, "master key differs from the host oracle")
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--n", type=int, default=1024, help="ceremony committee size")
+    ap.add_argument("--t", type=int, default=341, help="ceremony threshold")
+    ap.add_argument("--served-n", type=int, default=None, help="default: --n")
+    ap.add_argument("--served-t", type=int, default=None, help="default: --t")
+    ap.add_argument("--served-requests", type=int, default=3)
+    ap.add_argument("--seed", type=int, default=22)
+    ap.add_argument("--mesh", action="store_true", help="four chips: the sharded ceremony only")
+    ap.add_argument(
+        "--rehearse", action="store_true",
+        help="sandbox rehearsal on the CPU backend; never prints the ok line",
+    )
+    args = ap.parse_args()
+
+    from dkg_tpu.utils import compilecache, runtimeobs
+
+    if args.rehearse:
+        from dkg_tpu.parallel.hostmesh import force_cpu_mesh
+
+        force_cpu_mesh(4 if args.mesh else 1)
+    cache_dir = compilecache.enable()
+
+    import jax
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices())}
+    if not args.rehearse and dev.platform != "tpu":
+        raise SystemExit(f"chip_smoke: no TPU, JAX found {device}")
+    runtimeobs.install(force=True)
+    _emit({"phase": "start", "device": device, "jax": jax.__version__, "compile_cache": cache_dir})
+
+    t0 = time.perf_counter()
+    if args.mesh:
+        phase_mesh(args, dev)
+    else:
+        phase_ceremony(args, dev)
+        phase_served(args, dev)
+    _emit({"phase": "end", "total_s": round(time.perf_counter() - t0, 3)})
+    if args.rehearse:
+        _emit({"rehearsal": True, "device": device})
+    else:
+        print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

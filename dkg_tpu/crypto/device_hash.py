@@ -2,7 +2,7 @@
 
 Why: Fiat-Shamir batch randomizers must bind the COMPLETE round-1
 transcript (commitments + share matrices).  Hashing on host means
-shipping the full tensors over PCIe/tunnel — ~2.1 GB at n=4096 — so the
+shipping the full tensors over PCIe — ~2.1 GB at n=4096 — so the
 digest is computed where the data lives and only 32 bytes cross to the
 host.  This is the device-side reduction the protocol layer
 (dkg.ceremony.transcript_digest) uses on its hot path; the byte-level

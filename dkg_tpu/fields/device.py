@@ -66,10 +66,9 @@ def _on_tpu() -> bool:
         return env == "tpu"
     global _backend_cache
     if _backend_cache is None:
-        try:
-            _backend_cache = jax.default_backend()
-        except Exception:  # pragma: no cover — backend init failure
-            return False
+        # a backend that fails to come up raises here: silently taking
+        # the CPU formulations would hide a dead chip
+        _backend_cache = jax.default_backend()
     return _backend_cache == "tpu"
 
 

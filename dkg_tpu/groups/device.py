@@ -1233,14 +1233,15 @@ def _msm_pippenger_core(
     once (the m axis is the only sequential dimension that grows with
     the problem):
 
-    1. scatter — the Pallas bucket-accumulate kernel when the fused
-       tier is active (ops/pallas_mxu.bucket_accumulate: buckets stay
-       VMEM-resident, indexed read-modify-write per point, no
-       materialized one-hot); otherwise the XLA scan leg
-       (:func:`_bucket_scan`).  Both produce bit-identical bucket
-       tensors — same add order through the same complete formulas.
-       Digit-0 contributions land in bucket 0, which the reduction
-       ignores (identity-safe).
+    1. scatter — the XLA scan leg (:func:`_bucket_scan`) on every
+       backend.  Digit-0 contributions land in bucket 0, which the
+       reduction ignores (identity-safe).  The Pallas twin
+       (ops/pallas_mxu.bucket_accumulate, buckets VMEM-resident) is
+       bit-identical in interpret mode and compiles for the v5e, but on
+       the chip its bucket tensor differed from this leg's and four
+       served signatures came out wrong (PR 22) — it is off the
+       dispatch until tests/test_pallas_mxu.py's on-chip parity case
+       passes (ROADMAP S3).
     2. bucket close — descending suffix-sum scan over the 2**c - 1
        non-zero buckets: run += B_b; tot += run computes
        Σ_b b·B_b in 2 adds per bucket, for every window in parallel.
@@ -1254,13 +1255,7 @@ def _msm_pippenger_core(
     nw = min(_n_windows(cs, window), -(-nbits // window))
     digits = scalar_windows(cs, scalars, window)[..., :nw]  # (..., m, nw)
 
-    buckets = None
-    if fused_kernels_active():
-        from ..ops import pallas_mxu
-
-        buckets = pallas_mxu.bucket_accumulate(cs, points, digits, window, nw)
-    if buckets is None:  # fused tier off, or Pallas unavailable
-        buckets = _bucket_scan(cs, points, digits, entries)
+    buckets = _bucket_scan(cs, points, digits, entries)
 
     # descending suffix sums over buckets [entries-1 .. 1]
     nonzero = jnp.moveaxis(buckets[..., 1:, :, :], -3, 0)[::-1]

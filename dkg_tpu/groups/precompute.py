@@ -102,15 +102,14 @@ def reset(clear_disk: bool = False) -> None:
 def cache_dir() -> pathlib.Path:
     """Where table files live: ``DKG_TPU_TABLE_CACHE`` if set, else a
     ``dkg_tpu_fb_tables/`` directory alongside the JAX compilation
-    cache (same lifecycle: wiping one should wipe both), falling back
-    to the system temp dir when no compilation cache is configured."""
-    from ..utils import envknobs
+    cache (utils.compilecache — same lifecycle: wiping one wipes
+    both)."""
+    from ..utils import compilecache, envknobs
 
     env = envknobs.string("DKG_TPU_TABLE_CACHE", "fixed-base table cache directory")
     if env is not None:
         return pathlib.Path(env)
-    base = jax.config.jax_compilation_cache_dir or tempfile.gettempdir()
-    return pathlib.Path(base) / "dkg_tpu_fb_tables"
+    return pathlib.Path(compilecache.cache_root()) / "dkg_tpu_fb_tables"
 
 
 def _table_path(cs: gd.CurveSpec, key: tuple, window: int) -> pathlib.Path:

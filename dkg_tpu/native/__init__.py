@@ -13,6 +13,7 @@ framework runs unchanged on hosts without a toolchain.
 from __future__ import annotations
 
 import ctypes
+import os
 import pathlib
 import subprocess
 from typing import Optional
@@ -46,14 +47,19 @@ class WsCtxStruct(ctypes.Structure):
 
 def _build() -> bool:
     _LIB.parent.mkdir(parents=True, exist_ok=True)
+    # build beside the target and rename: another process (an xdist
+    # worker) must never find a half-written library at _LIB
+    tmp = _LIB.with_name(f"{_LIB.name}.{os.getpid()}.tmp")
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-        str(_SRC), "-o", str(_LIB),
+        str(_SRC), "-o", str(tmp),
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _LIB)
         return True
     except Exception:
+        tmp.unlink(missing_ok=True)
         return False
 
 

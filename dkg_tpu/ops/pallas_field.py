@@ -33,19 +33,13 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..fields.spec import FieldSpec
 from ..utils import metrics
 
 BLOCK = 128  # lane width: one VPU register row of batch elements
-
-try:  # pallas import is deferred-safe: CPU-only environments still work
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
 
 
 def _mul_columns(rows_a, rows_b):
@@ -165,7 +159,7 @@ def mxu_operands(fs: FieldSpec, interpret: bool = False):
     enable the MXU multiply core for ``fs`` — both empty when
     :func:`rows_mul_dispatch` selects the Barrett core, so call sites
     can splat them unconditionally."""
-    if not HAVE_PALLAS or rows_mul_dispatch(fs, interpret) != "mxu":
+    if rows_mul_dispatch(fs, interpret) != "mxu":
         return [], []
     from .pallas_mxu import mxu_const_arrays
 
@@ -327,10 +321,6 @@ def mod_mul(fs: FieldSpec, a: jax.Array, b: jax.Array, *, interpret: bool | None
     batch is flattened, padded to a BLOCK multiple, and mapped onto the
     lane axis.  Drop-in parity with ``fields.device.mul``.
     """
-    if not HAVE_PALLAS:  # pragma: no cover
-        from ..fields import device as fd
-
-        return fd.mul(fs, a, b)
     metrics.REGISTRY.inc("pallas_calls_total", kernel="mod_mul")
     a = jnp.asarray(a, jnp.uint32)
     b = jnp.asarray(b, jnp.uint32)
@@ -366,10 +356,6 @@ def mod_madd(
     loop (reference: src/dkg/committee.rs:163-186 ->
     src/polynomial.rs:68-74) collapsed to one launch per coefficient.
     """
-    if not HAVE_PALLAS:  # pragma: no cover
-        from ..fields import device as fd
-
-        return fd.add(fs, fd.mul(fs, a, b), c)
     metrics.REGISTRY.inc("pallas_calls_total", kernel="mod_madd")
     a, b, c = jnp.broadcast_arrays(
         jnp.asarray(a, jnp.uint32), jnp.asarray(b, jnp.uint32), jnp.asarray(c, jnp.uint32)

@@ -23,7 +23,10 @@ float32's 2**24 integer range:
   matmul over the bucket lanes, added through the complete formulas
   (ops/pallas_point.py row cores), and written back with a branchless
   lane select.  The XLA leg's per-point ``(…, nw, entries)`` one-hot
-  and whole-tensor ``jnp.where`` never materialize in HBM.
+  and whole-tensor ``jnp.where`` never materialize in HBM.  NOT
+  dispatched: it matches the XLA leg in interpret mode and compiles for
+  the v5e, but differed from it on the chip (PR 22, ROADMAP S3) —
+  reachable only directly, from its tests.
 
 Layout contract matches ops/pallas_field.py: limbs on the sublane axis,
 batch on the lane axis; all field/curve constants are baked Python-int
@@ -40,18 +43,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..fields.spec import FieldSpec
 from ..utils import metrics
 from .pallas_field import BLOCK, _cond_sub, _mul_columns, _normalize
-
-try:  # pallas import is deferred-safe: CPU-only environments still work
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
 
 #: lane width of the second-level quotient-table one-hot (one VPU row)
 _QL = 128
@@ -63,6 +60,21 @@ _BUCKET_UNROLL_MAX = 64
 
 def _mask16(x):
     return x & jnp.uint32(0xFFFF)
+
+
+# Mosaic has no direct uint32 <-> float32 cast; every value crossing
+# these is proved < 2**24 (spec._build_mulred), so the int32 hop is
+# exact.  One-hots are built with a select, not a bool cast.
+def _u2f(x):
+    return x.astype(jnp.int32).astype(jnp.float32)
+
+
+def _f2u(x):
+    return x.astype(jnp.int32).astype(jnp.uint32)
+
+
+def _onehot(cond):
+    return jnp.where(cond, jnp.float32(1), jnp.float32(0))
 
 
 @functools.lru_cache(maxsize=None)
@@ -124,9 +136,9 @@ def mxu_mul_rows(fs: FieldSpec, rows_a, rows_b, foldm_t=None, q2=None):
         + [r >> 16 for r in phi]
         + [plo[L - 1] >> 16]
     )
-    digits = jnp.concatenate(digit_rows, axis=0).astype(jnp.float32)  # (3L+1, W)
+    digits = _u2f(jnp.concatenate(digit_rows, axis=0))  # (3L+1, W)
     cols8 = jnp.dot(foldm_t, digits, preferred_element_type=jnp.float32)
-    cols8 = cols8.astype(jnp.uint32)  # (2L, W), entries < 2**24
+    cols8 = _f2u(cols8)  # (2L, W), entries < 2**24
     new_cols = []
     for j in range(L):
         keep = plo[j] if j < L - 1 else _mask16(plo[L - 1])
@@ -148,14 +160,11 @@ def mxu_mul_rows(fs: FieldSpec, rows_a, rows_b, foldm_t=None, q2=None):
     u = (v[L - 1] >> mr.shift_e) | (v[L] << (16 - mr.shift_e))  # <= u_max < 2**13
     qh = q2.shape[1]
     w = u.shape[-1]
-    oh_hi = (
-        jax.lax.broadcasted_iota(jnp.uint32, (qh, w), 0) == (u >> 7)
-    ).astype(jnp.float32)
+    ui = u.astype(jnp.int32)
+    oh_hi = _onehot(jax.lax.broadcasted_iota(jnp.int32, (qh, w), 0) == (ui >> 7))
     tmp = jnp.dot(q2, oh_hi, preferred_element_type=jnp.float32)
-    oh_lo = (
-        jax.lax.broadcasted_iota(jnp.uint32, (_QL, w), 0) == (u & jnp.uint32(127))
-    ).astype(jnp.float32)
-    q = jnp.sum(tmp * oh_lo, axis=0, keepdims=True).astype(jnp.uint32)  # (1, W)
+    oh_lo = _onehot(jax.lax.broadcasted_iota(jnp.int32, (_QL, w), 0) == (ui & 127))
+    q = _f2u(jnp.sum(tmp * oh_lo, axis=0, keepdims=True))  # (1, W)
     npl = [int(x) for x in mr.np_limbs]
     w_cols = [v[j] + q * jnp.uint32(npl[j]) for j in range(L + 1)]
     out = _cond_sub(_normalize(w_cols), [int(x) for x in fs.p_limbs_ext])
@@ -213,13 +222,8 @@ def mxu_mod_mul(
     """Batched (a * b) mod p in ONE fused MXU kernel launch.
 
     a, b: (..., L) uint32 limb arrays (the framework-wide layout);
-    drop-in parity with ``fields.device.mul``.  Falls back to the XLA
-    twin of the same formulation when Pallas is unavailable.
+    drop-in parity with ``fields.device.mul``.
     """
-    if not HAVE_PALLAS:  # pragma: no cover
-        from ..fields import device as fd
-
-        return fd._mul_gemm(fs, a, b)
     metrics.REGISTRY.inc("pallas_calls_total", kernel="mxu_mod_mul")
     a = jnp.asarray(a, jnp.uint32)
     b = jnp.asarray(b, jnp.uint32)
@@ -256,7 +260,7 @@ def _bucket_call(cs, pts_t, digs_t, window: int, nw: int, interpret: bool):
     L, C = cs.field.limbs, cs.ncoords
     entries = 1 << window
     lanes = nw * entries
-    m_pad = pts_t.shape[-1]
+    m_pad = pts_t.shape[1]
     extra, extra_specs = pf.mxu_operands(cs.field, interpret)
 
     def kernel(pts_ref, digs_ref, *rest):
@@ -264,18 +268,18 @@ def _bucket_call(cs, pts_t, digs_t, window: int, nw: int, interpret: bool):
         # one-hot layout constants from iota (Pallas kernels cannot
         # capture array constants): lane q holds bucket q % entries of
         # window q >> window_bits
-        expand = (
-            jax.lax.broadcasted_iota(jnp.uint32, (nw, lanes), 0)
-            == (jax.lax.broadcasted_iota(jnp.uint32, (nw, lanes), 1) >> window)
-        ).astype(jnp.float32)
-        gather = (
-            (jax.lax.broadcasted_iota(jnp.uint32, (lanes, nw), 0) >> window)
-            == jax.lax.broadcasted_iota(jnp.uint32, (lanes, nw), 1)
-        ).astype(jnp.float32)
+        lane_win = jax.lax.broadcasted_iota(jnp.int32, (nw, lanes), 1) >> window
+        expand = _onehot(jax.lax.broadcasted_iota(jnp.int32, (nw, lanes), 0) == lane_win)
+        gather = _onehot(
+            (jax.lax.broadcasted_iota(jnp.int32, (lanes, nw), 0) >> window)
+            == jax.lax.broadcasted_iota(jnp.int32, (lanes, nw), 1)
+        )
         eid = (
-            jax.lax.broadcasted_iota(jnp.uint32, (1, lanes), 1)
-            & jnp.uint32(entries - 1)
+            jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1) & (entries - 1)
         ).astype(jnp.float32)
+        eye = jax.lax.broadcasted_iota(
+            jnp.int32, (C * L, C * L), 0
+        ) == jax.lax.broadcasted_iota(jnp.int32, (C * L, C * L), 1)
         ident = _identity_rows(cs, jnp.zeros((1, lanes), jnp.uint32))
         for c in range(C):
             for i in range(L):
@@ -283,12 +287,15 @@ def _bucket_call(cs, pts_t, digs_t, window: int, nw: int, interpret: bool):
 
         def body(mm, carry):
             bt = out_ref[0]  # (C·L, lanes) uint32, limbs < 2**16
-            if isinstance(mm, int):
-                dig = digs_ref[0, mm : mm + 1, :]
-                ptcol = pts_ref[0, :, mm : mm + 1]
-            else:
-                dig = digs_ref[0, pl.dslice(mm, 1), :]
-                ptcol = pts_ref[0, :, pl.dslice(mm, 1)]
+            row = slice(mm, mm + 1) if isinstance(mm, int) else pl.dslice(mm, 1)
+            dig = digs_ref[0, row, :]
+            # point mm is a (1, C·L) sublane row (a dynamic width-1 LANE
+            # slice is not provably 128-aligned for Mosaic); turn it into
+            # a column through the diagonal of its broadcast
+            ptrow = jnp.broadcast_to(_u2f(pts_ref[0, row, :]), (C * L, C * L))
+            ptcol = _f2u(
+                jnp.sum(jnp.where(eye, ptrow, jnp.float32(0)), axis=1, keepdims=True)
+            )
             # dig_exp[0, q] = digit of window q//entries — exact f32
             dig_exp = jnp.dot(
                 dig.astype(jnp.float32), expand, preferred_element_type=jnp.float32
@@ -296,11 +303,13 @@ def _bucket_call(cs, pts_t, digs_t, window: int, nw: int, interpret: bool):
             mask = eid == dig_exp  # (1, lanes): this point's bucket per window
             # gather the selected bucket per window: exactly one nonzero
             # per (row, window), limb values < 2**16 — exact f32 matmul
-            cur = jnp.dot(
-                bt.astype(jnp.float32) * mask.astype(jnp.float32),
-                gather,
-                preferred_element_type=jnp.float32,
-            ).astype(jnp.uint32)  # (C·L, nw)
+            cur = _f2u(
+                jnp.dot(
+                    jnp.where(mask, _u2f(bt), jnp.float32(0)),
+                    gather,
+                    preferred_element_type=jnp.float32,
+                )
+            )  # (C·L, nw)
             cur_rows = tuple(
                 [cur[c * L + i : c * L + i + 1, :] for i in range(L)] for c in range(C)
             )
@@ -315,9 +324,9 @@ def _bucket_call(cs, pts_t, digs_t, window: int, nw: int, interpret: bool):
             # scatter back: expand each window's sum across its lanes,
             # commit only the masked lane (digit-0 lands in bucket 0,
             # ignored downstream exactly like the XLA scan leg)
-            new_exp = jnp.dot(
-                new_mat.astype(jnp.float32), expand, preferred_element_type=jnp.float32
-            ).astype(jnp.uint32)
+            new_exp = _f2u(
+                jnp.dot(_u2f(new_mat), expand, preferred_element_type=jnp.float32)
+            )
             out_ref[0] = jnp.where(mask, new_exp, bt)
             return carry
 
@@ -333,7 +342,7 @@ def _bucket_call(cs, pts_t, digs_t, window: int, nw: int, interpret: bool):
         kernel,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, C * L, m_pad), lambda i: (i, 0, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, m_pad, C * L), lambda i: (i, 0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, m_pad, nw), lambda i: (i, 0, 0), memory_space=pltpu.VMEM),
         ]
         + extra_specs,
@@ -353,18 +362,15 @@ def bucket_accumulate(
     nw: int,
     *,
     interpret: bool | None = None,
-) -> jax.Array | None:
+) -> jax.Array:
     """Pippenger scatter pass with VMEM-resident buckets.
 
     points (..., m, C, L), digits (..., m, nw) ->
     buckets (..., nw, 2**window, C, L) — bit-identical to the XLA scan
     leg's bucket tensor (same add order through the same complete
     formulas), so groups.device's bucket-close and window-combine
-    passes run unchanged on either leg.  Returns ``None`` when Pallas
-    is unavailable (callers fall back to the scan leg).
+    passes run unchanged on either leg.
     """
-    if not HAVE_PALLAS:  # pragma: no cover
-        return None
     metrics.REGISTRY.inc("pallas_calls_total", kernel="bucket_accumulate")
     L, C = cs.field.limbs, cs.ncoords
     entries = 1 << window
@@ -374,14 +380,13 @@ def bucket_accumulate(
     for d in batch:
         b *= int(d)
     pts = jnp.reshape(jnp.asarray(points, jnp.uint32), (b, m, C * L))
-    pts = jnp.transpose(pts, (0, 2, 1))  # (B, C·L, m)
     digs = jnp.reshape(jnp.asarray(digits, jnp.int32), (b, m, nw))
     interp = _want_interpret() if interpret is None else interpret
     m_pad = m if interp else max(BLOCK, -(-m // BLOCK) * BLOCK)
     if m_pad != m:
         # sentinel digit == entries never matches a bucket lane, so the
         # padding points are computed but never committed
-        pts = jnp.pad(pts, [(0, 0), (0, 0), (0, m_pad - m)])
+        pts = jnp.pad(pts, [(0, 0), (0, m_pad - m), (0, 0)])
         digs = jnp.pad(digs, [(0, 0), (0, m_pad - m), (0, 0)], constant_values=entries)
     out = _bucket_call(cs, pts, digs, window, nw, interp)  # (B, C·L, lanes)
     buckets = jnp.reshape(out, (b, C, L, nw, entries))

@@ -25,6 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..groups.device import CurveSpec
 from ..utils import metrics
@@ -36,14 +38,6 @@ from .pallas_field import (
     mxu_operands,
     rows_mul_context,
 )
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
 
 
 def _const_rows(fs, value: int, like):
@@ -450,10 +444,6 @@ def pt_add(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None 
     """Fused-kernel twin of groups.device.add (both curve kinds).
 
     p, q: (..., C, L) projective/extended points (batches broadcast)."""
-    if not HAVE_PALLAS:  # pragma: no cover
-        from ..groups import device as gd
-
-        return gd._add_xla(cs, p, q)
     metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_add")
     p, q = jnp.broadcast_arrays(jnp.asarray(p, jnp.uint32), jnp.asarray(q, jnp.uint32))
     p_t, batch, n = _to_tiles(cs, p)
@@ -465,10 +455,6 @@ def pt_add(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None 
 def pt_madd(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Fused mixed add: q affine-normalised (Z = 1).  Weierstrass
     callers must not pass q = identity (see groups/device.madd)."""
-    if not HAVE_PALLAS:  # pragma: no cover
-        from ..groups import device as gd
-
-        return gd._madd_xla(cs, p, q)
     metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_madd")
     p, q = jnp.broadcast_arrays(jnp.asarray(p, jnp.uint32), jnp.asarray(q, jnp.uint32))
     p_t, batch, n = _to_tiles(cs, p)
@@ -479,12 +465,6 @@ def pt_madd(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None
 
 def pt_double(cs: CurveSpec, p: jax.Array, n_doubles: int = 1, *, interpret: bool | None = None) -> jax.Array:
     """Fused 2^n_doubles·P in one launch."""
-    if not HAVE_PALLAS:  # pragma: no cover
-        from ..groups import device as gd
-
-        for _ in range(n_doubles):
-            p = gd._double_xla(cs, p)
-        return p
     metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_double")
     p = jnp.asarray(p, jnp.uint32)
     p_t, batch, n = _to_tiles(cs, p)
@@ -496,12 +476,6 @@ def pt_window_step(
     cs: CurveSpec, acc: jax.Array, entry: jax.Array, n_doubles: int = 4, *, interpret: bool | None = None
 ) -> jax.Array:
     """acc <- 2^n_doubles · acc + entry, fused in one kernel launch."""
-    if not HAVE_PALLAS:  # pragma: no cover
-        from ..groups import device as gd
-
-        for _ in range(n_doubles):
-            acc = gd._double_xla(cs, acc)
-        return gd._add_xla(cs, acc, entry)
     metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_window_step")
     acc, entry = jnp.broadcast_arrays(
         jnp.asarray(acc, jnp.uint32), jnp.asarray(entry, jnp.uint32)
@@ -529,20 +503,6 @@ def pt_ladder_mul_add(
     the whole nbits-step ladder — this is eval_point_poly's Horner step
     (acc <- x·acc + E_l) collapsed from ~2·nbits XLA ops into one.
     """
-    if not HAVE_PALLAS:  # pragma: no cover — XLA ladder, no re-dispatch
-        from ..groups import device as gd
-
-        bits = (
-            jnp.asarray(x, jnp.uint32)[..., None]
-            >> jnp.arange(nbits - 1, -1, -1, dtype=jnp.uint32)
-        ) & 1
-        acc = gd.identity(cs, jnp.asarray(p).shape[:-2])
-        for i in range(nbits):
-            acc = gd._double_xla(cs, acc)
-            acc = gd.select(
-                bits[..., i] != 0, gd._add_xla(cs, acc, p), acc
-            )
-        return gd._add_xla(cs, acc, addend)
     metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_ladder_mul_add")
     p, addend = jnp.broadcast_arrays(
         jnp.asarray(p, jnp.uint32), jnp.asarray(addend, jnp.uint32)

@@ -29,32 +29,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect
-
-# jax renamed shard_map's replication-check kwarg (check_rep -> check_vma
-# in 0.9), and newer versions drop it entirely (checked semantics became
-# the only semantics).  Resolve the right name once so call sites stay
-# stable; None means "no kwarg to pass" — every body in this module is
-# collective-explicit, so it type-checks under the always-checked
-# signature and the wrapper degrades to plain shard_map.
-_SHARD_MAP_CHECK_KW = next(
-    (k for k in ("check_vma", "check_rep") if k in inspect.signature(_shard_map).parameters),
-    None,
-)
+from jax import shard_map as _shard_map
 
 
 def _shard_map_nocheck(f, *, mesh, in_specs, out_specs):
-    """shard_map with the replication/VMA check disabled where the
-    installed jax still exposes one (named so a future call site wanting
-    jax's checked semantics doesn't silently get this wrapper)."""
+    """shard_map with the VMA (replication) check disabled (named so a
+    future call site wanting jax's checked semantics doesn't silently
+    get this wrapper)."""
     return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **({_SHARD_MAP_CHECK_KW: False} if _SHARD_MAP_CHECK_KW else {}),
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 from ..dkg import ceremony as ce

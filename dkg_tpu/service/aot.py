@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import ast
 import hashlib
+import logging
 import os
 import pickle
 import tempfile
@@ -55,12 +56,12 @@ import jax
 import numpy as np
 from jax.experimental import serialize_executable as _se
 
-from ..utils import envknobs
+from ..utils import compilecache, envknobs
 from ..utils.metrics import REGISTRY
 
 #: Bump when the artifact layout changes; old files fail the digest
 #: check and silently rebuild.
-_FORMAT_VERSION = 1
+_FORMAT_VERSION = 2
 
 #: Knobs that change the traced program at fixed shapes: two processes
 #: with different tiers must never serve each other's executables, so
@@ -86,6 +87,7 @@ _TIER_KNOBS = (
 )
 
 _LOCK = threading.RLock()
+_LOG = logging.getLogger(__name__)
 #: Per-key build/load locks: the global lock only guards the maps, so a
 #: minutes-long XLA compile for one program never stalls an unrelated
 #: key's lookup (e.g. the steady sign lane behind a ceremony build).
@@ -112,13 +114,12 @@ def enabled() -> bool:
 
 def cache_dir() -> str:
     """The artifact directory: ``DKG_TPU_AOT_DIR``, else beside the JAX
-    compilation cache, else the system temp dir (mirrors
-    precompute.cache_dir so the two stores land together)."""
+    compilation cache (utils.compilecache — mirrors precompute.cache_dir
+    so the two stores land together)."""
     override = envknobs.string("DKG_TPU_AOT_DIR", "AOT executable store directory")
     if override:
         return override
-    base = jax.config.jax_compilation_cache_dir or tempfile.gettempdir()
-    return os.path.join(base, "dkg_tpu_aot_store")
+    return os.path.join(compilecache.cache_root(), "dkg_tpu_aot_store")
 
 
 def knob_tier() -> str:
@@ -163,9 +164,29 @@ def _path(key: tuple) -> str:
     return os.path.join(cache_dir(), f"aot_v{_FORMAT_VERSION}_{key[0]}_{tag}.npz")
 
 
+def serialize(compiled) -> bytes:
+    """One compiled program as store payload bytes: the ids of the
+    device(s) it was compiled for, then jax's serialized executable."""
+    dev_ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps((dev_ids, *_se.serialize(compiled)), protocol=4)
+
+
+def deserialize(blob: bytes):
+    """Load :func:`serialize`'s payload back onto the SAME device(s).
+    ``deserialize_and_load``'s own default is every local device, which
+    a one-device executable cannot run on."""
+    dev_ids, *payload = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        *payload, execution_devices=[by_id[i] for i in dev_ids]
+    )
+
+
 def _load_blob(path: str, key: tuple):
-    """Deserialize one artifact; None on ANY failure (missing, torn,
-    digest mismatch, version skew, unloadable executable)."""
+    """Deserialize one artifact.  Missing, torn, digest-mismatched or
+    version-skewed files are cache misses (None, ``disk_rejects``); an
+    artifact that passes its digest and then fails to LOAD is an error
+    (None too — the caller rebuilds — but counted and logged)."""
     t0 = time.perf_counter()
     try:
         with np.load(path, allow_pickle=False) as z:
@@ -176,13 +197,17 @@ def _load_blob(path: str, key: tuple):
             raise ValueError("key mismatch")
         if digest != _digest(_header(key), blob):
             raise ValueError("digest mismatch")
-        fn = _se.deserialize_and_load(*pickle.loads(blob))
     except FileNotFoundError:
         return None
     except Exception:
         with _LOCK:  # may run outside the global lock (get_or_build)
             _STATS["disk_rejects"] += 1
         REGISTRY.inc("aot_disk_rejects_total")
+        return None
+    try:
+        fn = deserialize(blob)
+    except Exception as exc:
+        note_error(exc, f"load {path}")
         return None
     dt = time.perf_counter() - t0
     with _LOCK:
@@ -252,14 +277,11 @@ def get_or_build(key: tuple, build):
             REGISTRY.inc("aot_builds_total")
             REGISTRY.observe("aot_build_seconds", dt)
             try:
-                blob = pickle.dumps(_se.serialize(fn), protocol=4)
-                _persist(path, key, blob)
-            except Exception:
+                _persist(path, key, serialize(fn))
+            except Exception as exc:
                 # some backends can't serialize; the compiled program
                 # still serves this process
-                with _LOCK:
-                    _STATS["errors"] += 1
-                REGISTRY.inc("aot_errors_total")
+                note_error(exc, "serialize")
         with _LOCK:
             _PROC[key] = fn
         return fn
@@ -377,11 +399,15 @@ def has_prefix(prefix: tuple) -> bool:
         return any(k[: len(prefix)] == prefix for k in _PROC)
 
 
-def note_error() -> None:
-    """Count one store failure (the caller degraded to its jit path)."""
+def note_error(exc: BaseException, where: str) -> None:
+    """Count one store failure (the caller rebuilt or degraded to its
+    jit path) and log the first with its exception text."""
     with _LOCK:
         _STATS["errors"] += 1
+        first = _STATS["errors"] == 1
     REGISTRY.inc("aot_errors_total")
+    if first:
+        _LOG.error("AOT store error (%s): %s: %s", where, type(exc).__name__, exc)
 
 
 def stats() -> dict:

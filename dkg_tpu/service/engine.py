@@ -229,16 +229,16 @@ def _aot_dispatch(key_prefix: tuple, args: tuple, lower, fallback):
     it is enabled, else the ordinary jitted twin.  ``lower`` maps a
     tuple of ShapeDtypeStruct specs to a ``jax.stages.Lowered`` (statics
     baked in); the compiled result is persisted for every later process.
-    A store failure of any kind degrades to ``fallback`` — a request
-    must never die on a cache problem."""
+    A store failure degrades to ``fallback`` — a request must never die
+    on a cache problem — but is counted and logged (aot.note_error)."""
     if not aot.enabled():
         return fallback()
     try:
         key = key_prefix + (aot.spec_sig(args),)
         fn = aot.get_or_build(key, lambda: lower(_specs(args)).compile())
         return fn(*args)
-    except Exception:
-        aot.note_error()
+    except Exception as exc:
+        aot.note_error(exc, f"dispatch {key_prefix[0]}")
         return fallback()
 
 

@@ -47,15 +47,12 @@ ladder over the device mesh; 1 engages only where shards run
 concurrently (accelerator backend or a multi-core host), force on any
 >=2-device mesh; the Mesh handle and shard_map live in
 parallel.signmesh, per lint rule DKG015) via parallel.signmesh,
-DKG_TPU_NORTH_STAR (bench.py: 1 forces the north-star sharded rung on
-any platform, 0 skips it; read by the driver scripts, not dkg_tpu/),
 DKG_TPU_EPOCH_MAX_CHURN (leave+join budget a reshare accepts; 0
 refuses any membership change) and DKG_TPU_EPOCH_DEADLINE_S
 (per-epoch-round fetch timeout) via dkg_tpu.epoch.manager — lint
 rule DKG008 likewise bans raw environment access in dkg_tpu/epoch/,
 DKG_TPU_AOT_DIR (AOT-serialized executable store directory; unset
-keeps the store off) via service.aot — also read by scripts/aot_lab.py
-as its compile-cache location,
+keeps the store off) via service.aot,
 DKG_TPU_AOT_TOPOLOGY (chip-less topology scripts/aot_lab.py compiles
 against, default v5e:2x2),
 DKG_TPU_FLEET_PROCS (initial worker-process count) /

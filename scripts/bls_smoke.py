@@ -25,8 +25,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import jax
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/dkg_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from dkg_tpu.utils import compilecache
+
+compilecache.enable()
 
 from dkg_tpu.dkg import ceremony as ce
 from dkg_tpu.utils.tracing import CeremonyTrace

@@ -60,11 +60,9 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-    # epoch runs compile the dealing kernels; persist them across storms
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR", "/tmp/dkg_tpu_jax_cache_cputest"
-    )
+from dkg_tpu.utils import compilecache  # noqa: E402
+
+compilecache.enable()  # epoch runs compile the dealing kernels; persist them across storms
 
 from dkg_tpu.groups import host as gh  # noqa: E402
 from dkg_tpu.net import InProcessChannel, PartyResult, TcpHub, TcpHubChannel  # noqa: E402

@@ -53,21 +53,13 @@ import random
 import sys
 import time
 
-if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-    # persistent compile cache: stacked-lane programs cost minutes to
-    # compile on CPU and never change between rounds
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR", "/tmp/dkg_tpu_jax_cache_cputest"
-    )
-
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 import jax  # noqa: E402
 
-if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-    jax.config.update(
-        "jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"]
-    )
+from dkg_tpu.utils import compilecache  # noqa: E402
+
+compilecache.enable()
 
 from dkg_tpu.service import buckets, engine  # noqa: E402
 from dkg_tpu.service.scheduler import CeremonyScheduler  # noqa: E402

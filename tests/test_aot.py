@@ -11,7 +11,6 @@ exercised end-to-end by scripts/aot_build.py + scripts/fleet_bench.py.
 from __future__ import annotations
 
 import os
-import pickle
 
 import jax
 import jax.numpy as jnp
@@ -191,13 +190,32 @@ def test_targeted_preload_and_disk_presence(store):
 
 def test_serialized_blob_roundtrip_bit_identical(store):
     """The serialize/deserialize pair itself: payload pickles whole and
-    the loaded executable answers exactly like the original."""
-    from jax.experimental import serialize_executable as se
-
+    the loaded executable answers exactly like the original — on the
+    one device it was compiled for, not every local device."""
     compiled = _build_double()
-    blob = pickle.dumps(se.serialize(compiled), protocol=4)
-    fn = se.deserialize_and_load(*pickle.loads(blob))
+    fn = aot.deserialize(aot.serialize(compiled))
     np.testing.assert_array_equal(np.asarray(fn(_X)), np.asarray(compiled(_X)))
+
+
+def test_valid_artifact_that_fails_to_load_is_an_error(store, monkeypatch, caplog):
+    """A digest-valid artifact whose executable will not load is counted
+    in errors / aot_errors_total and logged with the exception text —
+    not filed under disk_rejects as if it were a stale file."""
+    aot.get_or_build(KEY, _build_double)
+    aot.reset()
+
+    def _boom(blob):
+        raise RuntimeError("executable refused to load")
+
+    monkeypatch.setattr(aot, "deserialize", _boom)
+    before = aot.REGISTRY.snapshot()["counters"].get("aot_errors_total", 0)
+    with caplog.at_level("ERROR", logger=aot.__name__):
+        fn = aot.get_or_build(KEY, _build_double)
+    np.testing.assert_array_equal(np.asarray(fn(_X)), _X * 2)
+    s = aot.stats()
+    assert s["errors"] == 1 and s["disk_rejects"] == 0 and s["builds"] == 1
+    assert aot.REGISTRY.snapshot()["counters"]["aot_errors_total"] == before + 1
+    assert "executable refused to load" in caplog.text
 
 
 def test_spec_sig_pins_shapes_and_dtypes():

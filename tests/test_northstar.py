@@ -38,48 +38,28 @@ def _load_script(name: str):
 
 
 # ---------------------------------------------------------------------------
-# shard_map check-kwarg version seam
+# shard_map wrapper
 # ---------------------------------------------------------------------------
 
 
-def test_shard_map_check_kw_resolves_on_this_jax():
-    """The seam must land on a kwarg this jax actually accepts — or
-    None, which _shard_map_nocheck treats as 'pass nothing'."""
-    params = inspect.signature(pm._shard_map).parameters
-    if pm._SHARD_MAP_CHECK_KW is None:
-        assert "check_vma" not in params and "check_rep" not in params
-    else:
-        assert pm._SHARD_MAP_CHECK_KW in params
+def test_installed_shard_map_takes_check_vma():
+    """mesh.py passes check_vma by name — the installed jax's spelling."""
+    assert "check_vma" in inspect.signature(pm._shard_map).parameters
 
 
-def test_shard_map_nocheck_tolerates_kwargless_shard_map(monkeypatch):
-    """jax versions that dropped BOTH check kwargs must still work: the
-    seam resolves to None and _shard_map_nocheck passes no check kwarg
-    at all (passing check_rep=False to such a shard_map would raise
-    TypeError at every collective call site)."""
-
+def test_shard_map_nocheck_disables_the_vma_check(monkeypatch):
     seen = {}
 
-    def bare_shard_map(f, *, mesh, in_specs, out_specs):
-        seen["called"] = True
+    def recording_shard_map(f, **kw):
+        seen.update(kw)
         return f
 
-    kw = next(
-        (
-            k
-            for k in ("check_vma", "check_rep")
-            if k in inspect.signature(bare_shard_map).parameters
-        ),
-        None,
-    )
-    assert kw is None, "the resolver must yield None for a kwargless signature"
-    monkeypatch.setattr(pm, "_shard_map", bare_shard_map)
-    monkeypatch.setattr(pm, "_SHARD_MAP_CHECK_KW", kw)
+    monkeypatch.setattr(pm, "_shard_map", recording_shard_map)
     wrapped = pm._shard_map_nocheck(
-        lambda x: x + 1, mesh=None, in_specs=None, out_specs=None
+        lambda x: x + 1, mesh="m", in_specs="i", out_specs="o"
     )
     assert wrapped(41) == 42
-    assert seen["called"]
+    assert seen == {"mesh": "m", "in_specs": "i", "out_specs": "o", "check_vma": False}
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,115 @@
+"""The chip's compiler, asked in the sandbox: the Pallas kernels of the
+default on-chip path must compile for a described (not attached) v5e.
+
+Interpret-mode parity tests cannot see what Mosaic refuses — a missing
+uint32<->float32 cast and a dynamic lane slice got through every one of
+them (PR 22).  These compile each kernel with ``interpret=False`` at
+batch 1024 against ``v5e:2x2`` and look for the ``tpu_custom_call`` in
+the result.  Nothing runs, so they say nothing about results or time.
+
+The topology is described inside a module-scoped fixture — never at
+import, in a ``skipif`` or in ``parametrize`` arguments: only one
+process may load libtpu, every xdist worker imports this file, and only
+the worker that runs it may make the call.  Keep these tests in this
+ONE file for the same reason.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dkg_tpu.groups import device as gd
+from dkg_tpu.ops import pallas_field as pf
+from dkg_tpu.ops import pallas_mxu as pm
+from dkg_tpu.ops import pallas_point as pp
+
+B = 1024  # batch lanes
+M = 1024  # bucket kernel: points per MSM (pippenger_window picks w=8)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here, or it is locked
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a topology compile is written to the persistent cache but cannot
+    # be read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _kernel(name: str, cs: gd.CurveSpec):
+    """(function, operand shapes) for one kernel at the smoke's widths."""
+    fs, L, C = cs.field, cs.field.limbs, cs.ncoords
+    elem, point = (B, L), (B, C, L)
+    if name == "bucket_accumulate":
+        w = gd.pippenger_window(M, cs.name)
+        nw = -(-cs.scalar.limbs * 16 // w)
+        return (
+            lambda p, d: pm.bucket_accumulate(cs, p, d, w, nw, interpret=False),
+            [((4, M, C, L), jnp.uint32), ((4, M, nw), jnp.int32)],
+        )
+    table = {
+        "mod_mul": (lambda a, b: pf.mod_mul(fs, a, b, interpret=False), [elem] * 2),
+        "mod_madd": (lambda a, b, c: pf.mod_madd(fs, a, b, c, interpret=False), [elem] * 3),
+        "mxu_mod_mul": (lambda a, b: pm.mxu_mod_mul(fs, a, b, interpret=False), [elem] * 2),
+        "pt_add": (lambda p, q: pp.pt_add(cs, p, q, interpret=False), [point] * 2),
+        "pt_madd": (lambda p, q: pp.pt_madd(cs, p, q, interpret=False), [point] * 2),
+        "pt_double": (lambda p: pp.pt_double(cs, p, interpret=False), [point]),
+        "pt_window_step": (
+            lambda p, q: pp.pt_window_step(cs, p, q, 4, interpret=False), [point] * 2,
+        ),
+        "pt_ladder_mul_add": (
+            lambda p, a, x: pp.pt_ladder_mul_add(cs, p, a, x, 11, interpret=False),
+            [point, point, (B,)],
+        ),
+    }
+    fn, shapes = table[name]
+    return fn, [(s, jnp.uint32) for s in shapes]
+
+
+def _compiles_to_a_tpu_kernel(one_chip, curve: str, name: str) -> None:
+    fn, operands = _kernel(name, gd.ALL_CURVES[curve])
+    specs = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in operands]
+    compiled = jax.jit(fn).lower(*specs).compile()  # raises what the chip's compiler raises
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "curve,name",
+    [
+        ("secp256k1", "mod_mul"),
+        ("secp256k1", "mod_madd"),
+        ("secp256k1", "mxu_mod_mul"),
+        ("secp256k1", "pt_add"),
+        ("secp256k1", "pt_madd"),
+        ("secp256k1", "pt_double"),
+        ("secp256k1", "bucket_accumulate"),
+        ("ristretto255", "pt_add"),
+    ],
+)
+def test_kernel_compiles_for_v5e(one_chip, curve, name, monkeypatch):
+    monkeypatch.delenv("DKG_TPU_MUL", raising=False)  # the default (mxu) multiply core
+    assert pf.rows_mul_dispatch(gd.ALL_CURVES[curve].field, False) == "mxu"
+    _compiles_to_a_tpu_kernel(one_chip, curve, name)
+
+
+@pytest.mark.slow  # 20-30 s each in the sandbox
+@pytest.mark.parametrize("name", ["pt_window_step", "pt_ladder_mul_add"])
+def test_multi_op_kernel_compiles_for_v5e(one_chip, name, monkeypatch):
+    monkeypatch.delenv("DKG_TPU_MUL", raising=False)
+    _compiles_to_a_tpu_kernel(one_chip, "secp256k1", name)
