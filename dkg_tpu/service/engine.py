@@ -32,6 +32,15 @@ whole ceremonies: ``start`` only *dispatches* device work (JAX dispatch
 is asynchronous), so a scheduler worker can start convoy k+1 before
 doing convoy k's host-side transcript/DEM work under the device's
 dispatch shadow.
+
+Every stage of a convoy runs inside ``tracing.phase_span(fl.trace,
+"convoy.<stage>")`` (:data:`CONVOY_STAGES`; docs/observability.md has
+the table): a host event ``dkg/convoy.<stage>`` on the profiler's clock,
+a ``dkg_phase_seconds{phase="convoy.<stage>"}`` observation and an entry
+of the convoy's :class:`~dkg_tpu.utils.tracing.CeremonyTrace`.  A device
+step is two stages, ``*_dispatch`` (host work up to the asynchronous
+dispatch's return) and ``*_wait`` (blocked in ``np.asarray``), so host
+time and waiting never share a number.
 """
 
 from __future__ import annotations
@@ -39,6 +48,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
+import itertools
 import random
 import threading
 
@@ -52,6 +62,7 @@ from ..fields import host as fh
 from ..groups import device as gd
 from ..groups import host as gh
 from ..groups import precompute as gp
+from ..utils import tracing
 from . import aot, buckets
 from .errors import PoisonedRequest
 
@@ -129,6 +140,11 @@ class CeremonyOutcome:
     #: time.monotonic() stamp set by the scheduler when the outcome was
     #: recorded — lets clients compute queue-to-completion latency
     completed_at: float = 0.0
+    #: width of the convoy this ceremony ran in, and the seconds it sat
+    #: queued (admission to the pop that took it into that convoy); set
+    #: by the scheduler, 0 for outcomes that never rode a convoy
+    convoy_width: int = 0
+    queue_seconds: float = 0.0
     #: epoch counter of the held sharing: 0 at the ceremony, +1 per
     #: completed refresh/reshare against this outcome (the scheduler's
     #: epoch methods CAS on it).  ``master`` never changes with it.
@@ -342,8 +358,24 @@ def rng_for(req: CeremonyRequest):
     return random.Random(req.seed)
 
 
+#: A convoy's stages in the order they run.  ``hold`` is the scheduler's
+#: (service/scheduler.py): dispatched, waiting for its worker to come
+#: back from the previous convoy's finish.
+CONVOY_STAGES = (
+    "draw", "deal_dispatch", "hold", "deal_wait", "digest_dispatch",
+    "digest_wait", "rho_fold", "verify_dispatch", "verify_wait", "blame",
+    "finalise_dispatch", "finalise_wait", "encode",
+)
+
+_CONVOY_SEQ = itertools.count()
+
+
+def _stage(trace, stage: str):
+    return tracing.phase_span(trace, f"convoy.{stage}")
+
+
 def derive_rho_convoy(
-    cfg: ce.CeremonyConfig, a, e, s, r, rho_bits: int
+    cfg: ce.CeremonyConfig, a, e, s, r, rho_bits: int, trace=None
 ) -> np.ndarray:
     """Per-ceremony Fiat-Shamir randomizers for a whole convoy, (k, n,
     L) — bit-identical to calling :func:`dkg_tpu.dkg.ceremony.
@@ -355,31 +387,32 @@ def derive_rho_convoy(
     outer fold (3 small arrays through one blake2b) stays per ceremony.
     This is the digest's share of the dispatch amortization that makes
     the stacked lane pay: per-ceremony digest calls were ~40% of a small
-    convoy's wall clock.
+    convoy's wall clock.  A width-1 convoy takes the same path: its
+    (1*n, ...) rows are ``derive_rho``'s own shapes, so no program is
+    added, and its stages carry the same names.
     """
     k, n = s.shape[0], s.shape[1]
-    if k == 1:
-        return ce.derive_rho(cfg, a[0], e[0], s[0], r[0], rho_bits)[None]
-    rows_a, rows_e, rows_sr = ce._dealer_rows_device(
-        cfg,
-        np.reshape(a, (k * n,) + a.shape[2:]),
-        np.reshape(e, (k * n,) + e.shape[2:]),
-        np.reshape(s, (k * n,) + s.shape[2:]),
-        np.reshape(r, (k * n,) + r.shape[2:]),
-    )
-    rows_a = np.asarray(rows_a).reshape(k, n, -1)
-    rows_e = np.asarray(rows_e).reshape(k, n, -1)
-    rows_sr = np.asarray(rows_sr).reshape(k, n, -1)
-    return np.stack(
-        [
-            ce.fiat_shamir_rho(
-                cfg,
-                ce._fold_digest_device(cfg, rows_a[i], rows_e[i], rows_sr[i]),
-                rho_bits,
-            )
-            for i in range(k)
-        ]
-    )
+    with _stage(trace, "digest_dispatch"):
+        rows = ce._dealer_rows_device(
+            cfg,
+            np.reshape(a, (k * n,) + a.shape[2:]),
+            np.reshape(e, (k * n,) + e.shape[2:]),
+            np.reshape(s, (k * n,) + s.shape[2:]),
+            np.reshape(r, (k * n,) + r.shape[2:]),
+        )
+    with _stage(trace, "digest_wait"):
+        rows_a, rows_e, rows_sr = (np.asarray(x).reshape(k, n, -1) for x in rows)
+    with _stage(trace, "rho_fold"):
+        return np.stack(
+            [
+                ce.fiat_shamir_rho(
+                    cfg,
+                    ce._fold_digest_device(cfg, rows_a[i], rows_e[i], rows_sr[i]),
+                    rho_bits,
+                )
+                for i in range(k)
+            ]
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -400,6 +433,12 @@ class InFlight:
     e: jax.Array
     s: jax.Array  # (k, n_pad, n_pad, L)
     r: jax.Array
+    #: the convoy's stage seconds (``convoy.<stage>``); ``meta`` names
+    #: the convoy: sequence number, width, bucket, ceremony ids, and the
+    #: worker slot once a scheduler has added it
+    trace: tracing.CeremonyTrace = dataclasses.field(
+        default_factory=tracing.CeremonyTrace
+    )
 
 
 def start_convoy(
@@ -414,37 +453,47 @@ def start_convoy(
         raise ValueError("start_convoy: mixed convoy keys")
     req0 = reqs[0]
     b = req0.bucket()
+    k = len(reqs)
     cfg_pad = ce.CeremonyConfig(req0.curve, req0.n, req0.t).padded(b.n, b.t)
     _, g_table, h_table = runtime.commitment(req0.curve, req0.shared_string)
-    ca, cb = [], []
-    for req in reqs:
-        cfg_real = ce.CeremonyConfig(req.curve, req.n, req.t)
-        a_real, b_real = draw_coeffs(cfg_real, rng_for(req))
-        ca.append(pad_coeffs(a_real, b.n, b.t))
-        cb.append(pad_coeffs(b_real, b.n, b.t))
-    if len(reqs) == 1:
-        args = (jnp.asarray(ca[0]), jnp.asarray(cb[0]), g_table, h_table)
-        a, e, s, r = _aot_dispatch(
-            ("deal", req0.curve, b.n, b.t, 1, 0),
-            args,
-            lambda sp: ce.deal.lower(cfg_pad, *sp),
-            lambda: ce.deal(cfg_pad, *args),
-        )
-        a, e, s, r = a[None], e[None], s[None], r[None]
-    else:
-        args = (
-            jnp.asarray(np.stack(ca)), jnp.asarray(np.stack(cb)),
-            g_table, h_table,
-        )
-        a, e, s, r = _aot_dispatch(
-            ("deal", req0.curve, b.n, b.t, len(reqs), 0),
-            args,
-            lambda sp: _deal_stack.lower(cfg_pad, *sp),
-            lambda: _deal_stack(cfg_pad, *args),
-        )
     if ids is None:
         ids = [request_id(req, i) for i, req in enumerate(reqs)]
-    return InFlight(list(reqs), list(ids), cfg_pad, g_table, h_table, a, e, s, r)
+    trace = tracing.CeremonyTrace(
+        meta={
+            "convoy": next(_CONVOY_SEQ),
+            "width": k,
+            "bucket": f"{b.n}x{b.t}",
+            "ceremonies": list(ids),
+        }
+    )
+    with _stage(trace, "draw"):
+        ca, cb = [], []
+        for req in reqs:
+            cfg_real = ce.CeremonyConfig(req.curve, req.n, req.t)
+            a_real, b_real = draw_coeffs(cfg_real, rng_for(req))
+            ca.append(pad_coeffs(a_real, b.n, b.t))
+            cb.append(pad_coeffs(b_real, b.n, b.t))
+        ca_h, cb_h = (ca[0], cb[0]) if k == 1 else (np.stack(ca), np.stack(cb))
+    with _stage(trace, "deal_dispatch"):
+        args = (jnp.asarray(ca_h), jnp.asarray(cb_h), g_table, h_table)
+        if k == 1:
+            a, e, s, r = _aot_dispatch(
+                ("deal", req0.curve, b.n, b.t, 1, 0),
+                args,
+                lambda sp: ce.deal.lower(cfg_pad, *sp),
+                lambda: ce.deal(cfg_pad, *args),
+            )
+            a, e, s, r = a[None], e[None], s[None], r[None]
+        else:
+            a, e, s, r = _aot_dispatch(
+                ("deal", req0.curve, b.n, b.t, k, 0),
+                args,
+                lambda sp: _deal_stack.lower(cfg_pad, *sp),
+                lambda: _deal_stack(cfg_pad, *args),
+            )
+    return InFlight(
+        list(reqs), list(ids), cfg_pad, g_table, h_table, a, e, s, r, trace
+    )
 
 
 def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
@@ -453,43 +502,47 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
     :func:`start_convoy` — everything before this call overlaps it."""
     del runtime  # tables travel on the InFlight
     cfg_pad = fl.cfg_pad
+    trace = fl.trace
     k = len(fl.reqs)
     n_pad = cfg_pad.n
     rho_bits = fl.reqs[0].rho_bits
-    a_h, e_h = np.asarray(fl.a), np.asarray(fl.e)
-    s_h, r_h = np.asarray(fl.s), np.asarray(fl.r)
-    rho = derive_rho_convoy(cfg_pad, a_h, e_h, s_h, r_h, rho_bits)
+    with _stage(trace, "deal_wait"):
+        a_h, e_h = np.asarray(fl.a), np.asarray(fl.e)
+        s_h, r_h = np.asarray(fl.s), np.asarray(fl.r)
+    rho = derive_rho_convoy(cfg_pad, a_h, e_h, s_h, r_h, rho_bits, trace)
     curve = fl.reqs[0].curve
-    if k == 1:
-        args = (
-            fl.e[0], fl.s[0], fl.r[0], jnp.asarray(rho[0]),
-            fl.g_table, fl.h_table,
-        )
-        ok = _aot_dispatch(
-            ("verify", curve, n_pad, cfg_pad.t, 1, rho_bits),
-            args,
-            lambda sp: ce.verify_batch.lower(
-                cfg_pad, sp[0], sp[1], sp[2], sp[3], rho_bits, sp[4], sp[5]
-            ),
-            lambda: ce.verify_batch(
-                cfg_pad, args[0], args[1], args[2], args[3], rho_bits,
-                args[4], args[5],
-            ),
-        )[None]
-    else:
-        args = (fl.e, fl.s, fl.r, jnp.asarray(rho), fl.g_table, fl.h_table)
-        ok = _aot_dispatch(
-            ("verify", curve, n_pad, cfg_pad.t, k, rho_bits),
-            args,
-            lambda sp: _verify_stack.lower(
-                cfg_pad, sp[0], sp[1], sp[2], sp[3], rho_bits, sp[4], sp[5]
-            ),
-            lambda: _verify_stack(
-                cfg_pad, args[0], args[1], args[2], args[3], rho_bits,
-                args[4], args[5],
-            ),
-        )
-    ok_h = np.asarray(ok)
+    with _stage(trace, "verify_dispatch"):
+        if k == 1:
+            args = (
+                fl.e[0], fl.s[0], fl.r[0], jnp.asarray(rho[0]),
+                fl.g_table, fl.h_table,
+            )
+            ok = _aot_dispatch(
+                ("verify", curve, n_pad, cfg_pad.t, 1, rho_bits),
+                args,
+                lambda sp: ce.verify_batch.lower(
+                    cfg_pad, sp[0], sp[1], sp[2], sp[3], rho_bits, sp[4], sp[5]
+                ),
+                lambda: ce.verify_batch(
+                    cfg_pad, args[0], args[1], args[2], args[3], rho_bits,
+                    args[4], args[5],
+                ),
+            )[None]
+        else:
+            args = (fl.e, fl.s, fl.r, jnp.asarray(rho), fl.g_table, fl.h_table)
+            ok = _aot_dispatch(
+                ("verify", curve, n_pad, cfg_pad.t, k, rho_bits),
+                args,
+                lambda sp: _verify_stack.lower(
+                    cfg_pad, sp[0], sp[1], sp[2], sp[3], rho_bits, sp[4], sp[5]
+                ),
+                lambda: _verify_stack(
+                    cfg_pad, args[0], args[1], args[2], args[3], rho_bits,
+                    args[4], args[5],
+                ),
+            )
+    with _stage(trace, "verify_wait"):
+        ok_h = np.asarray(ok)
 
     qualified = np.zeros((k, n_pad), bool)
     complaints: list[list[tuple[int, int]]] = [[] for _ in range(k)]
@@ -500,11 +553,12 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
             # rare blame path, per ceremony: the engine holds the
             # plaintext share matrix, so re-checking IS adjudication
             # (mirrors BatchedCeremony.run)
-            pw = np.asarray(
-                ce.verify_pairwise(
-                    cfg_pad, fl.e[i], fl.s[i], fl.r[i], fl.g_table, fl.h_table
-                )
-            )[: req.n, : req.n]
+            with _stage(trace, "blame"):
+                pw = np.asarray(
+                    ce.verify_pairwise(
+                        cfg_pad, fl.e[i], fl.s[i], fl.r[i], fl.g_table, fl.h_table
+                    )
+                )[: req.n, : req.n]
             guilty = ~pw.all(axis=1)
             complaints[i] = [
                 (int(rcp) + 1, int(dlr) + 1) for dlr, rcp in zip(*np.nonzero(~pw))
@@ -513,52 +567,56 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
             if int(guilty.sum()) > req.t:
                 errors[i] = "MISBEHAVIOUR_HIGHER_THRESHOLD"
 
-    if k == 1:
-        # width-1 lanes reuse the plain executables (shared with
-        # BatchedCeremony and the rest of the suite's compile cache)
-        q0 = jnp.asarray(qualified[0])
-        final_shares = _aot_dispatch(
-            ("aggregate", curve, n_pad, cfg_pad.t, 1, 0),
-            (fl.s[0], q0),
-            lambda sp: ce.aggregate_shares.lower(cfg_pad, *sp),
-            lambda: ce.aggregate_shares(cfg_pad, fl.s[0], q0),
-        )[None]
-        master = _aot_dispatch(
-            ("master", curve, n_pad, cfg_pad.t, 1, 0),
-            (fl.a[0], q0),
-            lambda sp: ce.master_key_from_bare.lower(cfg_pad, *sp),
-            lambda: ce.master_key_from_bare(cfg_pad, fl.a[0], q0),
-        )[None]
-    else:
-        qd = jnp.asarray(qualified)
-        final_shares, master = _aot_dispatch(
-            ("finalise", curve, n_pad, cfg_pad.t, k, 0),
-            (fl.a, fl.s, qd),
-            lambda sp: _finalise_stack.lower(cfg_pad, *sp),
-            lambda: _finalise_stack(cfg_pad, fl.a, fl.s, qd),
-        )
-    shares_h = np.asarray(final_shares)
-    master_enc = gd.encode_batch(cfg_pad.cs, np.asarray(master))
-
-    out = []
-    for i, req in enumerate(fl.reqs):
-        failed = bool(errors[i])
-        out.append(
-            CeremonyOutcome(
-                ceremony_id=fl.ids[i],
-                status="failed" if failed else "done",
-                curve=req.curve,
-                n=req.n,
-                t=req.t,
-                bucket_n=cfg_pad.n,
-                bucket_t=cfg_pad.t,
-                master=b"" if failed else master_enc[i].tobytes(),
-                qualified=tuple(bool(q) for q in qualified[i, : req.n]),
-                complaints=tuple(complaints[i]),
-                error=errors[i],
-                final_shares=None if failed else shares_h[i, : req.n],
+    with _stage(trace, "finalise_dispatch"):
+        if k == 1:
+            # width-1 lanes reuse the plain executables (shared with
+            # BatchedCeremony and the rest of the suite's compile cache)
+            q0 = jnp.asarray(qualified[0])
+            final_shares = _aot_dispatch(
+                ("aggregate", curve, n_pad, cfg_pad.t, 1, 0),
+                (fl.s[0], q0),
+                lambda sp: ce.aggregate_shares.lower(cfg_pad, *sp),
+                lambda: ce.aggregate_shares(cfg_pad, fl.s[0], q0),
+            )[None]
+            master = _aot_dispatch(
+                ("master", curve, n_pad, cfg_pad.t, 1, 0),
+                (fl.a[0], q0),
+                lambda sp: ce.master_key_from_bare.lower(cfg_pad, *sp),
+                lambda: ce.master_key_from_bare(cfg_pad, fl.a[0], q0),
+            )[None]
+        else:
+            qd = jnp.asarray(qualified)
+            final_shares, master = _aot_dispatch(
+                ("finalise", curve, n_pad, cfg_pad.t, k, 0),
+                (fl.a, fl.s, qd),
+                lambda sp: _finalise_stack.lower(cfg_pad, *sp),
+                lambda: _finalise_stack(cfg_pad, fl.a, fl.s, qd),
             )
-        )
+    with _stage(trace, "finalise_wait"):
+        shares_h = np.asarray(final_shares)
+        master_h = np.asarray(master)
+
+    with _stage(trace, "encode"):
+        master_enc = gd.encode_batch(cfg_pad.cs, master_h)
+        out = []
+        for i, req in enumerate(fl.reqs):
+            failed = bool(errors[i])
+            out.append(
+                CeremonyOutcome(
+                    ceremony_id=fl.ids[i],
+                    status="failed" if failed else "done",
+                    curve=req.curve,
+                    n=req.n,
+                    t=req.t,
+                    bucket_n=cfg_pad.n,
+                    bucket_t=cfg_pad.t,
+                    master=b"" if failed else master_enc[i].tobytes(),
+                    qualified=tuple(bool(q) for q in qualified[i, : req.n]),
+                    complaints=tuple(complaints[i]),
+                    error=errors[i],
+                    final_shares=None if failed else shares_h[i, : req.n],
+                )
+            )
     return out
 
 

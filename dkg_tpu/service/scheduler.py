@@ -94,7 +94,7 @@ import numpy as np
 from ..epoch import inprocess as epoch_inprocess
 from ..fields import host as fh
 from ..groups import host as gh
-from ..utils import envknobs, obslog, runtimeobs
+from ..utils import envknobs, obslog, runtimeobs, tracing
 from ..utils.metrics import REGISTRY
 from . import buckets, errors, httpobs
 from .durable import ServiceJournal
@@ -118,7 +118,10 @@ _MAX_CRASH_REQUEUES = 1
 
 
 class _Pending:
-    __slots__ = ("cid", "seq", "req", "deadline_at", "crashes")
+    __slots__ = (
+        "cid", "seq", "req", "deadline_at", "crashes", "admitted_at",
+        "queue_s",
+    )
 
     def __init__(self, cid, seq, req, deadline_at):
         self.cid = cid
@@ -126,6 +129,8 @@ class _Pending:
         self.req = req
         self.deadline_at = deadline_at
         self.crashes = 0  # worker-crash orphanings survived so far
+        self.admitted_at = time.monotonic()
+        self.queue_s = 0.0  # admission to the pop that took it into a convoy
 
 
 class _SignPending:
@@ -1268,9 +1273,18 @@ class CeremonyScheduler:
                 w for w in buckets.WIDTHS if w <= min(len(mates), cap)
             )
             convoy = mates[:width]
+            now = time.monotonic()
+            b = head.req.bucket()
+            label = f"{b.n}x{b.t}"
             for p in convoy:
                 self._queue.remove(p)
                 self._status[p.cid] = "running"
+                # a request re-queued after a worker crash is popped, and
+                # observed, again: its wait is then to the later pop
+                p.queue_s = now - p.admitted_at
+                self.metrics.observe(
+                    "service_queue_wait_seconds", p.queue_s, bucket=label
+                )
             self.metrics.set_gauge("service_queue_depth", len(self._queue))
             self.metrics.inc("service_convoys_total")
             self._cond.notify_all()
@@ -1290,10 +1304,11 @@ class CeremonyScheduler:
 
     def _run_once(self, convoy):
         """Synchronous start+finish of a (sub-)convoy — the bisection /
-        retry lane, off the two-deep pipeline."""
+        retry lane, off the two-deep pipeline (so never held: its trace
+        has no ``convoy.hold``).  Returns (outcomes, the convoy's trace)."""
         reqs = [p.req for p in convoy]
         fl = self._engine_start(reqs, [p.cid for p in convoy])
-        return self._engine_finish(fl, reqs)
+        return self._engine_finish(fl, reqs), getattr(fl, "trace", None)
 
     def _hold(self, slot: int, convoy) -> None:
         with self._cond:
@@ -1306,7 +1321,7 @@ class CeremonyScheduler:
                 held.remove(convoy)
 
     def _worker(self, slot: int) -> None:
-        inflight = None  # (convoy, InFlight, t_start)
+        inflight = None  # (convoy, InFlight, t_start, t_dispatched)
         while True:
             convoy = self._pop_convoy(block=inflight is None)
             if convoy is not None:
@@ -1320,10 +1335,14 @@ class CeremonyScheduler:
                     self._isolate(convoy, exc, t0)
                     self._release(slot, convoy)
                     continue
+                held_from = time.perf_counter()
+                trace = getattr(fl, "trace", None)  # an engine stand-in has none
+                if trace is not None:
+                    trace.meta["slot"] = slot
                 if inflight is not None:
                     self._finish(*inflight)
                     self._release(slot, inflight[0])
-                inflight = (convoy, fl, t0)
+                inflight = (convoy, fl, t0, held_from)
                 continue
             if inflight is not None:
                 self._finish(*inflight)
@@ -1434,15 +1453,22 @@ class CeremonyScheduler:
                     p.done = True
             self._sign_cond.notify_all()
 
-    def _finish(self, convoy, fl, t0) -> None:
+    def _finish(self, convoy, fl, t0, held_from) -> None:
+        trace = getattr(fl, "trace", None)
+        # the worker started the next convoy, and finished the one
+        # before, between this convoy's dispatch and now: a stage of
+        # this convoy in which nothing ran for it
+        tracing.book_phase(
+            trace, "convoy.hold", time.perf_counter() - held_from
+        )
         try:
             outcomes = self._engine_finish(fl, [p.req for p in convoy])
         except Exception as exc:  # noqa: BLE001 — worker must survive
             self._isolate(convoy, exc, t0)
             return
-        self._finish_outcomes(convoy, outcomes, t0)
+        self._finish_outcomes(convoy, outcomes, t0, trace)
 
-    def _finish_outcomes(self, convoy, outcomes, t0) -> None:
+    def _finish_outcomes(self, convoy, outcomes, t0, trace=None) -> None:
         dt = time.monotonic() - t0
         # per-ceremony attribution: a width-w convoy's wall clock is
         # shared by w ceremonies (the whole-convoy time goes to the
@@ -1450,6 +1476,8 @@ class CeremonyScheduler:
         share = dt / max(1, len(convoy))
         for p, out in zip(convoy, outcomes):
             out.seconds = share
+            out.convoy_width = len(convoy)
+            out.queue_seconds = p.queue_s
             if (
                 p.deadline_at is not None
                 and time.monotonic() > p.deadline_at
@@ -1468,10 +1496,32 @@ class CeremonyScheduler:
                     seconds=share,
                 )
             with self._cond:
-                self._finish_one(out, durable=p.req.durable)
+                self._finish_one(
+                    out, durable=p.req.durable, admitted_at=p.admitted_at
+                )
         self.metrics.observe(
             "service_convoy_seconds", dt, width=str(len(convoy))
         )
+        if self._log is not None and trace is not None:
+            # a worker thread has no ambient recorder (as in the sign
+            # lane), so the convoy's span goes to the scheduler's own:
+            # the stages' seconds as subs, in the order they ran
+            self._log.emit_span(
+                "convoy",
+                ts0=time.time() - dt,
+                mono0=t0,
+                dur_s=dt,
+                subs={
+                    k.removeprefix("convoy."): v
+                    for k, v in trace.timings_s.items()
+                },
+                convoy=trace.meta.get("convoy"),
+                width=len(convoy),
+                bucket=trace.meta.get("bucket"),
+                slot=trace.meta.get("slot"),
+                ceremonies=[p.cid for p in convoy],
+                queue_wait_s=[p.queue_s for p in convoy],
+            )
         # device/host memory watermarks at the convoy boundary (no-op
         # unless runtimeobs is installed; internally throttled)
         runtimeobs.maybe_sample(phase="convoy_finish")
@@ -1508,11 +1558,11 @@ class CeremonyScheduler:
         for half in (convoy[:mid], convoy[mid:]):
             t1 = time.monotonic()
             try:
-                outs = self._run_once(half)
+                outs, trace = self._run_once(half)
             except Exception as e2:  # noqa: BLE001 — isolation must conclude
                 self._isolate(half, e2, t1)
             else:
-                self._finish_outcomes(half, outs, t1)
+                self._finish_outcomes(half, outs, t1, trace)
 
     def _retry_transient(self, convoy, exc, t0):
         """Bounded whole-convoy retry for a transient engine fault.
@@ -1528,7 +1578,7 @@ class CeremonyScheduler:
             )
             time.sleep(self.retry_backoff_s * (2 ** (attempt - 1)))
             try:
-                outs = self._run_once(convoy)
+                outs, trace = self._run_once(convoy)
             except errors.TransientEngineError as e2:
                 last = e2
                 self._emit(
@@ -1542,7 +1592,7 @@ class CeremonyScheduler:
                     error_kind=type(e2).__name__,
                 )
                 return e2
-            self._finish_outcomes(convoy, outs, t0)
+            self._finish_outcomes(convoy, outs, t0, trace)
             return None
         return last
 
@@ -1590,6 +1640,7 @@ class CeremonyScheduler:
         self,
         out: CeremonyOutcome,
         durable: bool = False,
+        admitted_at: float | None = None,
     ) -> None:
         """Record a terminal outcome.  Journal the public outcome for
         durable ceremonies so recovery re-serves instead of re-running.
@@ -1598,19 +1649,28 @@ class CeremonyScheduler:
         if durable and self._journal is not None:
             self._journal.record_done(out)
         with self._cond:
-            self._record(out)
+            self._record(out, admitted_at)
 
-    def _record(self, out: CeremonyOutcome) -> None:
+    def _record(
+        self, out: CeremonyOutcome, admitted_at: float | None = None
+    ) -> None:
         out.completed_at = time.monotonic()
         self._results[out.ceremony_id] = out
         self._status[out.ceremony_id] = out.status
         self.metrics.inc("service_completed_total", status=out.status)
+        # bucket label, not ceremony_id: a server runs unboundedly many
+        # ceremonies and histogram series must stay bounded
+        # (per-ceremony attribution goes through obslog/tracing)
+        bucket = f"{out.bucket_n}x{out.bucket_t}" if out.bucket_n else "none"
         if out.seconds:
-            # bucket label, not ceremony_id: a server runs unboundedly
-            # many ceremonies and histogram series must stay bounded
-            # (per-ceremony attribution goes through obslog/tracing)
             self.metrics.observe(
-                "service_ceremony_seconds", out.seconds,
-                bucket=f"{out.bucket_n}x{out.bucket_t}" if out.bucket_n else "none",
+                "service_ceremony_seconds", out.seconds, bucket=bucket
+            )
+        if admitted_at is not None and out.status == "done":
+            # what a client waits: admission to here, queue and convoy
+            # both (service_ceremony_seconds is the convoy over its width)
+            self.metrics.observe(
+                "service_request_seconds", out.completed_at - admitted_at,
+                bucket=bucket,
             )
         self._cond.notify_all()

@@ -299,12 +299,17 @@ EVENT_SCHEMA: dict[str, dict[str, tuple | None]] = {
     # spans (tracing.phase_span / service scheduler).  The sign lane's
     # ``sign_convoy`` spans annotate the convoy composition: curve,
     # request/message/ceremony counts, proved flag, flush reason, and
-    # how many tickets ended in error.
+    # how many tickets ended in error.  The ceremony lane's ``convoy``
+    # spans name the convoy (sequence number, width, bucket, worker
+    # slot), its members (``ceremonies`` is there the list of their ids,
+    # ``queue_wait_s`` each one's seconds queued, in the same order) and
+    # carry the stage seconds of service/engine.CONVOY_STAGES as subs.
     "span": {
         "required": ("name", "ts0", "mono0", "dur_s"),
         "optional": (
             "subs", "curve", "requests", "messages", "ceremonies",
             "proved", "reason", "errors",
+            "convoy", "width", "bucket", "slot", "queue_wait_s",
         ),
     },
     # open kinds: payload varies by probe/deployment (utils.runtimeobs,

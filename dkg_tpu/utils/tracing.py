@@ -13,7 +13,8 @@ errors are the only signal).  Here observability is first-class:
   (:mod:`~dkg_tpu.utils.metrics`) and, when the calling thread has an
   ambient flight recorder bound (:mod:`~dkg_tpu.utils.obslog`), emits a
   span event carrying the sub-timings accumulated during the phase.
-* :func:`profile_to` — whole-ceremony ``jax.profiler`` capture helper.
+* :func:`book_phase` — what a completed span books (trace entry +
+  histogram observation), for time in which nothing ran to annotate.
 """
 
 from __future__ import annotations
@@ -121,6 +122,17 @@ def _annotation_cls():
     return _ANNOTATION_CLS
 
 
+def book_phase(trace: CeremonyTrace | None, phase: str, seconds: float) -> None:
+    """Book ``seconds`` to ``phase`` as a completed :func:`phase_span`
+    does: an entry of ``trace`` and a ``dkg_phase_seconds`` observation.
+    Called directly for time that is a phase but holds no work to
+    annotate (a dispatched convoy waiting for its worker to come back:
+    ``convoy.hold``, service/scheduler.py)."""
+    if trace is not None:
+        trace.record(phase, seconds)
+    metrics.REGISTRY.observe("dkg_phase_seconds", seconds, phase=phase)
+
+
 @contextlib.contextmanager
 def phase_span(trace: CeremonyTrace | None, phase: str, annotate_device: bool = True):
     """Time a phase; also annotates the device profile when jax has a
@@ -138,9 +150,7 @@ def phase_span(trace: CeremonyTrace | None, phase: str, annotate_device: bool = 
     with ann:
         yield
     dt = time.perf_counter() - t0
-    if trace is not None:
-        trace.record(phase, dt)
-    metrics.REGISTRY.observe("dkg_phase_seconds", dt, phase=phase)
+    book_phase(trace, phase, dt)
     # device/host memory watermark at the phase boundary (no-op unless
     # runtimeobs is installed; internally throttled)
     runtimeobs.maybe_sample(phase=phase)
@@ -154,15 +164,3 @@ def phase_span(trace: CeremonyTrace | None, phase: str, annotate_device: bool = 
                 if v - subs0.get(k, 0.0) > 0
             }
         recorder.emit_span(phase, ts0=ts0, mono0=t0, dur_s=dt, subs=subs or None)
-
-
-@contextlib.contextmanager
-def profile_to(logdir: str):
-    """Capture a jax profiler trace for the enclosed ceremony section."""
-    import jax.profiler
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

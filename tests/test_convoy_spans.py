@@ -1,0 +1,274 @@
+"""Stage spans inside a convoy, the scheduler's queue wait, hold and request
+latency, the flight recorder's `convoy` span, and the benchmark's readers of
+them (ISSUE 25).
+
+One scheduler run on the CPU at the suite's smallest bucket (ristretto255
+(5,2) -> (8,2)): a dozen seeded requests through one worker at convoy widths
+1 and 2, so the worker's two-deep pipeline holds every convoy but the last.
+One file, one module-scoped run: the two widths' programs compile once.
+"""
+
+from __future__ import annotations
+
+import hashlib
+import importlib.util
+import math
+import pathlib
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from dkg_tpu.dkg import ceremony as ce
+from dkg_tpu.service import engine
+from dkg_tpu.service import scheduler as scheduler_mod
+from dkg_tpu.service.scheduler import CeremonyScheduler
+from dkg_tpu.utils import obslog, tracing
+from dkg_tpu.utils.metrics import REGISTRY, MetricsRegistry
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+CURVE, N, T = "ristretto255", 5, 2
+SEEDS = range(100, 112)
+# blake2b-16 over master || final_shares of SEEDS in order, from
+# engine.run_convoy at width 1 on the commit before the spans went in
+GOLDEN = "048d565eb98e260caadc84acafabf664"
+READERS = (
+    "convoy_host_ms", "convoy_device_wait_ms", "convoy_hold_ms",
+    "queue_wait_program_ms", "convoy_hold_ms.steady",
+    "convoy_device_wait_ms.steady",
+)
+# every stage but `blame`, which runs only where a dealer cheated
+HONEST_STAGES = tuple(s for s in engine.CONVOY_STAGES if s != "blame")
+
+
+def _hist(snap: dict, name: str, **labels: str) -> tuple[float, int]:
+    """(sum, count) over every series of histogram `name` carrying `labels`."""
+    total, count = 0.0, 0
+    for series, h in snap["histograms"].items():
+        base, _, rest = series.partition("{")
+        if base == name and all(f'{k}="{v}"' in rest for k, v in labels.items()):
+            total, count = total + h["sum"], count + h["count"]
+    return total, count
+
+
+def _delta(run: dict, name: str, **labels: str) -> tuple[float, int]:
+    b, a = _hist(run["before"], name, **labels), _hist(run["after"], name, **labels)
+    return a[0] - b[0], a[1] - b[1]
+
+
+def _requests():
+    return [engine.CeremonyRequest(CURVE, N, T, seed=s) for s in SEEDS]
+
+
+@pytest.fixture(scope="module")
+def runtime():
+    rt = engine.WarmRuntime()
+    reqs = _requests()
+    engine.run_convoy(rt, reqs[:1])  # compile both widths outside the run
+    engine.run_convoy(rt, reqs[:2])
+    return rt
+
+
+@pytest.fixture(scope="module")
+def run(runtime):
+    """A dozen requests through one worker: the first alone (width 1), the
+    rest queued together behind it (widths 2, and 1 for the odd one)."""
+    reqs = _requests()
+    log = obslog.ObsLog()
+    before = REGISTRY.snapshot()
+    sch = CeremonyScheduler(concurrency=1, batch_max=2, runtime=runtime, log=log)
+    try:
+        first = sch.submit(reqs[0])
+        deadline = time.monotonic() + 60
+        while sch.poll(first) == "queued" and time.monotonic() < deadline:
+            time.sleep(0.002)
+        with sch._cond:  # queued as one: the worker's next pops find pairs
+            rest = [sch.submit(r) for r in reqs[1:]]
+        outs = [sch.result(cid, timeout=300) for cid in [first] + rest]
+    finally:
+        sch.close()
+    return {
+        "outs": outs,
+        "before": before,
+        "after": REGISTRY.snapshot(),
+        "events": log.events(),
+    }
+
+
+def test_every_stage_is_booked_and_encode_counts_the_convoys(run):
+    convoys = _delta(run, "service_convoy_seconds")[1]
+    widths = sorted({o.convoy_width for o in run["outs"]})
+    assert widths == [1, 2] and convoys >= 7
+    for stage in HONEST_STAGES:
+        seconds, count = _delta(run, "dkg_phase_seconds", phase=f"convoy.{stage}")
+        assert count == convoys, stage
+        assert seconds > 0, stage
+    assert _delta(run, "dkg_phase_seconds", phase="convoy.blame") == (0.0, 0)
+    # one worker, two deep: every convoy but the last waits for the one before it
+    held = _delta(run, "dkg_phase_seconds", phase="convoy.hold")[0]
+    assert held > _delta(run, "dkg_phase_seconds", phase="convoy.draw")[0]
+
+
+def test_stages_and_hold_account_for_the_convoys(run):
+    booked = sum(
+        _delta(run, "dkg_phase_seconds", phase=f"convoy.{s}")[0]
+        for s in engine.CONVOY_STAGES
+    )
+    wall = _delta(run, "service_convoy_seconds")[0]
+    assert 0.95 * wall <= booked <= 1.0001 * wall
+
+
+def test_queue_wait_and_latency_once_per_done_request(run):
+    outs = run["outs"]
+    assert all(o.status == "done" for o in outs)
+    wait_s, waits = _delta(run, "service_queue_wait_seconds", bucket="8x2")
+    latency_s, latencies = _delta(run, "service_request_seconds", bucket="8x2")
+    assert waits == latencies == len(outs)
+    assert wait_s == pytest.approx(sum(o.queue_seconds for o in outs))
+    # a request's latency is its queue wait and its whole convoy, not the
+    # convoy over its width (service_ceremony_seconds, unchanged beside it)
+    share_s, shares = _delta(run, "service_ceremony_seconds", bucket="8x2")
+    assert shares == len(outs)
+    assert share_s == pytest.approx(sum(o.seconds for o in outs))
+    assert latency_s >= wait_s + sum(o.seconds * o.convoy_width for o in outs) * 0.95
+    assert outs[0].queue_seconds < outs[-1].queue_seconds
+
+
+def test_one_convoy_span_per_convoy_names_its_members(run):
+    assert obslog.validate_events(run["events"], allow_unknown=True) == []
+    spans = [e for e in run["events"] if e["kind"] == "span" and e["name"] == "convoy"]
+    assert len(spans) == _delta(run, "service_convoy_seconds")[1]
+    by_cid = {o.ceremony_id: o for o in run["outs"]}
+    members = [cid for s in spans for cid in s["ceremonies"]]
+    assert sorted(members) == sorted(by_cid)
+    assert len({s["convoy"] for s in spans}) == len(spans)
+    for s in spans:
+        assert s["width"] == len(s["ceremonies"]) == len(s["queue_wait_s"])
+        assert (s["bucket"], s["slot"]) == ("8x2", 0)
+        assert [by_cid[c].queue_seconds for c in s["ceremonies"]] == s["queue_wait_s"]
+        assert set(HONEST_STAGES) - {"hold"} <= set(s["subs"]) <= set(engine.CONVOY_STAGES)
+        assert 0.95 * s["dur_s"] <= sum(s["subs"].values()) <= 1.0001 * s["dur_s"]
+    assert sum("hold" in s["subs"] for s in spans) == len(spans)
+
+
+def test_no_recorder_no_span(runtime, monkeypatch):
+    monkeypatch.delenv("DKG_TPU_OBSLOG", raising=False)
+    ambient = obslog.ObsLog()
+    with obslog.use(ambient), CeremonyScheduler(concurrency=1, batch_max=1, runtime=runtime) as sch:
+        assert sch._log is None
+        out = sch.result(sch.submit(_requests()[3]), timeout=300)
+    assert out.status == "done" and (out.convoy_width, out.queue_seconds > 0) == (1, True)
+    assert ambient.events() == []  # a worker thread has no ambient recorder either
+
+
+def test_masters_and_shares_bit_for_bit(run, runtime):
+    h = hashlib.blake2b(digest_size=16)
+    for out in run["outs"]:
+        h.update(out.master)
+        h.update(np.ascontiguousarray(out.final_shares).tobytes())
+    assert h.hexdigest() == GOLDEN
+    # the same request alone, at width 1, outside the scheduler
+    (alone,) = engine.run_convoy(runtime, _requests()[5:6])
+    assert run["outs"][5].convoy_width == 2
+    assert alone.master == run["outs"][5].master
+    assert np.array_equal(alone.final_shares, run["outs"][5].final_shares)
+
+
+def test_width_one_rho_is_derive_rho(runtime):
+    fl = engine.start_convoy(runtime, _requests()[:1])
+    a, e, s, r = (np.asarray(x) for x in (fl.a, fl.e, fl.s, fl.r))
+    want = ce.derive_rho(fl.cfg_pad, a[0], e[0], s[0], r[0], 128)
+    trace = tracing.CeremonyTrace()
+    got = engine.derive_rho_convoy(fl.cfg_pad, a, e, s, r, 128, trace)
+    assert got.shape == (1,) + want.shape and np.array_equal(got[0], want)
+    assert list(trace.timings_s) == ["convoy.digest_dispatch", "convoy.digest_wait", "convoy.rho_fold"]
+    assert fl.trace.meta["width"] == 1 and fl.trace.meta["bucket"] == "8x2"
+    assert list(fl.trace.timings_s) == ["convoy.draw", "convoy.deal_dispatch"]
+
+
+def test_an_engine_stand_in_without_a_trace_is_served(monkeypatch):
+    """tests/test_service.py's stand-ins return a dict: no trace, no span, no stage."""
+    monkeypatch.setattr(scheduler_mod, "start_convoy", lambda rt, reqs, ids=None: {"reqs": reqs, "ids": ids})
+    monkeypatch.setattr(
+        scheduler_mod,
+        "finish_convoy",
+        lambda rt, fl: [
+            engine.CeremonyOutcome(ceremony_id=c, status="done", bucket_n=8, bucket_t=2)
+            for c in fl["ids"]
+        ],
+    )
+    log, reg = obslog.ObsLog(), MetricsRegistry()
+    with CeremonyScheduler(concurrency=1, batch_max=1, runtime=object(), log=log, metrics=reg) as sch:
+        out = sch.result(sch.submit(_requests()[0]), timeout=10)
+    assert (out.status, out.convoy_width) == ("done", 1)
+    assert [e for e in log.events() if e["kind"] == "span"] == []
+    hists = reg.snapshot()["histograms"]
+    assert hists['service_request_seconds{bucket="8x2"}']["count"] == 1
+    assert hists['service_queue_wait_seconds{bucket="8x2"}']["count"] == 1
+
+
+def test_book_phase_books_trace_and_registry():
+    trace = tracing.CeremonyTrace()
+    series = 'dkg_phase_seconds{phase="test.book_phase"}'
+    before = REGISTRY.snapshot()["histograms"].get(series, {"count": 0})["count"]
+    tracing.book_phase(trace, "test.book_phase", 0.25)
+    tracing.book_phase(None, "test.book_phase", 0.5)
+    assert trace.timings_s == {"test.book_phase": 0.25}
+    assert REGISTRY.snapshot()["histograms"][series]["count"] == before + 2
+
+
+@pytest.fixture(scope="module")
+def readers():
+    bench = str(ROOT / "benchmark")
+    sys.path.insert(0, bench)  # the readers import their sibling bench_spans
+    try:
+        loaded = {}
+        for name in READERS:
+            spec = importlib.util.spec_from_file_location(
+                f"reader_{name.replace('.', '_')}", ROOT / "benchmark" / "layer_metrics" / f"{name}.py"
+            )
+            loaded[name] = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(loaded[name])
+        yield loaded
+    finally:
+        sys.path.remove(bench)
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_reader_reads_real_snapshots_and_nothing_from_none(run, readers, name):
+    value = readers[name].read({"counters": {"before": run["before"], "after": run["after"]}})
+    assert value is not None and math.isfinite(value) and value > 0
+    empty = MetricsRegistry().snapshot()
+    assert readers[name].read({"counters": {"before": empty, "after": empty}}) is None
+    # a program that never had the series: the same window seen twice adds nothing
+    assert readers[name].read({"counters": {"before": run["after"], "after": run["after"]}}) is None
+
+
+def test_readers_split_the_convoy_without_overlap(run, readers):
+    ctx = {"counters": {"before": run["before"], "after": run["after"]}}
+    convoys = _delta(run, "service_convoy_seconds")[1]
+    parts = sum(readers[n].read(ctx) for n in ("convoy_host_ms", "convoy_device_wait_ms", "convoy_hold_ms"))
+    booked = sum(_delta(run, "dkg_phase_seconds", phase=f"convoy.{s}")[0] for s in engine.CONVOY_STAGES)
+    assert parts == pytest.approx(booked / convoys * 1e3)  # the run drained: every convoy passed every stage
+    assert readers["convoy_hold_ms.steady"].read(ctx) == readers["convoy_hold_ms"].read(ctx)
+    assert readers["convoy_device_wait_ms.steady"].read(ctx) == readers["convoy_device_wait_ms"].read(ctx)
+    wait_s, waits = _delta(run, "service_queue_wait_seconds")
+    assert readers["queue_wait_program_ms"].read(ctx) == pytest.approx(wait_s / waits * 1e3)
+
+
+def test_a_convoy_in_flight_at_the_windows_end_does_not_inflate_the_mean(readers):
+    """Two convoys finished, a third has booked its first stages: a stage's
+    seconds go over the convoys that passed it, not over those that finished."""
+
+    def snap(draw, hold, encode):
+        reg = MetricsRegistry()
+        for seconds, phase in [(s, "convoy.draw") for s in draw] + [(s, "convoy.hold") for s in hold] + [
+            (s, "convoy.encode") for s in encode
+        ]:
+            reg.observe("dkg_phase_seconds", seconds, phase=phase)
+        return reg.snapshot()
+
+    ctx = {"counters": {"before": snap([], [], []), "after": snap([0.010] * 3, [1.0] * 3, [0.020] * 2)}}
+    assert readers["convoy_hold_ms"].read(ctx) == pytest.approx(1000.0)
+    assert readers["convoy_host_ms"].read(ctx) == pytest.approx(30.0)
