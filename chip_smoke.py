@@ -7,6 +7,7 @@ process, on whatever accelerator JAX finds — and fails (non-zero, no
 result line) when that is not a TPU.  No CPU path, no caught phase.
 
     python chip_smoke.py            # one chip: phases `ceremony`, `served`
+    python chip_smoke.py --digest   # one chip: canonicalisation, digest and rho only
     python chip_smoke.py --mesh     # four chips: the sharded ceremony only
 
 * ``ceremony`` — ``BatchedCeremony("secp256k1", n=1024, t=341)`` from a
@@ -24,6 +25,16 @@ result line) when that is not a TPU.  No CPU path, no caught phase.
   (PR 22: 269 s for n=256 t=85), and the whole script must fit 1200 s
   cold — at the ceremony's shape the served leg adds only the sign
   programs.
+* ``--digest`` — the Fiat-Shamir leg alone, which no fetched outcome
+  shows: ``gd.affine_canon`` against its host big-int twin at a width-8
+  (16,5) convoy's two shapes and at two lane counts whose Montgomery
+  scan is 85 and 4 rows long, identity lanes spliced in, limb for limb;
+  the 16-bit table's hash (256 rows); then one such convoy through ``engine.run_convoy`` (masters against
+  the host oracle, ``affine_canon_calls_total`` three up on the fused
+  path) and its transcript digests and rho from the device leg against
+  the host leg, bit for bit.  A minute from a baked executable store
+  (``DKG_TPU_AOT_DIR``); the digests are printed, so two commits run on
+  one seed can be compared.
 * ``--mesh`` — ``run_sharded_ceremony`` on a 4-device mesh against the
   same seeded ``BatchedCeremony`` on device 0: master key, final shares
   and qualified set bit for bit; fails unless every sharded input
@@ -38,6 +49,7 @@ interpret mode where forced on) and never prints that line.
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import math
 import pathlib
@@ -279,6 +291,162 @@ def phase_served(args, dev) -> None:
     _require(len(sigs) == len(msgs) and sigs == want_sigs, "signature differs from secret*H(m)")
 
 
+def projective_batch(cs, shape: tuple, rng) -> "np.ndarray":
+    """(*shape, C, L) limbs: eight real group elements tiled over the
+    lanes, each lane rescaled by its own random nonzero factor (another
+    representative of the same element), an identity (Z = 0) in the
+    first lane and about one lane in seven after it.  The parity test
+    (tests/test_digest_dispatch.py) builds its batches with it too."""
+    import numpy as np
+
+    from dkg_tpu.fields import host as fh
+    from dkg_tpu.groups import device as gd
+    from dkg_tpu.groups import host as gh
+
+    group, p = gh.ALL_GROUPS[cs.name], cs.field.modulus
+    base = [group.scalar_mul(rng.randrange(1, 1 << 64), group.generator()) for _ in range(8)]
+    ints = fh.decode(cs.field, np.asarray(gd.from_host(cs, base)))  # (8, C) Python ints
+    ident = np.asarray(gd.identity(cs))
+    n = math.prod(shape)
+    out = np.empty((n, cs.ncoords, cs.field.limbs), np.uint32)
+    for i in range(n):
+        if i == 0 or rng.randrange(7) == 0:
+            out[i] = ident
+        else:
+            lam = rng.randrange(1, p)
+            out[i] = fh.encode(cs.field, [int(c) * lam % p for c in ints[i % 8]])
+    return out.reshape(tuple(shape) + out.shape[1:])
+
+
+def _canon_counts() -> dict:
+    from dkg_tpu.utils.metrics import REGISTRY
+
+    return {
+        k: v for k, v in REGISTRY.snapshot()["counters"].items() if k.startswith("affine_canon_")
+    }
+
+
+def phase_digest(args, dev) -> None:
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dkg_tpu.dkg import ceremony as ce
+    from dkg_tpu.groups import device as gd
+    from dkg_tpu.groups import host as gh
+    from dkg_tpu.service import CeremonyRequest, WarmRuntime, aot, engine
+
+    cs = gd.ALL_CURVES[CURVE]
+    n, t = args.served_n or 16, args.served_t or 5
+    k = 2 if args.rehearse else 8
+    # the convoy's two shapes (one row: no Montgomery scan), then lane
+    # counts whose scan is neither absent nor the table build's 256 rows:
+    # a four-chip n=1024 t=341 ceremony's shard (85 rows) and
+    # hybrid_batch's n*n KEM points at n=64 (4 rows)
+    scanned = ((2050,),) if args.rehearse else ((256, 342), (64, 64))
+    for shape in ((k * n, t + 1), (k,)) + scanned:
+        pts = projective_batch(cs, shape, random.Random(args.seed + len(shape)))
+        before = _canon_counts()
+        got = np.asarray(gd.affine_canon(cs, jnp.asarray(pts)))
+        booked = {k: v - before.get(k, 0) for k, v in _canon_counts().items() if v != before.get(k, 0)}
+        equal = bool(np.array_equal(got, gd.affine_canon_host(cs, pts)))
+        # the label booked against the program that ran: the compiled
+        # module holds a Mosaic launch exactly when the counter said fused
+        program = gd._affine_canon_jit.lower(cs, gd._canon_path(), jnp.asarray(pts)).compile()
+        kernel_in_program = "tpu_custom_call" in program.as_text()
+        fused_booked = any('path="fused"' in key for key in booked)
+        _emit(
+            {
+                "phase": "canon",
+                "shape": list(pts.shape),
+                "rows": gd._canon_rows(math.prod(shape)),
+                "identity_lanes": int((pts[..., 2, :] == 0).all(axis=-1).sum()),
+                "equals_host_twin": equal,
+                "booked": booked,
+                "kernel_in_program": kernel_in_program,
+            }
+        )
+        _require(equal, f"affine_canon differs from affine_canon_host at {pts.shape}")
+        _require(
+            kernel_in_program == fused_booked,
+            f"affine_canon booked {sorted(booked)} but kernel_in_program={kernel_in_program}",
+        )
+
+    # the table build's lane count: the 256-row Montgomery scan over the
+    # same inversion; canonical limbs are unique, so two commits that
+    # both build the table right print one hash
+    window = 8 if args.rehearse else 16
+    t0 = time.perf_counter()
+    table = np.asarray(gd.fixed_base_table_dev(cs, gh.ALL_GROUPS[CURVE].generator(), window))
+    _emit(
+        {
+            "phase": "table",
+            "window_bits": window,
+            "shape": list(table.shape),
+            "build_s": round(time.perf_counter() - t0, 3),
+            "blake2b": hashlib.blake2b(table.tobytes(), digest_size=16).hexdigest(),
+        }
+    )
+
+    reqs = [
+        CeremonyRequest(CURVE, n, t, shared_string=SHARED, seed=args.seed + 100 + i)
+        for i in range(k)
+    ]
+    runtime = WarmRuntime()
+    engine.run_convoy(runtime, reqs)  # warm: whatever traces or loads does so here
+    before = _canon_counts()
+    t0 = time.perf_counter()
+    outs = engine.run_convoy(runtime, reqs)
+    convoy_s = time.perf_counter() - t0
+    after = _canon_counts()
+    fs = cs.scalar
+    masters = [
+        o.master == _host_pubkey(CURVE, _seeded_secret(fs, n, t, r.seed))
+        for o, r in zip(outs, reqs)
+    ]
+
+    fl = engine.start_convoy(runtime, reqs)
+    cfg = fl.cfg_pad
+    tensors = [np.asarray(x) for x in (fl.a, fl.e, fl.s, fl.r)]
+    flat = [x.reshape((k * cfg.n,) + x.shape[2:]) for x in tensors]
+    legs = {}
+    for leg in ("device", "host"):
+        rows = [
+            np.asarray(x).reshape(k, cfg.n, -1)
+            for x in ce._dealer_rows_device(cfg, *flat, dispatch=leg)
+        ]
+        digests = [ce._fold_digest_device(cfg, *(r[i] for r in rows)) for i in range(k)]
+        rho = np.stack([ce.fiat_shamir_rho(cfg, d, reqs[0].rho_bits) for d in digests])
+        legs[leg] = (digests, rho)
+    served_rho = engine.derive_rho_convoy(cfg, *tensors, reqs[0].rho_bits)
+    same_digest = legs["device"][0] == legs["host"][0]
+    same_rho = bool(
+        np.array_equal(legs["device"][1], legs["host"][1])
+        and np.array_equal(served_rho, legs["host"][1])
+    )
+    _emit(
+        {
+            "phase": "digest",
+            "curve": CURVE,
+            "n": n,
+            "t": t,
+            "width": k,
+            "convoy_s": round(convoy_s, 3),
+            "aot": aot.stats() if aot.enabled() else None,
+            "statuses": [o.status for o in outs],
+            "masters_match_host_oracle": masters,
+            "affine_canon_counters_one_convoy": {
+                key: after[key] - before.get(key, 0) for key in after
+            },
+            "transcript_digests": [d.hex() for d in legs["device"][0]],
+            "device_leg_digests_equal_host_leg": same_digest,
+            "served_rho_equals_host_leg": same_rho,
+        }
+    )
+    _require(all(o.status == "done" for o in outs) and all(masters), "a convoy master is wrong")
+    _require(same_digest, "transcript digest: device leg differs from host leg")
+    _require(same_rho, "rho: device leg differs from host leg")
+
+
 def phase_mesh(args, dev) -> None:
     import jax
     import numpy as np
@@ -387,6 +555,10 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=22)
     ap.add_argument("--mesh", action="store_true", help="four chips: the sharded ceremony only")
     ap.add_argument(
+        "--digest", action="store_true",
+        help="one chip: canonicalisation, transcript digest and rho only, at (16,5) x8",
+    )
+    ap.add_argument(
         "--rehearse", action="store_true",
         help="sandbox rehearsal on the CPU backend; never prints the ok line",
     )
@@ -412,6 +584,8 @@ def main() -> int:
     t0 = time.perf_counter()
     if args.mesh:
         phase_mesh(args, dev)
+    elif args.digest:
+        phase_digest(args, dev)
     else:
         phase_ceremony(args, dev)
         phase_served(args, dev)

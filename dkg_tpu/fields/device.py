@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .spec import FieldSpec
+from .spec import POW_WINDOW, FieldSpec, window_digits
 
 # Plain int, not jnp.uint32: a module-level device constant would
 # initialise the jax backend at import time, defeating hostmesh's
@@ -541,28 +541,43 @@ def square(fs: FieldSpec, a: jax.Array) -> jax.Array:
 
 
 def pow_const(fs: FieldSpec, x: jax.Array, e: int) -> jax.Array:
-    """x**e mod p for a compile-time exponent, via an MSB-first bit scan.
+    """x**e mod p for a compile-time exponent: a fixed-window
+    (``POW_WINDOW`` = 4 bits) left-to-right chain.
 
-    The exponent bits live in a tiny constant array and the square/multiply
-    body is traced once (lax.scan), keeping compile time flat even for
-    255-bit exponents (inverse = x**(p-2), Fermat).
+    x**0 .. x**15 are built once (14 multiplies), then every further
+    digit of ``e`` costs four squarings and one table multiply — about
+    333 dependent multiplies for a 256-bit exponent.  The digits live in
+    a tiny constant array and the digit step is traced once (one
+    ``lax.scan``), so compile time stays flat for any exponent.  Generic
+    over ``e``: the Fermat inverse x**(p-2) of every base field and
+    ristretto's (p-5)/8 root share it.
+
+    Where the fused kernels are active the same chain runs in one
+    Pallas launch, lanes on the lane axis and every multiply in VMEM
+    (``ops.pallas_field.mod_pow_const``); here it runs on ``mul``.
     """
     if e < 0:
         raise ValueError("negative exponent")
     if e == 0:
         return jnp.broadcast_to(ones(fs), x.shape)
-    bits = [int(b) for b in bin(e)[2:]]
-    bits_arr = jnp.asarray(bits, dtype=jnp.uint32)
+    if fused_kernels_active():
+        from ..ops import pallas_field
 
-    def step(acc, bit):
-        acc = mul(fs, acc, acc)
-        acc_mul = mul(fs, acc, x)
-        acc = jnp.where(bit != 0, acc_mul, acc)
-        return acc, None
+        return pallas_field.mod_pow_const(fs, x, e)
+    digits = window_digits(e)
+    powers = [jnp.broadcast_to(ones(fs), x.shape), x]
+    for _ in range(2, max(digits) + 1):
+        powers.append(mul(fs, powers[-1], x))
+    acc = powers[digits[0]]
+    if len(digits) == 1:
+        return acc
+    table = jnp.stack(powers)
 
-    # Seed with 1 so the first iteration computes x**bits[0] uniformly.
-    init = jnp.broadcast_to(ones(fs), x.shape)
-    acc, _ = lax.scan(step, init, bits_arr)
+    def step(acc, digit):
+        acc = lax.fori_loop(0, POW_WINDOW, lambda _, a: mul(fs, a, a), acc)
+        return mul(fs, acc, lax.dynamic_index_in_dim(table, digit, keepdims=False)), None
+
+    acc, _ = lax.scan(step, acc, jnp.asarray(digits[1:], dtype=jnp.int32))
     return acc
 
 
@@ -578,9 +593,15 @@ def batch_inv(fs: FieldSpec, x: jax.Array, axis: int = 0) -> jax.Array:
     Lagrange reconstruction (reference: src/polynomial.rs:162-184) when
     denominators are device-resident.  Zero inputs produce garbage in the
     affected lane only (protocol code never inverts zero).
+
+    The two scans are ``k`` DEPENDENT steps each, so the trick pays only
+    while a step is wide enough to fill the device: the caller picks
+    ``k`` from its lane count (groups.device.affine_canon does), and
+    ``k == 1`` is the plain lane-wide inversion with no scan at all.
     """
     x = jnp.moveaxis(x, axis, 0)
-    k = x.shape[0]
+    if x.shape[0] == 1:
+        return jnp.moveaxis(inv(fs, x), 0, axis)
 
     def fwd(carry, xi):
         nxt = mul(fs, carry, xi)

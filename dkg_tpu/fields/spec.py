@@ -56,6 +56,23 @@ def limbs_to_int(limbs) -> int:
     return acc
 
 
+#: window width of the compile-time-exponent chain (fields.device.pow_const
+#: and its fused twin ops.pallas_field.mod_pow_const): 2**4 table entries
+POW_WINDOW = 4
+
+
+def window_digits(e: int) -> tuple[int, ...]:
+    """Base-``2**POW_WINDOW`` digits of a positive exponent, most
+    significant first — the schedule of the fixed-window chain."""
+    if e <= 0:
+        raise ValueError("window_digits expects a positive exponent")
+    digits = []
+    while e:
+        digits.append(e & ((1 << POW_WINDOW) - 1))
+        e >>= POW_WINDOW
+    return tuple(reversed(digits))
+
+
 @dataclasses.dataclass(frozen=True)
 class FieldSpec:
     """A prime field with its device-representation parameters."""

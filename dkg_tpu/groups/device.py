@@ -857,7 +857,24 @@ def eval_point_poly(
 # ---------------------------------------------------------------------------
 
 
-@_jit_static0
+#: fewest lanes one row of the canonicalisation's Montgomery scan may
+#: hold, and the most rows it takes.  A scan step is one DEPENDENT field
+#: multiply whatever its width, so a row narrower than the device
+#: computes at once buys nothing and costs three serial multiplies: a
+#: convoy's 768 and 8 lanes take ONE row (a plain lane-wide inversion,
+#: no scan), the million-lane table build its 256.  Swept once on a v5e
+#: (PERF.md section 6, PR 26): 1024 is the best of 256 / 1024 / 4096 at
+#: 16 k lanes and within 1.2-1.6 x of the best at 2 k, 87 k and 350 k
+#: (where 4096 wins); one row at every count loses by 5-13 x from 16 k up.
+_CANON_ROW_LANES = 1024
+_CANON_MAX_ROWS = 256
+
+
+def _canon_rows(n_lanes: int) -> int:
+    """Montgomery-scan length of :func:`affine_canon` for a lane count."""
+    return max(1, min(_CANON_MAX_ROWS, n_lanes // _CANON_ROW_LANES))
+
+
 def affine_canon(cs: CurveSpec, pts: jax.Array) -> jax.Array:
     """Canonical (affine, Z=1) limb representation of a point batch:
     (..., C, L) -> (..., C, L) with X/Z, Y/Z (+ T = XY for Edwards);
@@ -871,35 +888,81 @@ def affine_canon(cs: CurveSpec, pts: jax.Array) -> jax.Array:
     breaking cross-platform digest agreement for the same logical
     ceremony.
 
-    One batched Montgomery-trick inversion over all lanes (short scan
-    axis, wide batch — same shape discipline as the table build).
+    One batched inversion over all lanes, as wide as the batch and as
+    short as the exponent allows: the lanes fold into
+    :func:`_canon_rows` Montgomery-trick rows (one row, so no scan at
+    all, below ``2 * _CANON_ROW_LANES`` lanes), and the inversion under
+    them is the windowed ``fd.pow_const`` chain — on the fused field
+    kernel (``ops.pallas_field.mod_pow_const``) where
+    ``fd.fused_kernels_active()``, on ``fd.mul`` elsewhere.  One jitted
+    XLA module per shape, inversion included (``jit_affine_canon``).
+
+    Each eager call books ``affine_canon_calls_total{path, rows}`` and
+    ``affine_canon_lanes_total`` — per dispatch from the host, so a
+    process that finds the program compiled counts like one that traced
+    it; a call from inside another traced function books nothing.  The
+    ``path`` booked is the jitted program's static key, so it names the
+    inversion the compiled program holds and not a switch read later.
     """
+    path = _canon_path()
+    if not isinstance(pts, jax.core.Tracer):
+        from ..utils import metrics  # utils imports dkg, which imports this module
+
+        n_lanes = int(np.prod(pts.shape[:-2], dtype=np.int64))
+        metrics.REGISTRY.inc(
+            "affine_canon_calls_total",
+            path=path,
+            rows="1" if _canon_rows(n_lanes) == 1 else ">1",
+        )
+        metrics.REGISTRY.inc("affine_canon_lanes_total", n_lanes)
+    return _affine_canon_jit(cs, path, pts)
+
+
+def _canon_path() -> str:
+    """Which inversion a trace of :func:`affine_canon` made now would
+    hold: ``fd.pow_const`` resolves it from the environment at trace
+    time, so it is part of the jitted program's key (a process that
+    changes the switches re-traces instead of running the other
+    program) and the label its counter books."""
+    if not fd.fused_kernels_active():
+        return "xla"
+    return "fused" if fd._on_tpu() else "fused_interpret"
+
+
+def _affine_canon_traced(cs: CurveSpec, path: str, pts: jax.Array) -> jax.Array:
+    if path != _canon_path():
+        raise RuntimeError(f"affine_canon keyed {path!r}, traces {_canon_path()!r}")
     f = cs.field
     z = pts[..., 2, :]
     z_is_zero = fd.is_zero(z)
     z_safe = fd.select(z_is_zero, jnp.broadcast_to(fd.ones(f), z.shape), z)
     flat = z_safe.reshape(-1, f.limbs)
     n_lanes = flat.shape[0]
-    pad = (-n_lanes) % 256
+    rows = _canon_rows(n_lanes)
+    pad = (-n_lanes) % rows  # whole rows; the kernel pads a row to its block itself
     if pad:
         flat = jnp.concatenate(
             [flat, jnp.broadcast_to(fd.ones(f), (pad, f.limbs))]
         )
-    rows = 256 if flat.shape[0] >= 256 else 1
     zi = fd.batch_inv(f, flat.reshape(rows, -1, f.limbs), axis=0)
     zi = zi.reshape(-1, f.limbs)[:n_lanes].reshape(z.shape)
-    x_a = fd.mul(f, pts[..., 0, :], zi)
-    y_a = fd.mul(f, pts[..., 1, :], zi)
+    xy = fd.mul(f, pts[..., :2, :], zi[..., None, :])  # X/Z and Y/Z in one pass
+    x_a, y_a = xy[..., 0, :], xy[..., 1, :]
     one = jnp.broadcast_to(fd.ones(f), x_a.shape)
     if cs.kind == "edwards":
-        t_a = fd.mul(f, x_a, y_a)
-        out = jnp.stack([x_a, y_a, one, t_a], axis=-2)
+        out = jnp.stack([x_a, y_a, one, fd.mul(f, x_a, y_a)], axis=-2)
     else:
         out = jnp.stack([x_a, y_a, one], axis=-2)
     ident = identity(cs)
     return jnp.where(
         z_is_zero[..., None, None], jnp.broadcast_to(ident, out.shape), out
     )
+
+
+# the XLA module keeps the public name: device traces and the
+# benchmark's digest_time_share find the program as ``jit_affine_canon``
+_affine_canon_traced.__name__ = "affine_canon"
+_affine_canon_jit = jax.jit(_affine_canon_traced, static_argnums=(0, 1))
 
 
 def _batch_zinv_host(zs: list[int], p: int) -> list[int]:
@@ -971,12 +1034,14 @@ def encode_batch(cs: CurveSpec, pts) -> np.ndarray:
     to ``HostGroup.encode`` of that element (the DEM/KDF input and the
     wire point format).
 
-    ONE batched Montgomery-trick inversion and ONE device->host
-    transfer cover the entire batch — vs the scalar path's per-point
-    ``to_affine`` inversion plus per-dealer ``to_host``.  WHERE the
-    inversion runs follows the backend: on TPU the device
-    :func:`affine_canon` pass (wide lanes are nearly free there); on
-    CPU the same trick over host big-ints — XLA:CPU field muls are
+    ONE batched inversion and ONE device->host transfer cover the
+    entire batch — vs the scalar path's per-point ``to_affine``
+    inversion plus per-dealer ``to_host``.  WHERE the inversion runs
+    follows the backend: on TPU the device :func:`affine_canon` pass
+    (every lane inverted at once on the fused field kernel, so a
+    handful of points and a few thousand cost the device about the
+    same — but each call is a round trip through the device's queue);
+    on CPU the Montgomery trick over host big-ints — XLA:CPU field muls are
     per-op-overhead-bound at DEM batch widths, so the device pass costs
     ~100ms where 256-bit Python modmuls cost ~100ns each (the dealing
     bench regression that motivated the dispatch).  Both legs produce

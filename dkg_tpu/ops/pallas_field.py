@@ -36,7 +36,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..fields.spec import FieldSpec
+from ..fields.spec import POW_WINDOW, FieldSpec, window_digits
 from ..utils import metrics
 
 BLOCK = 128  # lane width: one VPU register row of batch elements
@@ -307,6 +307,88 @@ def _mod_madd_tiles(fs: FieldSpec, a_t, b_t, c_t, interpret: bool):
     )(a_t, b_t, c_t, *extra)
 
 
+def _make_pow_kernel(fs: FieldSpec, digits: tuple[int, ...]):
+    """x**e for the compile-time exponent whose window digits are
+    ``digits`` (fields.spec.window_digits): the fixed-window chain of
+    fields.device.pow_const, VMEM-resident.
+
+    Two multiply bodies are traced whatever the exponent: the table
+    build (x**k = x**(k-1) * x into a VMEM scratch of 2**POW_WINDOW
+    entries) and the digit step's (four squarings and the table
+    multiply share it); every loop is a ``fori_loop`` carrying one
+    (L, BLOCK) array (interpret mode too: its lowering compiles in
+    seconds, unrolled it would be 330 bodies).  The
+    digit of a step is a scalar read from SMEM and selects the table
+    entry by a sublane offset — every lane raises to the same exponent."""
+    L = fs.limbs
+
+    def rows_of(arr):
+        return [arr[i : i + 1, :] for i in range(L)]
+
+    def mul(a, b_rows):
+        return jnp.concatenate(mod_mul_rows(fs, rows_of(a), b_rows), axis=0)
+
+    def kernel(digits_ref, x_ref, *rest):
+        out_ref, tab_ref = rest[-2:]
+
+        def entry(d):
+            return pl.ds(pl.multiple_of(d * L, 8), L)
+
+        x_arr = x_ref[...]
+        x_rows = rows_of(x_arr)
+        one = jnp.ones_like(x_rows[0])
+        tab_ref[0:L, :] = jnp.concatenate([one] + [jnp.zeros_like(one)] * (L - 1), axis=0)
+        tab_ref[L : 2 * L, :] = x_arr
+        with rows_mul_context(fs, rest[:-2]):
+
+            def build(k, prev):  # prev = x**(k-1)
+                nxt = mul(prev, x_rows)
+                tab_ref[entry(k), :] = nxt
+                return nxt
+
+            def step(j, acc):
+                # POW_WINDOW squarings, then the digit's table entry: one
+                # traced body, its second operand selected by the trip
+                ent = tab_ref[entry(digits_ref[j]), :]
+                return jax.lax.fori_loop(
+                    0,
+                    POW_WINDOW + 1,
+                    lambda i, a: mul(a, rows_of(jnp.where(i < POW_WINDOW, a, ent))),
+                    acc,
+                )
+
+            jax.lax.fori_loop(2, max(digits) + 1, build, x_arr)
+            first = digits[0] * L
+            out_ref[...] = jax.lax.fori_loop(
+                1, len(digits), step, tab_ref[first : first + L, :]
+            )
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def _mod_pow_block(fs: FieldSpec, x_blk: jax.Array, digits: tuple, interpret: bool):
+    """(L, BLOCK) -> (L, BLOCK): every lane of ONE block to the power
+    the digits spell.  One block whatever the batch, so that a process
+    traces the kernel's multiply bodies once: the wrapper maps
+    this call over the blocks (``jax.vmap`` turns the map into the
+    launch's grid without re-tracing the kernel), where a grid written
+    here would re-trace them for every new lane count — seconds each
+    on a serving host, in every process, for every convoy width."""
+    spec = pl.BlockSpec((fs.limbs, BLOCK), lambda i: (0, i), memory_space=pltpu.VMEM)
+    extra, extra_specs = mxu_operands(fs, interpret)
+    return pl.pallas_call(
+        _make_pow_kernel(fs, digits),
+        grid=(1,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec] + extra_specs,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(x_blk.shape, jnp.uint32),
+        scratch_shapes=[pltpu.VMEM(((1 << POW_WINDOW) * fs.limbs, BLOCK), jnp.uint32)],
+        interpret=interpret,
+        name="mod_pow_const",
+    )(jnp.asarray(digits, jnp.int32), x_blk, *extra)
+
+
 def _want_interpret() -> bool:
     """Mosaic only exists on real TPU backends; interpret elsewhere."""
     from ..fields import device as fd
@@ -371,3 +453,34 @@ def mod_madd(
     interp = _want_interpret() if interpret is None else interpret
     out_t = _mod_madd_tiles(fs, flat[0].T, flat[1].T, flat[2].T, interp)
     return jnp.reshape(out_t.T[:n], batch + (fs.limbs,))
+
+
+def mod_pow_const(
+    fs: FieldSpec, x: jax.Array, e: int, *, interpret: bool | None = None
+) -> jax.Array:
+    """Batched x**e mod p for a compile-time exponent e > 0 in ONE
+    kernel launch: the fused twin of ``fields.device.pow_const`` (which
+    dispatches here where the fused kernels are active) — the Fermat
+    inversion under ``groups.device.affine_canon``.
+
+    x: (..., L) uint32 limb arrays; the batch is flattened, padded to a
+    BLOCK multiple and cut into (L, BLOCK) tiles, lanes on the lane
+    axis, one grid step a tile; the whole windowed chain (about 333
+    dependent multiplies for a 256-bit e) runs without leaving VMEM.
+    """
+    metrics.REGISTRY.inc("pallas_calls_total", kernel="mod_pow_const")
+    x = jnp.asarray(x, jnp.uint32)
+    batch = x.shape[:-1]
+    n = 1
+    for d in batch:
+        n *= int(d)
+    m = max(BLOCK, ((n + BLOCK - 1) // BLOCK) * BLOCK)
+    xf = jnp.reshape(x, (n, fs.limbs))
+    if m != n:
+        xf = jnp.pad(xf, [(0, m - n), (0, 0)])
+    interp = _want_interpret() if interpret is None else interpret
+    digits = window_digits(e)
+    blocks = jnp.swapaxes(jnp.reshape(xf, (m // BLOCK, BLOCK, fs.limbs)), 1, 2)
+    out = jax.vmap(lambda blk: _mod_pow_block(fs, blk, digits, interp))(blocks)
+    out = jnp.reshape(jnp.swapaxes(out, 1, 2), (m, fs.limbs))
+    return jnp.reshape(out[:n], batch + (fs.limbs,))

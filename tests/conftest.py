@@ -36,3 +36,22 @@ if (
     from dkg_tpu.utils import compilecache
 
     compilecache.enable()
+
+
+import pytest  # noqa: E402 — after the backend forcing above
+
+
+@pytest.fixture(scope="module")
+def free_compiled_programs():
+    """Drop every compiled program when the requesting file is done
+    (``pytestmark = pytest.mark.usefixtures("free_compiled_programs")``).
+    XLA:CPU maps a few memory regions per executable and the jit caches
+    keep them all; the files whose every case compiles its own programs
+    (test_fields.py alone leaves 16 k mappings) took the one-process
+    tier-1 run over ``vm.max_map_count`` (65530) two thirds through,
+    where it died inside a compile (PR 26).  The clear is process-wide:
+    what earlier files of the process compiled goes too."""
+    yield
+    import jax
+
+    jax.clear_caches()

@@ -165,6 +165,9 @@ def test_encode_batch_matches_host_encode_both_dispatches(curve, monkeypatch):
 
     monkeypatch.setattr(fd, "_on_tpu", lambda: False)
     host_leg = gd.encode_batch(cs, dev)
+    # the device leg, on this CPU: steer the leg, not the kernels (a
+    # pretended TPU would send affine_canon's inversion to Mosaic)
+    monkeypatch.setenv("DKG_TPU_PALLAS", "0")
     monkeypatch.setattr(fd, "_on_tpu", lambda: True)
     device_leg = gd.encode_batch(cs, dev)
     for i, w in enumerate(want):

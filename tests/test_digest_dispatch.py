@@ -18,11 +18,15 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from chip_smoke import projective_batch  # the on-chip proof's batches, at the repo's root
 from dkg_tpu.crypto import device_hash as dh
 from dkg_tpu.dkg import ceremony as ce
 from dkg_tpu.fields import host as fh
 
 RNG = random.Random(0xD15B)
+
+# every case compiles its own programs: tests/conftest.py says why they go
+pytestmark = pytest.mark.usefixtures("free_compiled_programs")
 
 # --- goldens from the pre-rewrite implementation (BatchedCeremony(
 # curve, n=4, t=1, b"golden", random.Random(0xD16)), deal_chunked,
@@ -185,21 +189,80 @@ def test_fiat_shamir_rho_golden_128():
 # --- host canonicalisation twin ---------------------------------------
 
 
-def test_affine_canon_host_matches_device():
-    """The host digest leg's big-int canonicalisation agrees limb-for-
-    limb with the jitted device one (identity lanes included)."""
+# the shapes that run: a convoy's master keys (8), one kernel block
+# (128), a width-8 (16,5) convoy's commitment tensor (128, 6) = 768
+# lanes, just past one row of the Montgomery scan (1030: still one row),
+# and the first lane count that scans two rows
+@pytest.mark.parametrize(
+    "shape", [(1,), (5,), (8,), (128,), (128, 6), (1030,), (2050,)], ids=str
+)
+@pytest.mark.parametrize("curve", ["secp256k1", "ristretto255", "bls12_381_g1"])
+def test_affine_canon_host_matches_device(curve, shape):
+    """The host digest leg's big-int canonicalisation agrees limb for
+    limb with the jitted device one at every lane count the row rule
+    tells apart, on 16- and 24-limb fields, identity lanes included
+    (canon must map them to the canonical identity encoding, not divide
+    by zero)."""
+    from dkg_tpu.groups import device as gd
+    from dkg_tpu.utils.metrics import REGISTRY
+
+    cs = gd.ALL_CURVES[curve]
+    n_lanes = int(np.prod(shape))
+    rows = gd._canon_rows(n_lanes)
+    assert rows == (2 if n_lanes == 2050 else 1)
+    pts = projective_batch(cs, shape, random.Random(0xCA9 + n_lanes))
+    series = 'affine_canon_calls_total{path="xla",rows="%s"}' % ("1" if rows == 1 else ">1")
+    before = REGISTRY.snapshot()["counters"]
+    dev = np.asarray(gd.affine_canon(cs, jnp.asarray(pts)))
+    after = REGISTRY.snapshot()["counters"]
+    host = gd.affine_canon_host(cs, pts)
+    np.testing.assert_array_equal(dev, host)
+    np.testing.assert_array_equal(dev.reshape(-1, *dev.shape[-2:])[0], gd.identity(cs))
+    # booked per dispatch from the host, never at trace time only
+    assert after[series] - before.get(series, 0) == 1
+    assert (
+        after["affine_canon_lanes_total"] - before.get("affine_canon_lanes_total", 0)
+        == n_lanes
+    )
+
+
+def test_affine_canon_is_one_module_named_for_the_trace():
+    """The inversion stays inside the one jitted module whose name the
+    device trace (and the benchmark's digest_time_share) matches."""
     from dkg_tpu.groups import device as gd
 
-    for curve in ("secp256k1", "ristretto255"):
-        cs = ce.CeremonyConfig(curve, 2, 1).cs
-        g = gd.generator(cs, (4,))
-        k = jnp.asarray(
-            fh.encode(cs.scalar, [3, 7, 1, 12345678901234567]), jnp.uint32
-        )
-        pts = gd.scalar_mul(cs, k, g)
-        # splice in an identity lane (zero Z) — canon must map it to the
-        # canonical identity encoding, not divide by zero
-        pts = jnp.concatenate([pts, gd.identity(cs, (1,))], axis=0)
-        dev = np.asarray(gd.affine_canon(cs, pts))
-        host = gd.affine_canon_host(cs, np.asarray(pts))
-        np.testing.assert_array_equal(dev, host, err_msg=curve)
+    cs = gd.SECP256K1
+    spec = jax.ShapeDtypeStruct((8, cs.ncoords, cs.field.limbs), jnp.uint32)
+    assert "module @jit_affine_canon " in gd._affine_canon_jit.lower(cs, "xla", spec).as_text()
+
+
+def test_affine_canon_path_keys_the_compiled_program(monkeypatch):
+    """The inversion is picked from the environment when the program is
+    traced, so the path is a static key of the jitted program: one shape
+    called under the other switch traces again (the kernel's wrapper is
+    entered) and books the path it runs, where a key without it would
+    run the cached program under the other label."""
+    from dkg_tpu.groups import device as gd
+    from dkg_tpu.utils.metrics import REGISTRY
+
+    cs = gd.SECP256K1
+    pts = projective_batch(cs, (3,), random.Random(0xCA9))
+    monkeypatch.delenv("DKG_TPU_ASSUME_BACKEND", raising=False)
+    monkeypatch.setenv("DKG_TPU_PALLAS", "0")
+    on_xla = np.asarray(gd.affine_canon(cs, jnp.asarray(pts)))
+    monkeypatch.setenv("DKG_TPU_PALLAS", "1")
+    before = REGISTRY.snapshot()["counters"]
+    on_kernel = np.asarray(gd.affine_canon(cs, jnp.asarray(pts)))
+    after = REGISTRY.snapshot()["counters"]
+    np.testing.assert_array_equal(on_xla, gd.affine_canon_host(cs, pts))
+    np.testing.assert_array_equal(on_kernel, on_xla)
+    rose = {
+        k: v - before.get(k, 0)
+        for k, v in after.items()
+        if k.startswith(("affine_canon_", "pallas_calls_total")) and v != before.get(k, 0)
+    }
+    assert rose == {
+        'affine_canon_calls_total{path="fused_interpret",rows="1"}': 1,
+        "affine_canon_lanes_total": 3,
+        'pallas_calls_total{kernel="mod_pow_const"}': 1,
+    }

@@ -24,6 +24,9 @@ from dkg_tpu.fields import (
 
 RNG = random.Random(0xD1C6)
 
+# every case compiles its own programs: tests/conftest.py says why they go
+pytestmark = pytest.mark.usefixtures("free_compiled_programs")
+
 FIELDS = list(ALL_FIELDS.values())
 FIELD_IDS = [fs.name for fs in FIELDS]
 
@@ -79,6 +82,64 @@ def test_pow_inv(fs):
     for i, v in enumerate(a):
         assert int(got_pow[i]) == pow(v, e, fs.modulus)
         assert int(got_inv[i]) == fh.inv(fs, v)
+
+
+_EXPONENTS = {
+    "0": lambda p: 0,
+    "1": lambda p: 1,
+    "2": lambda p: 2,
+    "15": lambda p: 15,
+    "16": lambda p: 16,
+    "17": lambda p: 17,
+    "p-2": lambda p: p - 2,
+    "(p-5)//8": lambda p: (p - 5) // 8,
+}
+
+
+@pytest.mark.parametrize("exp", list(_EXPONENTS))
+@pytest.mark.parametrize("fs", FIELDS, ids=FIELD_IDS)
+def test_pow_const_window_chain_matches_python_pow(fs, exp):
+    """The fixed-window chain at the edges of its schedule: no digit, one
+    digit below / at / above the table's end, a digit boundary (16, 17),
+    and the two exponents the program uses (the Fermat inverse of every
+    field, ristretto's square-root exponent)."""
+    e = _EXPONENTS[exp](fs.modulus)
+    a = sample(fs, 8)
+    got = fh.decode(fs, np.asarray(fd.pow_const(fs, jnp.asarray(fh.encode(fs, a)), e)))
+    assert [int(v) for v in got] == [pow(v, e, fs.modulus) for v in a]
+
+
+@pytest.mark.parametrize(
+    "name",
+    [
+        "secp256k1_base",  # ~17 s of interpret-mode compile: the cells' field stays in tier 1
+        pytest.param("ed25519_base", marks=pytest.mark.slow),  # ~17 s
+        pytest.param("bls12_381_base", marks=pytest.mark.slow),  # ~5 min at 24 limbs
+    ],
+)
+def test_fused_pow_kernel_matches_inv(name):
+    """ops.pallas_field.mod_pow_const (the kernel fd.pow_const dispatches
+    to where the fused kernels are active), in interpret mode at one
+    128-lane block, against the XLA chain and the host oracle."""
+    from dkg_tpu.ops import pallas_field as pf
+
+    fs = ALL_FIELDS[name]
+    a = sample(fs, pf.BLOCK)
+    da = jnp.asarray(fh.encode(fs, a))
+    got = np.asarray(pf.mod_pow_const(fs, da, fs.modulus - 2, interpret=True))
+    np.testing.assert_array_equal(got, np.asarray(fd.inv(fs, da)))
+    assert [int(v) for v in fh.decode(fs, got)] == [fh.inv(fs, v) if v else 0 for v in a]
+
+
+def test_batch_inv_one_row_is_the_plain_inversion():
+    """k == 1 along the scan axis takes no prefix or suffix scan and
+    gives the same values as the Montgomery trick over k == 15."""
+    fs = P25519
+    a = [v for v in sample(fs, 16) if v != 0]
+    da = jnp.asarray(fh.encode(fs, a))
+    got = np.asarray(fd.batch_inv(fs, da[None], axis=0))
+    np.testing.assert_array_equal(got[0], np.asarray(fd.batch_inv(fs, da, axis=0)))
+    np.testing.assert_array_equal(got[0], np.asarray(fd.inv(fs, da)))
 
 
 def test_batch_inv_matches_scalar_inv():

@@ -41,6 +41,9 @@ CURVE = "ristretto255"
 
 
 def test_install_gating(monkeypatch):
+    # an earlier file of this process may have left the listeners on (the
+    # serial tier-1 order does: this test failed there at PR 25 too)
+    runtimeobs.uninstall()
     try:
         # unset: implicit installers (the scheduler) stay off
         monkeypatch.delenv("DKG_TPU_RUNTIMEOBS", raising=False)
@@ -403,8 +406,12 @@ def test_scheduler_serves_scrape_surface(fake_engine, monkeypatch):
 def test_scheduler_http_off_by_default(fake_engine, monkeypatch):
     monkeypatch.delenv("DKG_TPU_RUNTIMEOBS", raising=False)
     monkeypatch.delenv("DKG_TPU_SERVICE_HTTP_PORT", raising=False)
+    # its own registry: the process-wide one carries whatever failures
+    # the files this worker ran before it recorded on purpose, and the
+    # SLO's error budget would judge those
     sch = CeremonyScheduler(
-        concurrency=1, queue_depth=4, batch_max=1, runtime=object()
+        concurrency=1, queue_depth=4, batch_max=1, runtime=object(),
+        metrics=MetricsRegistry(),
     )
     try:
         assert sch._http is None

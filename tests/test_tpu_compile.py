@@ -67,6 +67,9 @@ def _kernel(name: str, cs: gd.CurveSpec):
         "mod_mul": (lambda a, b: pf.mod_mul(fs, a, b, interpret=False), [elem] * 2),
         "mod_madd": (lambda a, b, c: pf.mod_madd(fs, a, b, c, interpret=False), [elem] * 3),
         "mxu_mod_mul": (lambda a, b: pm.mxu_mod_mul(fs, a, b, interpret=False), [elem] * 2),
+        "mod_pow_const": (
+            lambda a: pf.mod_pow_const(fs, a, fs.modulus - 2, interpret=False), [elem],
+        ),
         "pt_add": (lambda p, q: pp.pt_add(cs, p, q, interpret=False), [point] * 2),
         "pt_madd": (lambda p, q: pp.pt_madd(cs, p, q, interpret=False), [point] * 2),
         "pt_double": (lambda p: pp.pt_double(cs, p, interpret=False), [point]),
@@ -100,6 +103,8 @@ def _compiles_to_a_tpu_kernel(one_chip, curve: str, name: str) -> None:
         ("secp256k1", "pt_double"),
         ("secp256k1", "bucket_accumulate"),
         ("ristretto255", "pt_add"),
+        ("secp256k1", "mod_pow_const"),
+        ("ristretto255", "mod_pow_const"),
     ],
 )
 def test_kernel_compiles_for_v5e(one_chip, curve, name, monkeypatch):
@@ -113,3 +118,32 @@ def test_kernel_compiles_for_v5e(one_chip, curve, name, monkeypatch):
 def test_multi_op_kernel_compiles_for_v5e(one_chip, name, monkeypatch):
     monkeypatch.delenv("DKG_TPU_MUL", raising=False)
     _compiles_to_a_tpu_kernel(one_chip, "secp256k1", name)
+
+
+@pytest.mark.parametrize("shape", [(128, 6, 3, 16), (8, 3, 16)], ids=str)
+def test_affine_canon_lowers_to_the_kernel_for_v5e(one_chip, shape, monkeypatch):
+    """The digest leg's canonicalisation at a width-8 (16,5) convoy's two
+    shapes (the commitment tensors, the master keys), as the chip traces
+    it: one module, the inversion one Mosaic launch (one row, so no
+    Montgomery scan, and the window chain loops inside the kernel), and
+    no XLA ``while`` but the carry ripples of the closing X/Z, Y/Z
+    multiply.  The trace-time dispatch is steered here, in the test:
+    this process's backend is the CPU.  The steered path is part of the
+    jitted program's key, so this trace and the eager CPU ones of the
+    parity tests never answer for each other, in either order."""
+    from dkg_tpu.fields import device as fd
+
+    monkeypatch.setenv("DKG_TPU_ASSUME_BACKEND", "tpu")
+    monkeypatch.delenv("DKG_TPU_PALLAS", raising=False)
+    monkeypatch.delenv("DKG_TPU_MUL", raising=False)
+    cs = gd.ALL_CURVES["secp256k1"]
+    spec = jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
+    assert gd._canon_path() == "fused"
+    text = gd._affine_canon_jit.lower(cs, "fused", spec).compile().as_text()
+    assert "jit_affine_canon" in text
+    assert text.count("tpu_custom_call") == 1
+
+    xy = jax.ShapeDtypeStruct(shape[:-2] + (2, 16), jnp.uint32, sharding=one_chip)
+    zi = jax.ShapeDtypeStruct(shape[:-2] + (1, 16), jnp.uint32, sharding=one_chip)
+    closing = jax.jit(lambda a, b: fd.mul(cs.field, a, b)).lower(xy, zi).compile().as_text()
+    assert text.count(" while(") == closing.count(" while(") > 0
