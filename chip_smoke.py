@@ -6,25 +6,30 @@ Drives the main path through the entry points a user calls, in ONE
 process, on whatever accelerator JAX finds — and fails (non-zero, no
 result line) when that is not a TPU.  No CPU path, no caught phase.
 
-    python chip_smoke.py            # one chip: phases `ceremony`, `served`
+    python chip_smoke.py            # one chip: phases `served`, `ceremony`
     python chip_smoke.py --digest   # one chip: canonicalisation, digest and rho only
     python chip_smoke.py --mesh     # four chips: the sharded ceremony only
 
-* ``ceremony`` — ``BatchedCeremony("secp256k1", n=1024, t=341)`` from a
-  fixed seed (BASELINE.json config 3), run cold then warm; every batch
-  check passes, no complaints, and the master key equals the host
-  oracle (sum of the seeded constant coefficients times the generator,
-  big-int arithmetic in groups/host.py) bit for bit.
 * ``served`` — an in-process ``CeremonyScheduler`` over one
   ``WarmRuntime`` (examples/serve.py's shape, one worker): three seeded
   requests submitted, polled, fetched; each master compared with
   ``engine.run_single_reference`` and the host oracle; then
   ``service.sign`` (proved, the default) over four messages against
-  ``secret * H(m)``.  The requests use the ceremony's own (n, t): a
-  first call of any new shape costs minutes of tracing and compiling
-  (PR 22: 269 s for n=256 t=85), and the whole script must fit 1200 s
-  cold — at the ceremony's shape the served leg adds only the sign
-  programs.
+  ``secret * H(m)``.  Served as a deployment serves, through the
+  executable store (``DKG_TPU_AOT_DIR``, by default beside the compile
+  cache), and FIRST, so that this process builds the four programs
+  there: the phase's line carries ``setup_split_s``, each stored
+  program's build seconds by stage (trace, lower, compile, serialize),
+  the loads, and the digest leg's first call.  The requests use the
+  ceremony's own (n, t): a first call of any new shape costs a build,
+  and the whole script must fit 1200 s cold.
+* ``ceremony`` — ``BatchedCeremony("secp256k1", n=1024, t=341)`` from a
+  fixed seed (BASELINE.json config 3), run twice; every batch check
+  passes, no complaints, and the master key equals the host oracle
+  (sum of the seeded constant coefficients times the generator, big-int
+  arithmetic in groups/host.py) bit for bit.  Its programs are the
+  served phase's, already traced: ``first_call_s`` no longer holds
+  their build (until PR 27 it did: 540 s), ``warm_s`` is what it was.
 * ``--digest`` — the Fiat-Shamir leg alone, which no fetched outcome
   shows: ``gd.affine_canon`` against its host big-int twin at a width-8
   (16,5) convoy's two shapes and at two lane counts whose Montgomery
@@ -52,6 +57,7 @@ import argparse
 import hashlib
 import json
 import math
+import os
 import pathlib
 import random
 import sys
@@ -204,12 +210,30 @@ def phase_ceremony(args, dev) -> None:
     _require(got == want and got_warm == want, "master key differs from the host oracle")
 
 
+def _setup_split() -> dict:
+    """What the served path's set-up cost so far, from the program's own
+    series: each stored program's build by stage, the loads, and the digest
+    leg's first call per shape (seconds; docs/observability.md)."""
+    from dkg_tpu.utils.metrics import REGISTRY
+
+    split: dict = {}
+    for series, h in sorted(REGISTRY.snapshot()["histograms"].items()):
+        name, _, labels = series.partition("{")
+        if name in ("aot_build_stage_seconds", "aot_load_seconds", "digest_leg_first_call_seconds"):
+            split.setdefault(name, {})[labels.rstrip("}") or "all"] = round(h["sum"], 3)
+    return split
+
+
 def phase_served(args, dev) -> None:
     from dkg_tpu.groups import device as gd
     from dkg_tpu.groups import host as gh
-    from dkg_tpu.service import CeremonyRequest, CeremonyScheduler, WarmRuntime, engine
+    from dkg_tpu.service import CeremonyRequest, CeremonyScheduler, WarmRuntime, aot, engine
     from dkg_tpu.sign.hash2curve import hash_to_curve_host
     from dkg_tpu.utils import runtimeobs
+
+    # served as a deployment serves: through the executable store, beside
+    # the compile cache unless the operator has put it elsewhere
+    os.environ.setdefault("DKG_TPU_AOT_DIR", aot.cache_dir())
 
     n, t = args.served_n or args.n, args.served_t or args.t
     group = gh.ALL_GROUPS[CURVE]
@@ -277,6 +301,8 @@ def phase_served(args, dev) -> None:
             "compile_sign": _compile_delta(snap1, snap2),
             "compile_reference": _compile_delta(snap2, snap3),
             "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+            "aot": aot.stats(),
+            "setup_split_s": _setup_split(),
             "masters_match_reference": masters_ref,
             "masters_match_host_oracle": masters_host,
             "signatures_match_host_oracle": [g == w for g, w in zip(sigs, want_sigs)],
@@ -587,8 +613,8 @@ def main() -> int:
     elif args.digest:
         phase_digest(args, dev)
     else:
-        phase_ceremony(args, dev)
         phase_served(args, dev)
+        phase_ceremony(args, dev)
     _emit({"phase": "end", "total_s": round(time.perf_counter() - t0, 3)})
     if args.rehearse:
         _emit({"rehearsal": True, "device": device})

@@ -47,6 +47,7 @@ from ..fields import host as fh
 from ..groups import device as gd
 from ..groups import host as gh
 from ..poly import device as pdev
+from ..utils.metrics import REGISTRY
 
 
 @dataclasses.dataclass(frozen=True)
@@ -573,6 +574,11 @@ def _fold_digest_device(cfg: CeremonyConfig, rows_a, rows_e, rows_sr) -> bytes:
     return h.digest()
 
 
+#: (curve, "<dealers>x<t+1>") shapes whose device digest leg this process
+#: has already traced (``digest_leg_first_call_seconds``).
+_DIGEST_LEG_SEEN: set = set()
+
+
 def _dealer_rows_device(cfg: CeremonyConfig, a_comm, e_comm, shares, hidings,
                         dispatch: str | None = None):
     """Per-dealer BLAKE2s row digests of all four round-1 tensors:
@@ -596,6 +602,15 @@ def _dealer_rows_device(cfg: CeremonyConfig, a_comm, e_comm, shares, hidings,
     if dispatch is None:
         dispatch = dh.digest_dispatch()
     k = shares.shape[0]
+    shape = "x".join(str(d) for d in np.shape(e_comm)[:2])
+    first = dispatch != "host" and (cfg.curve, shape) not in _DIGEST_LEG_SEEN
+    if first:
+        # the device leg is jitted outside the executable store: a
+        # process's first call at a shape traces and compiles it, and the
+        # dispatches below return only then.  Booked once per shape so
+        # that set-up can say what the leg cost it.
+        _DIGEST_LEG_SEEN.add((cfg.curve, shape))
+        t0 = time.perf_counter()
     # Commitments are digested in CANONICAL affine form: projective Z
     # scale depends on the addition schedule (platform/flags), and rho
     # must be a function of the logical transcript, not of which kernel
@@ -623,6 +638,13 @@ def _dealer_rows_device(cfg: CeremonyConfig, a_comm, e_comm, shares, hidings,
     rows_a = dh.row_digests(a_canon.reshape(k, -1), domain=1, dispatch=dispatch)
     rows_e = dh.row_digests(e_canon.reshape(k, -1), domain=2, dispatch=dispatch)
     rows_sr = dh.row_digests(sr, domain=3, dispatch=dispatch)
+    if first:
+        REGISTRY.observe(
+            "digest_leg_first_call_seconds",
+            time.perf_counter() - t0,
+            curve=cfg.curve,
+            shape=shape,
+        )
     return rows_a, rows_e, rows_sr
 
 

@@ -182,10 +182,31 @@ def mod_mul_rows(fs: FieldSpec, rows_a, rows_b):
     """
     for cfs, foldm_t, q2 in reversed(_MXU_CONSTS):
         if cfs is fs:
-            from .pallas_mxu import mxu_mul_rows
+            return _rows_core(fs, "mxu")(list(rows_a), list(rows_b), foldm_t, q2)
+    return _rows_core(fs, "barrett")(list(rows_a), list(rows_b))
 
-            return mxu_mul_rows(fs, rows_a, rows_b, foldm_t=foldm_t, q2=q2)
-    return _barrett_mul_rows(fs, rows_a, rows_b)
+
+@functools.lru_cache(maxsize=None)
+def _rows_core(fs: FieldSpec, core: str):
+    """One multiply core of ``fs`` as a jitted function of its limb rows.
+
+    A point kernel chains 10-60 multiplies and each one is a thousand
+    small operations to trace: written inline, tracing the kernels'
+    bodies was most of a program's build (a (1024,341) verify: 114
+    multiplies, 60 of the 70 s its trace took in the sandbox, several
+    times that on a serving host).  Behind a ``jit`` a process traces
+    the core once per field and row width, and every further multiply
+    is a call of the cached jaxpr, which Mosaic's lowering inlines: the
+    kernel it builds is the same."""
+    if core == "mxu":
+        from .pallas_mxu import mxu_mul_rows
+
+        return jax.jit(
+            lambda rows_a, rows_b, foldm_t, q2: mxu_mul_rows(
+                fs, rows_a, rows_b, foldm_t=foldm_t, q2=q2
+            )
+        )
+    return jax.jit(lambda rows_a, rows_b: _barrett_mul_rows(fs, rows_a, rows_b))
 
 
 def _barrett_mul_rows(fs: FieldSpec, rows_a, rows_b):

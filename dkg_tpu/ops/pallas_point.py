@@ -241,11 +241,33 @@ def _rows_out(ref, rows, L: int):
             ref[c * L + i : c * L + i + 1, :] = rows[c][i]
 
 
-def _point_spec(cs: CurveSpec):
-    L = cs.field.limbs
-    return pl.BlockSpec(
-        (cs.ncoords * L, BLOCK), lambda i: (0, i), memory_space=pltpu.VMEM
-    )
+def _block_spec(rows: int):
+    return pl.BlockSpec((rows, BLOCK), lambda i: (0, i), memory_space=pltpu.VMEM)
+
+
+def _block_call(cs: CurveSpec, name: str, kernel, operands, rows_in, interpret: bool):
+    """One launch of ``kernel`` over ONE lane block: ``operands`` are
+    (rows, BLOCK) arrays, the result a (C·L, BLOCK) point block.
+
+    Every kernel below is written for one block inside its own ``jit``
+    and ``jax.vmap``ped over a batch's blocks by its wrapper, the form
+    ``pallas_field.mod_pow_const`` introduced: the batching rule turns the
+    map into the launch's grid from the cached jaxpr, so a process
+    traces a kernel's multiply bodies ONCE per (curve, kernel).  With
+    the grid written here they were traced again for every batch size
+    a program holds — a (1024,341) verify has 23 ``pt_add`` sizes, 5 s
+    each in the sandbox and more on a serving host."""
+    out_rows = cs.ncoords * cs.field.limbs
+    extra, extra_specs = mxu_operands(cs.field, interpret)
+    return pl.pallas_call(
+        kernel,
+        grid=(1,),
+        in_specs=[_block_spec(r) for r in rows_in] + extra_specs,
+        out_specs=_block_spec(out_rows),
+        out_shape=jax.ShapeDtypeStruct((out_rows, BLOCK), jnp.uint32),
+        interpret=interpret,
+        name=name,
+    )(*operands, *extra)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
@@ -258,17 +280,7 @@ def _add_call(cs: CurveSpec, p_t: jax.Array, q_t: jax.Array, interpret: bool):
                 rest[-1], _add_rows(cs, _rows_in(p_ref, L, C), _rows_in(q_ref, L, C)), L
             )
 
-    B = p_t.shape[-1]
-    spec = _point_spec(cs)
-    extra, extra_specs = mxu_operands(cs.field, interpret)
-    return pl.pallas_call(
-        kernel,
-        grid=(B // BLOCK,),
-        in_specs=[spec, spec] + extra_specs,
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((C * L, B), jnp.uint32),
-        interpret=interpret,
-    )(p_t, q_t, *extra)
+    return _block_call(cs, "pt_add", kernel, (p_t, q_t), (C * L, C * L), interpret)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
@@ -281,17 +293,7 @@ def _madd_call(cs: CurveSpec, p_t: jax.Array, q_t: jax.Array, interpret: bool):
                 rest[-1], _madd_rows(cs, _rows_in(p_ref, L, C), _rows_in(q_ref, L, C)), L
             )
 
-    B = p_t.shape[-1]
-    spec = _point_spec(cs)
-    extra, extra_specs = mxu_operands(cs.field, interpret)
-    return pl.pallas_call(
-        kernel,
-        grid=(B // BLOCK,),
-        in_specs=[spec, spec] + extra_specs,
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((C * L, B), jnp.uint32),
-        interpret=interpret,
-    )(p_t, q_t, *extra)
+    return _block_call(cs, "pt_madd", kernel, (p_t, q_t), (C * L, C * L), interpret)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2, 3))
@@ -305,17 +307,7 @@ def _double_call(cs: CurveSpec, p_t: jax.Array, n_doubles: int, interpret: bool)
                 rows = _double_rows(cs, rows)
             _rows_out(rest[-1], rows, L)
 
-    B = p_t.shape[-1]
-    spec = _point_spec(cs)
-    extra, extra_specs = mxu_operands(cs.field, interpret)
-    return pl.pallas_call(
-        kernel,
-        grid=(B // BLOCK,),
-        in_specs=[spec] + extra_specs,
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((C * L, B), jnp.uint32),
-        interpret=interpret,
-    )(p_t, *extra)
+    return _block_call(cs, "pt_double", kernel, (p_t,), (C * L,), interpret)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2, 3))
@@ -333,17 +325,9 @@ def _window_call(cs: CurveSpec, acc_t: jax.Array, n_doubles: int, interpret: boo
             rows = _add_rows(cs, rows, _rows_in(entry_ref, L, C))
             _rows_out(rest[-1], rows, L)
 
-    B = acc_t.shape[-1]
-    spec = _point_spec(cs)
-    extra, extra_specs = mxu_operands(cs.field, interpret)
-    return pl.pallas_call(
-        kernel,
-        grid=(B // BLOCK,),
-        in_specs=[spec, spec] + extra_specs,
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((C * L, B), jnp.uint32),
-        interpret=interpret,
-    )(acc_t, entry_t, *extra)
+    return _block_call(
+        cs, "pt_window_step", kernel, (acc_t, entry_t), (C * L, C * L), interpret
+    )
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3, 4))
@@ -394,22 +378,21 @@ def _ladder_call(
             rows = _add_rows(cs, _rows_in(m_arr, L, C), _rows_in(add_ref, L, C))
         _rows_out(rest[-1], rows, L)
 
-    B = p_t.shape[-1]
-    spec = _point_spec(cs)
-    bits_spec = pl.BlockSpec((nbits, BLOCK), lambda i: (0, i), memory_space=pltpu.VMEM)
-    extra, extra_specs = mxu_operands(cs.field, interpret)
-    return pl.pallas_call(
-        kernel,
-        grid=(B // BLOCK,),
-        in_specs=[spec, spec, bits_spec] + extra_specs,
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((C * L, B), jnp.uint32),
-        interpret=interpret,
-    )(p_t, add_t, bits_t, *extra)
+    return _block_call(
+        cs, "pt_ladder_mul_add", kernel, (p_t, add_t, bits_t),
+        (C * L, C * L, nbits), interpret,
+    )
+
+
+def _lane_blocks(flat: jax.Array) -> jax.Array:
+    """(m, rows) with m a BLOCK multiple -> (m // BLOCK, rows, BLOCK):
+    lanes on the lane axis, one kernel block each."""
+    m, rows = flat.shape
+    return jnp.swapaxes(jnp.reshape(flat, (m // BLOCK, BLOCK, rows)), 1, 2)
 
 
 def _to_tiles(cs: CurveSpec, pts: jax.Array) -> tuple[jax.Array, tuple, int]:
-    """(..., C, L) -> ((C·L, B_padded), batch_shape, n)."""
+    """(..., C, L) -> ((nb, C·L, BLOCK) lane blocks, batch_shape, n)."""
     L, C = cs.field.limbs, cs.ncoords
     batch = pts.shape[:-2]
     n = 1
@@ -426,12 +409,13 @@ def _to_tiles(cs: CurveSpec, pts: jax.Array) -> tuple[jax.Array, tuple, int]:
         flat = jnp.concatenate(
             [flat, jnp.broadcast_to(jnp.asarray(ident.reshape(-1)), (m - n, C * L))]
         )
-    return flat.T, batch, n
+    return _lane_blocks(flat), batch, n
 
 
 def _from_tiles(cs: CurveSpec, t: jax.Array, batch: tuple, n: int) -> jax.Array:
     L, C = cs.field.limbs, cs.ncoords
-    return jnp.reshape(t.T[:n], batch + (C, L))
+    flat = jnp.reshape(jnp.swapaxes(t, 1, 2), (-1, C * L))
+    return jnp.reshape(flat[:n], batch + (C, L))
 
 
 def _interp() -> bool:
@@ -448,7 +432,8 @@ def pt_add(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None 
     p, q = jnp.broadcast_arrays(jnp.asarray(p, jnp.uint32), jnp.asarray(q, jnp.uint32))
     p_t, batch, n = _to_tiles(cs, p)
     q_t, _, _ = _to_tiles(cs, q)
-    out = _add_call(cs, p_t, q_t, _interp() if interpret is None else interpret)
+    interp = _interp() if interpret is None else interpret
+    out = jax.vmap(lambda pb, qb: _add_call(cs, pb, qb, interp))(p_t, q_t)
     return _from_tiles(cs, out, batch, n)
 
 
@@ -459,7 +444,8 @@ def pt_madd(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None
     p, q = jnp.broadcast_arrays(jnp.asarray(p, jnp.uint32), jnp.asarray(q, jnp.uint32))
     p_t, batch, n = _to_tiles(cs, p)
     q_t, _, _ = _to_tiles(cs, q)
-    out = _madd_call(cs, p_t, q_t, _interp() if interpret is None else interpret)
+    interp = _interp() if interpret is None else interpret
+    out = jax.vmap(lambda pb, qb: _madd_call(cs, pb, qb, interp))(p_t, q_t)
     return _from_tiles(cs, out, batch, n)
 
 
@@ -468,7 +454,8 @@ def pt_double(cs: CurveSpec, p: jax.Array, n_doubles: int = 1, *, interpret: boo
     metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_double")
     p = jnp.asarray(p, jnp.uint32)
     p_t, batch, n = _to_tiles(cs, p)
-    out = _double_call(cs, p_t, n_doubles, _interp() if interpret is None else interpret)
+    interp = _interp() if interpret is None else interpret
+    out = jax.vmap(lambda pb: _double_call(cs, pb, n_doubles, interp))(p_t)
     return _from_tiles(cs, out, batch, n)
 
 
@@ -482,8 +469,9 @@ def pt_window_step(
     )
     acc_t, batch, n = _to_tiles(cs, acc)
     entry_t, _, _ = _to_tiles(cs, entry)
-    out = _window_call(
-        cs, acc_t, n_doubles, _interp() if interpret is None else interpret, entry_t
+    interp = _interp() if interpret is None else interpret
+    out = jax.vmap(lambda ab, eb: _window_call(cs, ab, n_doubles, interp, eb))(
+        acc_t, entry_t
     )
     return _from_tiles(cs, out, batch, n)
 
@@ -510,15 +498,16 @@ def pt_ladder_mul_add(
     x = jnp.broadcast_to(jnp.asarray(x, jnp.uint32), p.shape[:-2])
     p_t, batch, n = _to_tiles(cs, p)
     a_t, _, _ = _to_tiles(cs, addend)
-    B = p_t.shape[-1]
+    B = p_t.shape[0] * BLOCK
     xf = jnp.reshape(x, (n,))
     if B != n:
         xf = jnp.concatenate([xf, jnp.zeros((B - n,), jnp.uint32)])
-    # MSB-first bit rows: bits_t[i] = bit (nbits-1-i) of x
+    # MSB-first bit rows per lane: bit (nbits-1-i) of x in row i
     shifts = jnp.arange(nbits - 1, -1, -1, dtype=jnp.uint32)
-    bits_t = (xf[None, :] >> shifts[:, None]) & jnp.uint32(1)
-    out = _ladder_call(
-        cs, p_t, a_t, nbits, _interp() if interpret is None else interpret, bits_t
+    bits_t = _lane_blocks((xf[:, None] >> shifts[None, :]) & jnp.uint32(1))
+    interp = _interp() if interpret is None else interpret
+    out = jax.vmap(lambda pb, ab, bb: _ladder_call(cs, pb, ab, nbits, interp, bb))(
+        p_t, a_t, bits_t
     )
     return _from_tiles(cs, out, batch, n)
 

@@ -14,8 +14,10 @@ contract:
 * atomic writes (``mkstemp`` + ``os.replace``) so concurrent worker
   processes never observe a torn file;
 * every artifact carries a BLAKE2b digest over a header binding the
-  format version, jax/jaxlib versions, backend, knob tier and the full
-  program key — corruption, truncation or version skew all fail the
+  format version, jax/jaxlib versions, backend, knob tier, the full
+  program key and :func:`source_fingerprint` (a hash of the text of the
+  package modules a stored program can trace) — corruption, truncation,
+  version skew or an executable baked from other source all fail the
   digest check and fall through to a silent rebuild (counted in
   :func:`stats`), never a crash and never a stale program.
 
@@ -44,6 +46,8 @@ when a bucket's programs are already resident.
 from __future__ import annotations
 
 import ast
+import contextlib
+import functools
 import hashlib
 import logging
 import os
@@ -61,7 +65,20 @@ from ..utils.metrics import REGISTRY
 
 #: Bump when the artifact layout changes; old files fail the digest
 #: check and silently rebuild.
-_FORMAT_VERSION = 2
+_FORMAT_VERSION = 3
+
+#: What a stored program can trace, relative to the package: the round
+#: programs and their stacked twins, and everything under them down to
+#: the kernels.  :func:`source_fingerprint` hashes the text of these.
+_TRACED_SOURCES = (
+    "dkg/ceremony.py",
+    "service/engine.py",
+    "utils/scanchunk.py",
+    "fields",
+    "groups",
+    "ops",
+    "poly",
+)
 
 #: Knobs that change the traced program at fixed shapes: two processes
 #: with different tiers must never serve each other's executables, so
@@ -85,6 +102,10 @@ _TIER_KNOBS = (
     "DKG_TPU_DEM",
     "DKG_TPU_DEM_CHUNK",
 )
+
+#: Build-stage buckets: one program's trace or compile runs from
+#: milliseconds to many minutes (a (1024,341) verify on a serving host).
+_BUILD_BUCKETS = (0.1, 1.0, 5.0, 15.0, 30.0, 60.0, 120.0, 300.0, 600.0, 1200.0)
 
 _LOCK = threading.RLock()
 _LOG = logging.getLogger(__name__)
@@ -143,12 +164,40 @@ def spec_sig(args: tuple) -> tuple:
     return tuple(out)
 
 
+@functools.lru_cache(maxsize=1)
+def source_fingerprint() -> str:
+    """Hex BLAKE2b over the text of every module a stored program can
+    trace (:data:`_TRACED_SOURCES`), names included.  Bound into every
+    artifact's digest, so a checkout whose programs were re-formed never
+    serves, or is measured with, another checkout's executables where
+    the store outlives it.  Reads a megabyte of source once a process;
+    nothing is lowered to learn it."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = []
+    for rel in _TRACED_SOURCES:
+        path = os.path.join(root, rel)
+        if os.path.isdir(path):
+            files += [
+                os.path.join(path, name)
+                for name in os.listdir(path)
+                if name.endswith(".py")
+            ]
+        else:
+            files.append(path)
+    h = hashlib.blake2b(digest_size=16)
+    for path in sorted(files):
+        h.update(os.path.relpath(path, root).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read() + b"\0")
+    return h.hexdigest()
+
+
 def _header(key: tuple) -> bytes:
     import jaxlib
 
     return (
         f"aot|{_FORMAT_VERSION}|{jax.__version__}|{jaxlib.__version__}|"
-        f"{jax.default_backend()}|{knob_tier()}|{key!r}"
+        f"{jax.default_backend()}|{knob_tier()}|{source_fingerprint()}|{key!r}"
     ).encode()
 
 
@@ -160,7 +209,12 @@ def _digest(header: bytes, blob: bytes) -> bytes:
 
 
 def _path(key: tuple) -> str:
-    tag = hashlib.blake2b(repr(key).encode(), digest_size=8).hexdigest()
+    # the source is in the name too, so that two checkouts sharing one
+    # store keep their artifacts side by side and never re-bake each
+    # other's away
+    tag = hashlib.blake2b(
+        f"{source_fingerprint()}|{key!r}".encode(), digest_size=8
+    ).hexdigest()
     return os.path.join(cache_dir(), f"aot_v{_FORMAT_VERSION}_{key[0]}_{tag}.npz")
 
 
@@ -233,6 +287,7 @@ def _persist(path: str, key: tuple, blob: bytes) -> None:
                     blob=np.frombuffer(blob, np.uint8),
                     digest=np.frombuffer(_digest(_header(key), blob), np.uint8),
                     key=np.frombuffer(repr(key).encode(), np.uint8),
+                    source=np.frombuffer(source_fingerprint().encode(), np.uint8),
                 )
             os.replace(tmp, path)
             with _LOCK:
@@ -245,11 +300,56 @@ def _persist(path: str, key: tuple, blob: bytes) -> None:
         pass
 
 
+@contextlib.contextmanager
+def _stage(kind: str, stage: str):
+    """One stage of one program's build, into
+    ``aot_build_stage_seconds{kind=,stage=}``."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        REGISTRY.observe(
+            "aot_build_stage_seconds",
+            time.perf_counter() - t0,
+            _BUILD_BUCKETS,
+            kind=kind,
+            stage=stage,
+        )
+
+
+def _build_staged(kind: str, build):
+    """Run ``build`` and whatever stages its result still lacks, each
+    timed apart: ``trace`` (Python tracing to a jaxpr: the thunk itself,
+    where it returns a ``Traced``), ``lower`` (jaxpr to StableHLO, the
+    Mosaic kernels' bodies included), ``compile`` (the backend).  A
+    thunk that returns a later stage has done the earlier ones inside
+    itself, and its seconds are booked under the stage it returns."""
+    t0 = time.perf_counter()
+    obj = build()
+    own = time.perf_counter() - t0
+    # a Traced can be lowered, a Lowered compiled, a Compiled neither
+    first = "trace" if hasattr(obj, "lower") else "lower" if hasattr(obj, "compile") else "compile"
+    REGISTRY.observe(
+        "aot_build_stage_seconds", own, _BUILD_BUCKETS, kind=kind, stage=first
+    )
+    if first == "trace":
+        with _stage(kind, "lower"):
+            obj = obj.lower()
+    if first != "compile":
+        with _stage(kind, "compile"):
+            obj = obj.compile()
+    return obj
+
+
 def get_or_build(key: tuple, build):
     """The store's one lookup: process cache -> validated disk load ->
-    ``build()`` (a thunk returning a ``jax.stages.Compiled``) + persist.
-    Returns a loaded executable callable with the program's dynamic
-    (non-static) operands."""
+    ``build()`` + persist.  ``build`` is a thunk returning the program
+    at any of jax's stages: a ``jax.stages.Traced`` (``jit(f).trace(
+    *specs)``: the engine's seams), a ``Lowered`` or a finished
+    ``Compiled``; the store takes it through the stages that are left
+    and books each one's seconds (:func:`_build_staged`).  Returns a
+    loaded executable callable with the program's dynamic (non-static)
+    operands."""
     with _LOCK:
         hit = _PROC.get(key)
         if hit is not None:
@@ -268,8 +368,9 @@ def get_or_build(key: tuple, build):
         path = _path(key)
         fn = _load_blob(path, key)
         if fn is None:
+            kind = str(key[0])
             t0 = time.perf_counter()
-            fn = build()
+            fn = _build_staged(kind, build)
             dt = time.perf_counter() - t0
             with _LOCK:
                 _STATS["builds"] += 1
@@ -277,7 +378,8 @@ def get_or_build(key: tuple, build):
             REGISTRY.inc("aot_builds_total")
             REGISTRY.observe("aot_build_seconds", dt)
             try:
-                _persist(path, key, serialize(fn))
+                with _stage(kind, "serialize"):
+                    _persist(path, key, serialize(fn))
             except Exception as exc:
                 # some backends can't serialize; the compiled program
                 # still serves this process
@@ -285,6 +387,27 @@ def get_or_build(key: tuple, build):
         with _LOCK:
             _PROC[key] = fn
         return fn
+
+
+def _stored_key(path: str):
+    """The program key of one artifact, from its small ``key`` member
+    (never the executable blob).  None for an artifact baked from other
+    source (another checkout's: not ours to load, and no fault) and for
+    one that does not parse (counted in ``disk_rejects``)."""
+    try:
+        with np.load(path, allow_pickle=False) as z:
+            # no such member: an artifact of before the store bound its source
+            if "source" not in z.files or z["source"].tobytes().decode() != source_fingerprint():
+                return None
+            key = ast.literal_eval(z["key"].tobytes().decode())
+        if not (isinstance(key, tuple) and key and isinstance(key[0], str)):
+            raise ValueError("not a program key")
+    except Exception:
+        with _LOCK:
+            _STATS["disk_rejects"] += 1
+        REGISTRY.inc("aot_disk_rejects_total")
+        return None
+    return key
 
 
 def _scan_disk() -> dict:
@@ -305,14 +428,8 @@ def _scan_disk() -> dict:
             if not (name.startswith("aot_v") and name.endswith(".npz")):
                 continue
             path = os.path.join(cache_dir(), name)
-            try:
-                with np.load(path, allow_pickle=False) as z:
-                    key = ast.literal_eval(z["key"].tobytes().decode())
-            except Exception:
-                _STATS["disk_rejects"] += 1
-                REGISTRY.inc("aot_disk_rejects_total")
-                continue
-            if isinstance(key, tuple) and key and isinstance(key[0], str):
+            key = _stored_key(path)
+            if key is not None:
                 disk[key] = path
         _DISK = disk
         return disk
@@ -371,18 +488,8 @@ def preload(max_seconds: float | None = None) -> int:
             if max_seconds is not None and time.perf_counter() - t0 > max_seconds:
                 break
             path = os.path.join(cache_dir(), name)
-            try:
-                with np.load(path, allow_pickle=False) as z:
-                    key = ast.literal_eval(z["key"].tobytes().decode())
-            except Exception:
-                _STATS["disk_rejects"] += 1
-                REGISTRY.inc("aot_disk_rejects_total")
-                continue
-            if not (isinstance(key, tuple) and key and isinstance(key[0], str)):
-                _STATS["disk_rejects"] += 1
-                REGISTRY.inc("aot_disk_rejects_total")
-                continue
-            if key in _PROC:
+            key = _stored_key(path)
+            if key is None or key in _PROC:
                 continue
             fn = _load_blob(path, key)
             if fn is not None:

@@ -240,18 +240,20 @@ def _specs(args: tuple) -> tuple:
     )
 
 
-def _aot_dispatch(key_prefix: tuple, args: tuple, lower, fallback):
+def _aot_dispatch(key_prefix: tuple, args: tuple, trace, fallback):
     """Serve one program dispatch from the AOT executable store when
-    it is enabled, else the ordinary jitted twin.  ``lower`` maps a
-    tuple of ShapeDtypeStruct specs to a ``jax.stages.Lowered`` (statics
-    baked in); the compiled result is persisted for every later process.
+    it is enabled, else the ordinary jitted twin.  ``trace`` maps a
+    tuple of ShapeDtypeStruct specs to a ``jax.stages.Traced`` (statics
+    baked in); the store lowers and compiles it, books each stage's
+    seconds (``aot_build_stage_seconds{kind=,stage=}``) and persists the
+    result for every later process.
     A store failure degrades to ``fallback`` — a request must never die
     on a cache problem — but is counted and logged (aot.note_error)."""
     if not aot.enabled():
         return fallback()
     try:
         key = key_prefix + (aot.spec_sig(args),)
-        fn = aot.get_or_build(key, lambda: lower(_specs(args)).compile())
+        fn = aot.get_or_build(key, lambda: trace(_specs(args)))
         return fn(*args)
     except Exception as exc:
         aot.note_error(exc, f"dispatch {key_prefix[0]}")
@@ -277,7 +279,7 @@ def aot_sign_folded(curve: str, sigma_limbs: np.ndarray, h_dev):
     return _aot_dispatch(
         ("sign_folded", curve, int(hh.shape[0])),
         args,
-        lambda sp: _sign_ladder.lower(cs, *sp),
+        lambda sp: _sign_ladder.trace(cs, *sp),
         lambda: signing.sign_folded(curve, sigma_limbs, h_dev),
     )
 
@@ -480,7 +482,7 @@ def start_convoy(
             a, e, s, r = _aot_dispatch(
                 ("deal", req0.curve, b.n, b.t, 1, 0),
                 args,
-                lambda sp: ce.deal.lower(cfg_pad, *sp),
+                lambda sp: ce.deal.trace(cfg_pad, *sp),
                 lambda: ce.deal(cfg_pad, *args),
             )
             a, e, s, r = a[None], e[None], s[None], r[None]
@@ -488,7 +490,7 @@ def start_convoy(
             a, e, s, r = _aot_dispatch(
                 ("deal", req0.curve, b.n, b.t, k, 0),
                 args,
-                lambda sp: _deal_stack.lower(cfg_pad, *sp),
+                lambda sp: _deal_stack.trace(cfg_pad, *sp),
                 lambda: _deal_stack(cfg_pad, *args),
             )
     return InFlight(
@@ -520,7 +522,7 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
             ok = _aot_dispatch(
                 ("verify", curve, n_pad, cfg_pad.t, 1, rho_bits),
                 args,
-                lambda sp: ce.verify_batch.lower(
+                lambda sp: ce.verify_batch.trace(
                     cfg_pad, sp[0], sp[1], sp[2], sp[3], rho_bits, sp[4], sp[5]
                 ),
                 lambda: ce.verify_batch(
@@ -533,7 +535,7 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
             ok = _aot_dispatch(
                 ("verify", curve, n_pad, cfg_pad.t, k, rho_bits),
                 args,
-                lambda sp: _verify_stack.lower(
+                lambda sp: _verify_stack.trace(
                     cfg_pad, sp[0], sp[1], sp[2], sp[3], rho_bits, sp[4], sp[5]
                 ),
                 lambda: _verify_stack(
@@ -575,13 +577,13 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
             final_shares = _aot_dispatch(
                 ("aggregate", curve, n_pad, cfg_pad.t, 1, 0),
                 (fl.s[0], q0),
-                lambda sp: ce.aggregate_shares.lower(cfg_pad, *sp),
+                lambda sp: ce.aggregate_shares.trace(cfg_pad, *sp),
                 lambda: ce.aggregate_shares(cfg_pad, fl.s[0], q0),
             )[None]
             master = _aot_dispatch(
                 ("master", curve, n_pad, cfg_pad.t, 1, 0),
                 (fl.a[0], q0),
-                lambda sp: ce.master_key_from_bare.lower(cfg_pad, *sp),
+                lambda sp: ce.master_key_from_bare.trace(cfg_pad, *sp),
                 lambda: ce.master_key_from_bare(cfg_pad, fl.a[0], q0),
             )[None]
         else:
@@ -589,7 +591,7 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
             final_shares, master = _aot_dispatch(
                 ("finalise", curve, n_pad, cfg_pad.t, k, 0),
                 (fl.a, fl.s, qd),
-                lambda sp: _finalise_stack.lower(cfg_pad, *sp),
+                lambda sp: _finalise_stack.trace(cfg_pad, *sp),
                 lambda: _finalise_stack(cfg_pad, fl.a, fl.s, qd),
             )
     with _stage(trace, "finalise_wait"):
