@@ -867,12 +867,8 @@ class BatchedCeremony:
         }
         self.rng = rng
         fs = cs.scalar
-        self.coeffs_a = jnp.asarray(
-            fh.encode(fs, [[fs.rand_int(rng) for _ in range(t + 1)] for _ in range(n)])
-        )
-        self.coeffs_b = jnp.asarray(
-            fh.encode(fs, [[fs.rand_int(rng) for _ in range(t + 1)] for _ in range(n)])
-        )
+        self.coeffs_a = jnp.asarray(fh.draw_limbs(fs, rng, (n, t + 1)))
+        self.coeffs_b = jnp.asarray(fh.draw_limbs(fs, rng, (n, t + 1)))
 
     def run(self, rho_bits: int = 128, trace=None, tamper=None):
         """Full ceremony over device arrays, including the blame path.

@@ -83,12 +83,8 @@ def batched_dealing(
     h_table = precompute.base_table(cs, env.commitment_key.h)
 
     # secret sampling stays host-side CSPRNG (SURVEY §7 hard part f)
-    coeffs_a = jnp.asarray(
-        fh.encode(fs, [[fs.rand_int(rng) for _ in range(t + 1)] for _ in range(m)])
-    )
-    coeffs_b = jnp.asarray(
-        fh.encode(fs, [[fs.rand_int(rng) for _ in range(t + 1)] for _ in range(m)])
-    )
+    coeffs_a = jnp.asarray(fh.draw_limbs(fs, rng, (m, t + 1)))
+    coeffs_b = jnp.asarray(fh.draw_limbs(fs, rng, (m, t + 1)))
     with phase_span(trace, "deal"):
         bare_dev, rand_dev, shares_dev, hidings_dev = deal_chunked(
             cfg, coeffs_a, coeffs_b, g_table, h_table
@@ -97,9 +93,7 @@ def batched_dealing(
     # device KEM + DEM for all (dealer, recipient) pairs, chunk-
     # pipelined so host sealing overlaps the next chunk's kernels
     pks_dev = gd.from_host(cs, [p.point for p in pks])
-    r_enc = jnp.asarray(
-        fh.encode(fs, [[fs.rand_int(rng) for _ in range(n)] for _ in range(m)])
-    )
+    r_enc = jnp.asarray(fh.draw_limbs(fs, rng, (m, n)))
     with phase_span(trace, "seal"):
         sealed = seal_shares_pipeline(
             group, cfg, shares_dev, hidings_dev, pks_dev, r_enc, g_table

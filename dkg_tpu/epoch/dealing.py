@@ -58,8 +58,10 @@ def deal_epoch_poly(
     use bare Feldman commitments only.
     """
     cs, fs = cfg.cs, group.scalar_field
-    coeffs = [constant % fs.modulus] + [fs.rand_int(rng) for _ in range(cfg.t)]
-    coeffs_a = jnp.asarray(fh.encode(fs, [coeffs]))
+    coeffs = np.empty((1, cfg.t + 1, fs.limbs), np.uint32)
+    coeffs[0, 0] = fh.encode(fs, constant)
+    coeffs[0, 1:] = fh.draw_limbs(fs, rng, (cfg.t,))
+    coeffs_a = jnp.asarray(coeffs)
     coeffs_b = jnp.zeros_like(coeffs_a)
     g_table = precompute.generator_table(cs)
     # zero hiding coefficients make the h-leg a no-op, so the g table
@@ -68,9 +70,7 @@ def deal_epoch_poly(
         cfg, coeffs_a, coeffs_b, g_table, g_table
     )
     pks_dev = gd.from_host(cs, [p.point for p in recipient_pks])
-    r_enc = jnp.asarray(
-        fh.encode(fs, [[fs.rand_int(rng) for _ in range(cfg.n)]])
-    )
+    r_enc = jnp.asarray(fh.draw_limbs(fs, rng, (1, cfg.n)))
     sealed = seal_shares_pipeline(
         group, cfg, shares, hidings, pks_dev, r_enc, g_table
     )

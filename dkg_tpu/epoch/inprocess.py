@@ -38,11 +38,10 @@ def _coeff_tensor(fs: FieldSpec, constants: list[int], ncoeffs: int, rng):
     """(rows, ncoeffs, L) coefficient tensor: column 0 holds
     ``constants``, the rest fresh CSPRNG scalars (host-side sampling,
     like the ceremony's batched_dealing)."""
-    rows = [
-        [c % fs.modulus] + [fs.rand_int(rng) for _ in range(ncoeffs - 1)]
-        for c in constants
-    ]
-    return jnp.asarray(fh.encode(fs, rows))
+    out = np.empty((len(constants), ncoeffs, fs.limbs), np.uint32)
+    out[:, 0] = fh.encode(fs, list(constants))
+    out[:, 1:] = fh.draw_limbs(fs, rng, (len(constants), ncoeffs - 1))
+    return jnp.asarray(out)
 
 
 def _fold_dealers(fs: FieldSpec, m: jnp.ndarray) -> jnp.ndarray:

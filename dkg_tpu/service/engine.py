@@ -76,7 +76,8 @@ class CeremonyRequest:
     """One ceremony-as-a-service request.
 
     ``seed`` pins the coefficient stream (``random.Random(seed)``, drawn
-    in exactly :class:`~dkg_tpu.dkg.ceremony.BatchedCeremony`'s order) so
+    in exactly :class:`~dkg_tpu.dkg.ceremony.BatchedCeremony`'s order:
+    :func:`draw_coeffs` and it share :func:`~dkg_tpu.fields.host.draw_limbs`) so
     results are reproducible and WAL replay after a crash re-deals
     byte-identical polynomials; ``None`` uses ``random.SystemRandom``
     (non-durable requests only).  ``deadline_s`` is a relative budget
@@ -333,13 +334,14 @@ def _finalise_stack(cfg, a_comm, shares, qualified):
 
 def draw_coeffs(cfg: ce.CeremonyConfig, rng) -> tuple[np.ndarray, np.ndarray]:
     """The REAL coefficient tensors, drawn in exactly
-    :class:`~dkg_tpu.dkg.ceremony.BatchedCeremony`'s order so a seeded
-    service ceremony and a fresh single-ceremony run of the same seed
-    deal byte-identical polynomials."""
+    :class:`~dkg_tpu.dkg.ceremony.BatchedCeremony`'s order (both call
+    :func:`~dkg_tpu.fields.host.draw_limbs`, ``a`` wholly before ``b``)
+    so a seeded service ceremony and a fresh single-ceremony run of the
+    same seed deal byte-identical polynomials."""
     fs = cfg.cs.scalar
-    n, t = cfg.n, cfg.t
-    a = fh.encode(fs, [[fs.rand_int(rng) for _ in range(t + 1)] for _ in range(n)])
-    b = fh.encode(fs, [[fs.rand_int(rng) for _ in range(t + 1)] for _ in range(n)])
+    shape = (cfg.n, cfg.t + 1)
+    a = fh.draw_limbs(fs, rng, shape)
+    b = fh.draw_limbs(fs, rng, shape)
     return a, b
 
 
@@ -349,6 +351,8 @@ def pad_coeffs(coeffs: np.ndarray, n_pad: int, t_pad: int) -> np.ndarray:
     polynomials, real dealers gain zero high-order coefficients — both
     inert under the pad-and-mask contract."""
     n, tc, limbs = coeffs.shape
+    if (n, tc) == (n_pad, t_pad + 1):
+        return coeffs
     out = np.zeros((n_pad, t_pad + 1, limbs), np.uint32)
     out[:n, :tc] = coeffs
     return out
@@ -671,9 +675,7 @@ def wire_broadcasts(
     fs = cs.scalar
     group = gh.ALL_GROUPS[req.curve]
     n, n_pad = req.n, cfg_pad.n
-    r_real = fh.encode(
-        fs, [[fs.rand_int(rng_enc) for _ in range(n)] for _ in range(n)]
-    )
+    r_real = fh.draw_limbs(fs, rng_enc, (n, n))
     r_pad = np.zeros((n_pad, n_pad, fs.limbs), np.uint32)
     r_pad[..., 0] = 1  # phantom lanes: r=1 (a zero KEM scalar has no inverse)
     r_pad[:n, :n] = r_real

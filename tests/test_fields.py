@@ -21,6 +21,9 @@ from dkg_tpu.fields import (
     host as fh,
     limbs_to_int,
 )
+from dkg_tpu.fields.spec import FieldSpec
+from dkg_tpu.groups import device as gd
+from dkg_tpu.utils import metrics
 
 RNG = random.Random(0xD1C6)
 
@@ -195,3 +198,114 @@ def test_2d_batch_shapes():
     for i in range(4):
         for j in range(3):
             assert int(got[i][j]) == fh.mul(fs, vals[i][j], vals[i][j])
+
+
+# ---------------------------------------------------------------------------
+# fh.draw_limbs: the bulk read of a generator's stream against the
+# scalar-at-a-time loop it replaces (fs.rand_int + fh.encode)
+# ---------------------------------------------------------------------------
+
+#: just over a power of two: half of all attempts are thrown away, and
+#: every attempt that is kept ties with the modulus in its top word
+JUST_OVER = FieldSpec("just_over_2_64", (1 << 64) + 13, 5)
+
+
+def _draw_counts():
+    c = metrics.REGISTRY.snapshot()["counters"]
+    return {
+        "bulk": c.get('coeff_draw_scalars_total{path="bulk"}', 0),
+        "sequential": c.get('coeff_draw_scalars_total{path="sequential"}', 0),
+        "rejected": c.get("coeff_draw_rejected_total", 0),
+    }
+
+
+def _delta(before):
+    after = _draw_counts()
+    return {k: after[k] - before[k] for k in after}
+
+
+def _sequential(fs, rng, count):
+    """The loop ``draw_limbs`` stands for, and how many attempts it threw
+    away."""
+    vals, rejected = [], 0
+    while len(vals) < count:
+        x = rng.getrandbits(fs.bits)
+        if x < fs.modulus:
+            vals.append(x)
+        else:
+            rejected += 1
+    return fh.encode(fs, vals).reshape(count, fs.limbs), rejected
+
+
+@pytest.mark.parametrize("seed", [0, 0xD1C6, 2**31 + 5])
+@pytest.mark.parametrize("curve", sorted(gd.ALL_CURVES))
+def test_draw_limbs_is_the_rand_int_loop(curve, seed):
+    fs = gd.ALL_CURVES[curve].scalar
+    bulk, loop = random.Random(seed), random.Random(seed)
+    got = fh.draw_limbs(fs, bulk, (9, 11))
+    want = fh.encode(fs, [[fs.rand_int(loop) for _ in range(11)] for _ in range(9)])
+    assert got.dtype == np.uint32 and got.shape == (9, 11, fs.limbs)
+    np.testing.assert_array_equal(got, want)
+    assert bulk.getrandbits(64) == loop.getrandbits(64)
+
+
+@pytest.mark.parametrize("count", [0, 1, 7, 5000])
+@pytest.mark.parametrize("fs", [L25519, JUST_OVER], ids=lambda fs: fs.name)
+def test_draw_limbs_under_rejection(fs, count):
+    bulk, loop = random.Random(count + 3), random.Random(count + 3)
+    before = _draw_counts()
+    got = fh.draw_limbs(fs, bulk, (count,))
+    want, rejected = _sequential(fs, loop, count)
+    np.testing.assert_array_equal(got, want)
+    assert bulk.getrandbits(64) == loop.getrandbits(64)
+    assert _delta(before) == {"bulk": count, "sequential": 0, "rejected": rejected}
+    assert count < 7 or rejected > 0
+
+
+def test_draw_limbs_reads_in_rounds(monkeypatch):
+    """More scalars than one read asks for: the rounds' attempts are
+    still the loop's attempts in order."""
+    monkeypatch.setattr(fh, "_DRAW_ATTEMPTS_PER_READ", 64)
+    bulk, loop = random.Random(8), random.Random(8)
+    got = fh.draw_limbs(JUST_OVER, bulk, (3, 100))
+    want, _ = _sequential(JUST_OVER, loop, 300)
+    np.testing.assert_array_equal(got.reshape(300, -1), want)
+    assert bulk.getrandbits(64) == loop.getrandbits(64)
+
+
+class _SubclassedRandom(random.Random):
+    """Inherits the Mersenne stream, but a subclass may have changed it."""
+
+
+class _StubGenerator:
+    """A test's own generator: only ``getrandbits``."""
+
+    def __init__(self, seed):
+        self._inner = random.Random(seed)
+
+    def getrandbits(self, k):
+        return self._inner.getrandbits(k) ^ 1
+
+
+@pytest.mark.parametrize("make", [_SubclassedRandom, _StubGenerator])
+def test_draw_limbs_other_generators_take_the_loop(make):
+    fs = L25519
+    before = _draw_counts()
+    got = fh.draw_limbs(fs, make(21), (4, 5))
+    loop = make(21)
+    want = fh.encode(fs, [[fs.rand_int(loop) for _ in range(5)] for _ in range(4)])
+    np.testing.assert_array_equal(got, want)
+    assert _delta(before) == {"bulk": 0, "sequential": 20, "rejected": 0}
+
+
+@pytest.mark.parametrize("fs", [L25519, JUST_OVER], ids=lambda fs: fs.name)
+def test_draw_limbs_system_random(fs):
+    before = _draw_counts()
+    got = fh.draw_limbs(fs, random.SystemRandom(), (6, 50))
+    assert got.dtype == np.uint32 and got.shape == (6, 50, fs.limbs)
+    assert int(got.max()) < 1 << 16
+    vals = [int(v) for v in fh.decode(fs, got).ravel()]
+    assert all(0 <= v < fs.modulus for v in vals)
+    assert len(set(vals)) == 300
+    d = _delta(before)
+    assert (d["bulk"], d["sequential"]) == (300, 0) and d["rejected"] > 0

@@ -703,6 +703,32 @@ def test_padded_run_matches_unpadded_real_lanes(runtime, convoy1):
     assert not np.asarray(fl.r[0])[n:].any()
 
 
+@pytest.mark.parametrize(
+    "n, t, bucket",
+    [(16, 5, (16, 5)), (5, 2, (8, 2)), (9, 3, (16, 4))],
+    ids=["16x5_exact", "5x2_padded", "9x3_padded"],
+)
+def test_draw_coeffs_is_batched_ceremonys_draw(n, t, bucket):
+    """A seeded request deals ``BatchedCeremony``'s polynomials of the
+    same seed: both draw through ``fh.draw_limbs``, ``a`` before ``b``,
+    and padding to the bucket only adds zeros (none at all, and no copy,
+    where the real shape is the bucket's)."""
+    from dkg_tpu.dkg import ceremony as ce
+
+    assert buckets.bucket_for(n, t) == buckets.Bucket(*bucket)
+    req = CeremonyRequest(CURVE, n, t, seed=0xBEEF + n)
+    a, b = engine.draw_coeffs(ce.CeremonyConfig(CURVE, n, t), engine.rng_for(req))
+    ref = ce.BatchedCeremony(CURVE, n, t, b"draw-order", random.Random(req.seed))
+    np.testing.assert_array_equal(a, np.asarray(ref.coeffs_a))
+    np.testing.assert_array_equal(b, np.asarray(ref.coeffs_b))
+    for real in (a, b):
+        padded = engine.pad_coeffs(real, *bucket)
+        assert padded.shape == (bucket[0], bucket[1] + 1, real.shape[-1])
+        assert (padded is real) == ((n, t) == bucket)
+        np.testing.assert_array_equal(padded[:n, : t + 1], real)
+        assert not padded[n:].any() and not padded[:, t + 1 :].any()
+
+
 def test_padded_master_matches_fresh_single_run(convoy1):
     """The service's padded+bucketed execution must be invisible in the
     result: same seed, same master key as a fresh unpadded ceremony."""
