@@ -175,15 +175,11 @@ def _deal_chunk_default(cfg: CeremonyConfig, m: int | None = None) -> int:
 
 def _env_chunk(name: str) -> int | None:
     """A validated chunk-size env knob: None when unset, else an int >= 0
-    (0 disables chunking).  Shared by DKG_TPU_DEAL_CHUNK and
-    DKG_TPU_RLC_CHUNK here and DKG_TPU_VERIFY_CHUNK (parallel/mesh)."""
+    (0 disables chunking).  Shared by DKG_TPU_RLC_CHUNK here and
+    DKG_TPU_VERIFY_CHUNK (parallel/mesh)."""
     from ..utils import envknobs
 
     return envknobs.nonneg_int(name, "0 disables chunking")
-
-
-def _deal_env_chunk() -> int | None:
-    return _env_chunk("DKG_TPU_DEAL_CHUNK")
 
 
 def deal_chunked(
@@ -199,14 +195,11 @@ def deal_chunked(
     Outputs are concatenated on the dealer axis and bit-identical to a
     one-shot ``deal`` (each dealer's row is independent).  Chunking
     exists purely to bound the TPU scan-carry padding described in
-    :func:`_deal_chunk_default`; when the caller does not pin a chunk,
-    ``DKG_TPU_DEAL_CHUNK`` forces the size (0 disables chunking) —
-    an explicit ``chunk`` argument always wins.
+    :func:`_deal_chunk_default`, which is what an unpinned ``chunk``
+    takes on TPU (elsewhere: no chunking; 0 disables it anywhere).
     """
     if chunk is None:
-        chunk = _deal_env_chunk()
-        if chunk is None:
-            chunk = _deal_chunk_default(cfg, coeffs_a.shape[0]) if fd._on_tpu() else 0
+        chunk = _deal_chunk_default(cfg, coeffs_a.shape[0]) if fd._on_tpu() else 0
     # chunk over the rows actually supplied — callers may deal for a
     # LOCAL subset of dealers (committee_batch: m <= n rows)
     n_rows = coeffs_a.shape[0]
@@ -255,9 +248,7 @@ def deal_commitments_traced_chunked(cfg, coeffs_a, coeffs_b, g_table, h_table):
     from ..utils.scanchunk import map_chunked
 
     m = int(coeffs_a.shape[0])
-    chunk = _deal_env_chunk()
-    if chunk is None:
-        chunk = _deal_chunk_default(cfg, m)
+    chunk = _deal_chunk_default(cfg, m)
 
     def call(off, w):
         ca = lax.dynamic_slice_in_dim(coeffs_a, off, w, 0)
@@ -273,9 +264,7 @@ def deal_shares_traced_chunked(cfg, coeffs_a, coeffs_b):
     from ..utils.scanchunk import map_chunked
 
     m = int(coeffs_a.shape[0])
-    chunk = _deal_env_chunk()
-    if chunk is None:
-        chunk = _shares_chunk_default(cfg, m)
+    chunk = _shares_chunk_default(cfg, m)
 
     def call(off, w):
         ca = lax.dynamic_slice_in_dim(coeffs_a, off, w, 0)

@@ -205,20 +205,14 @@ def seal_shares_pipeline(
     dispatch is asynchronous; the DEM's single transfer per chunk is
     what blocks, and only on its own chunk's kernels).
 
-    ``DKG_TPU_DEM_CHUNK`` pins dealers per chunk (0 disables chunking);
-    the default targets ~4096 pairs per chunk.  The DEM leg follows
+    ``chunk`` pins dealers per chunk (0 disables chunking); the default
+    targets ~4096 pairs per chunk.  The DEM leg follows
     ``DKG_TPU_DEM`` (:func:`dem_mode`).  Output is bit-identical to an
     unchunked ``kem_batch`` + seal: chunks are independent dealer rows.
     """
-    from ..utils import envknobs
-
     n_d, n_r = r_enc.shape[0], r_enc.shape[1]
     if chunk is None:
-        chunk = envknobs.nonneg_int(
-            "DKG_TPU_DEM_CHUNK", "dealers per DEM chunk; 0 disables chunking"
-        )
-        if chunk is None:
-            chunk = max(1, 4096 // max(1, n_r))
+        chunk = max(1, 4096 // max(1, n_r))
     seal = seal_shares if dem_mode() == "scalar" else seal_shares_batch
     shares = np.asarray(shares)
     hidings = np.asarray(hidings)

@@ -99,47 +99,11 @@ def _u32(x) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def carry_lookahead_active() -> bool:
-    """Whether carry/borrow propagation lowers as a log-depth Kogge-Stone
-    lookahead (``lax.associative_scan``) instead of the sequential
-    ``lax.scan`` ripple.  Both are bit-exact.  Default: ripple scan —
-    measured 2x faster than the lookahead on XLA:CPU (the associative
-    scan lowers to slice/concat chains there), and the TPU path was
-    designed around the lane-parallel scan.  DKG_TPU_CARRY=lookahead
-    opts in on backends where log-depth wins."""
-    from ..utils import envknobs
-
-    env = envknobs.choice(
-        "DKG_TPU_CARRY", ("scan", "lookahead"), "carry-propagation lowering"
-    )
-    return env == "lookahead"
-
-
-def _carry_op(a, b):
-    """Carry-lookahead combine: (generate, propagate) semigroup."""
-    return b[0] | (b[1] & a[0]), a[1] & b[1]
-
-
 def _shift_up(x: jax.Array) -> jax.Array:
     """Shift limbs one position up (towards higher significance),
     dropping the top limb; the last-dim length is preserved."""
     pad = [(0, 0)] * (x.ndim - 1) + [(1, 0)]
     return jnp.pad(x, pad)[..., :-1]
-
-
-def _normalize_lookahead(cols: jax.Array) -> jax.Array:
-    # Two local rounds squeeze any uint32 columns to limbs <= 2**16 ...
-    x = cols
-    for _ in range(2):
-        x = (x & MASK16) + _shift_up(x >> 16)
-    # ... then one log-depth lookahead settles the +1 ripple carries:
-    # carry out of limb j obeys c = g | (p & c_in) with g = "limb == b",
-    # p = "limb == b-1", an associative combine.
-    g = x >> 16  # in {0, 1}
-    r = x & MASK16
-    gp = (g, (r == MASK16).astype(jnp.uint32))
-    cout, _ = lax.associative_scan(_carry_op, gp, axis=-1)
-    return (r + _shift_up(cout)) & MASK16
 
 
 def normalize(cols: jax.Array, out_len: int) -> jax.Array:
@@ -156,8 +120,6 @@ def normalize(cols: jax.Array, out_len: int) -> jax.Array:
         pad = [(0, 0)] * (cols.ndim - 1) + [(0, out_len - k)]
         cols = jnp.pad(cols, pad)
     cols = cols[..., :out_len]
-    if carry_lookahead_active():
-        return _normalize_lookahead(cols)
     xs = jnp.moveaxis(cols, -1, 0)
 
     def step(carry, col):
@@ -174,12 +136,6 @@ def sub_with_borrow(a: jax.Array, b: jax.Array) -> tuple[jax.Array, jax.Array]:
     Both inputs must be normalized limb arrays of equal last-dim K.
     """
     a, b = jnp.broadcast_arrays(_u32(a), _u32(b))
-    if carry_lookahead_active():
-        d = (a - b) & MASK16  # per-limb difference mod b
-        gp = ((a < b).astype(jnp.uint32), (a == b).astype(jnp.uint32))
-        bout, _ = lax.associative_scan(_carry_op, gp, axis=-1)
-        limbs = (d - _shift_up(bout)) & MASK16
-        return limbs, bout[..., -1]
     xs = (jnp.moveaxis(a, -1, 0), jnp.moveaxis(b, -1, 0))
 
     def step(borrow, ab):
@@ -388,23 +344,7 @@ def reduce_wide(fs: FieldSpec, x: jax.Array) -> jax.Array:
     """Reduce a normalized 2L-limb value to L limbs mod p, picking the
     cheapest admissible reducer: pseudo-Mersenne fold, then the linear
     fold, then Barrett.  All three produce the canonical representative,
-    so the choice never changes results — only the op count.
-    DKG_TPU_REDUCE=fold|linear|barrett forces one (raising at trace time
-    if the field does not admit it), which is how the parity tests pin
-    the reducers against each other."""
-    from ..utils import envknobs
-
-    forced = envknobs.choice(
-        "DKG_TPU_REDUCE", ("fold", "linear", "barrett"), "wide-reduction dispatch"
-    )
-    if forced == "fold":
-        if fs.fold_limbs is None:
-            raise ValueError(f"{fs.name} does not admit fold_reduce")
-        return fold_reduce(fs, x)
-    if forced == "linear":
-        return linear_reduce(fs, x)
-    if forced == "barrett":
-        return barrett_reduce(fs, x)
+    so the choice never changes results — only the op count."""
     if fs.fold_limbs is not None:
         return fold_reduce(fs, x)
     if fs.linred is not None:

@@ -55,20 +55,7 @@ def fused_multi_active(cs: "CurveSpec") -> bool:
     on v5e (round 4: ristretto255 pt_window_step still compiling when
     hard-killed at ~870 s, while the same Weierstrass body compiled in
     77 s) — so Edwards composes single-op kernels via XLA instead.
-    DKG_TPU_FUSED_MULTI=1/0 forces either way (1 still requires the
-    fused kernels to be active at all).
     """
-    from ..utils import envknobs
-
-    env = envknobs.choice(
-        "DKG_TPU_FUSED_MULTI",
-        ("0", "1"),
-        "a typo would silently run the wrong kernel path",
-    )
-    if env == "0":
-        return False
-    if env == "1":
-        return fused_kernels_active()
     return fused_kernels_active() and cs.kind != "edwards"
 
 
@@ -627,35 +614,28 @@ def _affine_limbs(cs: CurveSpec, host_group, p) -> np.ndarray:
     return fh.encode(cs.field, list(coords))
 
 
+def default_fixed_window() -> int:
+    """Backend-matched fixed-base window width, the one owner of the
+    rule: 16 bits on TPU (device-composed tables), ``FIXED_WINDOW``
+    elsewhere (host-built).  Read at trace time by
+    :func:`fixed_base_table` and ``groups.precompute.base_table``."""
+    return 16 if fd._on_tpu() else FIXED_WINDOW
+
+
 def fixed_base_table(cs: CurveSpec, base) -> jax.Array:
     """Device window table for a fixed base point.
 
-    Backend-matched window width: on TPU the table is DEVICE-BUILT with
-    16-bit windows — 16 mixed adds per 256-bit scalar instead of 32,
-    for ~200 MB of HBM per base (a clear trade: the commitment phase is
-    add-bound, HBM is plentiful, and the build is one batched ladder
-    call amortised over the whole ceremony).  Elsewhere the 8-bit
-    host-built table.  DKG_TPU_FB_WINDOW=4/8/16 forces a width (any
-    non-host width builds on device; validated — a bare ``int(env)``
-    here used to raise an uncontextualised ValueError at trace time).
+    Backend-matched window width (:func:`default_fixed_window`): on TPU
+    the table is DEVICE-BUILT with 16-bit windows — 16 mixed adds per
+    256-bit scalar instead of 32, for ~200 MB of HBM per base (a clear
+    trade: the commitment phase is add-bound, HBM is plentiful, and the
+    build is one batched ladder call amortised over the whole
+    ceremony).  Elsewhere the 8-bit host-built table.
     """
-    from ..utils import envknobs
-
-    window = envknobs.pos_int(
-        "DKG_TPU_FB_WINDOW", "fixed-base window width in bits: 4, 8 or 16"
-    )
-    if window is not None:
-        if window not in (4, 8, 16):
-            raise ValueError(
-                f"DKG_TPU_FB_WINDOW={window}: expected a fixed-base "
-                "window width of 4, 8 or 16 bits"
-            )
-        if window == FIXED_WINDOW:
-            return jnp.asarray(_fixed_table_np(cs, base_key(cs, base)))
-        return fixed_base_table_dev(cs, base, window)
-    if fd._on_tpu():
-        return fixed_base_table_dev(cs, base, 16)
-    return jnp.asarray(_fixed_table_np(cs, base_key(cs, base)))
+    window = default_fixed_window()
+    if window == FIXED_WINDOW:
+        return jnp.asarray(_fixed_table_np(cs, base_key(cs, base)))
+    return fixed_base_table_dev(cs, base, window)
 
 
 def fixed_base_table_dev(cs: CurveSpec, base, window: int = 16) -> jax.Array:
@@ -1300,13 +1280,8 @@ def _msm_pippenger_core(
 
     1. scatter — the XLA scan leg (:func:`_bucket_scan`) on every
        backend.  Digit-0 contributions land in bucket 0, which the
-       reduction ignores (identity-safe).  The Pallas twin
-       (ops/pallas_mxu.bucket_accumulate, buckets VMEM-resident) is
-       bit-identical in interpret mode and compiles for the v5e, but on
-       the chip its bucket tensor differed from this leg's and four
-       served signatures came out wrong (PR 22) — it is off the
-       dispatch until tests/test_pallas_mxu.py's on-chip parity case
-       passes (ROADMAP S3).
+       reduction ignores (identity-safe).  (A VMEM-resident Pallas
+       twin was wrong on the v5e at PR 22 and was removed at PR 30.)
     2. bucket close — descending suffix-sum scan over the 2**c - 1
        non-zero buckets: run += B_b; tot += run computes
        Σ_b b·B_b in 2 adds per bucket, for every window in parallel.

@@ -49,7 +49,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..fields import device as fd
 from . import device as gd
 
 _FORMAT_VERSION = 1
@@ -207,38 +206,6 @@ def host_table(
         return table
 
 
-# Measured per-curve comb-width overrides, keyed (curve, on_tpu).  CPU
-# probe at batch 2048 fixed-base muls: BLS12-381 w=8 halves w=4
-# (3.80 s vs 7.63 s — half the gathered adds beats the 16x table), same
-# shape as the 16-limb curves, so no CPU override is needed; the table
-# exists so a TPU remeasure can pin a curve without touching dispatch.
-_COMB_WINDOW: dict[tuple[str, bool], int] = {}
-
-
-def _default_window(cs: gd.CurveSpec | None = None) -> int:
-    """Mirrors groups.device.fixed_base_table's dispatch: the validated
-    DKG_TPU_FB_WINDOW override, then the measured per-curve table, else
-    16 on TPU (device-composed) and 8 elsewhere (host-built)."""
-    from ..utils import envknobs
-
-    window = envknobs.pos_int(
-        "DKG_TPU_FB_WINDOW", "fixed-base window width in bits: 4, 8 or 16"
-    )
-    if window is not None:
-        if window not in (4, 8, 16):
-            raise ValueError(
-                f"DKG_TPU_FB_WINDOW={window}: expected a fixed-base "
-                "window width of 4, 8 or 16 bits"
-            )
-        return window
-    on_tpu = fd._on_tpu()
-    if cs is not None:
-        hit = _COMB_WINDOW.get((cs.name, on_tpu))
-        if hit is not None:
-            return hit
-    return 16 if on_tpu else gd.FIXED_WINDOW
-
-
 def base_table(cs: gd.CurveSpec, base, window: int | None = None) -> jax.Array:
     """Device window table for a fixed base, persistently cached.
 
@@ -251,7 +218,7 @@ def base_table(cs: gd.CurveSpec, base, window: int | None = None) -> jax.Array:
     host table (one batched add + one batched inversion).
     """
     if window is None:
-        window = _default_window(cs)
+        window = gd.default_fixed_window()
     key = gd.base_key(cs, base)
     ck = (cs.name, key, window)
     with _BUILD_LOCK:

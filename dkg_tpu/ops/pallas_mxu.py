@@ -1,9 +1,9 @@
-"""MXU-native Pallas kernels: fused multiply-reduce and bucket-accumulate.
+"""MXU-native Pallas kernel: the fused multiply-reduce.
 
-Two kernels that move the hottest inner loops off the VPU schoolbook
-tier (ops/pallas_field.py) and onto the matmul unit, the way the
-AI-ASIC ZKP literature maps big-int arithmetic onto accelerator GEMMs —
-limb products and reduction folds become small bounded-partial-sum f32
+It moves the hottest inner loop off the VPU schoolbook tier
+(ops/pallas_field.py) and onto the matmul unit, the way the AI-ASIC ZKP
+literature maps big-int arithmetic onto accelerator GEMMs — limb
+products and reduction folds become small bounded-partial-sum f32
 matmuls that are *exact* because every partial column sum stays below
 float32's 2**24 integer range:
 
@@ -17,16 +17,6 @@ float32's 2**24 integer range:
   one-hot matmul (no dynamic gather inside the kernel).  Bit-exact
   against ``fields.device.mul``; the XLA twin of the same formulation
   is ``fields.device._mul_gemm`` (the CPU leg's win).
-* :func:`bucket_accumulate` — the Pippenger scatter pass
-  (groups/device.py msm_pippenger) with the bucket array VMEM-resident:
-  per point, the current bucket per window is gathered with a one-hot
-  matmul over the bucket lanes, added through the complete formulas
-  (ops/pallas_point.py row cores), and written back with a branchless
-  lane select.  The XLA leg's per-point ``(…, nw, entries)`` one-hot
-  and whole-tensor ``jnp.where`` never materialize in HBM.  NOT
-  dispatched: it matches the XLA leg in interpret mode and compiles for
-  the v5e, but differed from it on the chip (PR 22, ROADMAP S3) —
-  reachable only directly, from its tests.
 
 Layout contract matches ops/pallas_field.py: limbs on the sublane axis,
 batch on the lane axis; all field/curve constants are baked Python-int
@@ -52,10 +42,6 @@ from .pallas_field import BLOCK, _cond_sub, _mul_columns, _normalize
 
 #: lane width of the second-level quotient-table one-hot (one VPU row)
 _QL = 128
-#: interpret-mode bucket kernels unroll the point loop up to this m
-#: (the fori_loop lowering is slow to build in interpret mode but keeps
-#: trace size flat — the right trade only once the unroll gets large)
-_BUCKET_UNROLL_MAX = 64
 
 
 def _mask16(x):
@@ -243,152 +229,3 @@ def mxu_mod_mul(
     out_t = _mxu_mul_tiles(fs, af.T, bf.T, interp)
     return jnp.reshape(out_t.T[:n], batch + (fs.limbs,))
 
-
-# ---------------------------------------------------------------------------
-# Pippenger bucket-accumulate
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.jit, static_argnums=(0, 3, 4, 5))
-def _bucket_call(cs, pts_t, digs_t, window: int, nw: int, interpret: bool):
-    """One grid step per (flattened) batch element; the whole
-    (C·L, nw·2**window) bucket tile stays VMEM-resident across the
-    m-point loop."""
-    from . import pallas_field as pf
-    from .pallas_point import _add_rows, _identity_rows
-
-    L, C = cs.field.limbs, cs.ncoords
-    entries = 1 << window
-    lanes = nw * entries
-    m_pad = pts_t.shape[1]
-    extra, extra_specs = pf.mxu_operands(cs.field, interpret)
-
-    def kernel(pts_ref, digs_ref, *rest):
-        out_ref = rest[-1]
-        # one-hot layout constants from iota (Pallas kernels cannot
-        # capture array constants): lane q holds bucket q % entries of
-        # window q >> window_bits
-        lane_win = jax.lax.broadcasted_iota(jnp.int32, (nw, lanes), 1) >> window
-        expand = _onehot(jax.lax.broadcasted_iota(jnp.int32, (nw, lanes), 0) == lane_win)
-        gather = _onehot(
-            (jax.lax.broadcasted_iota(jnp.int32, (lanes, nw), 0) >> window)
-            == jax.lax.broadcasted_iota(jnp.int32, (lanes, nw), 1)
-        )
-        eid = (
-            jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1) & (entries - 1)
-        ).astype(jnp.float32)
-        eye = jax.lax.broadcasted_iota(
-            jnp.int32, (C * L, C * L), 0
-        ) == jax.lax.broadcasted_iota(jnp.int32, (C * L, C * L), 1)
-        ident = _identity_rows(cs, jnp.zeros((1, lanes), jnp.uint32))
-        for c in range(C):
-            for i in range(L):
-                out_ref[0, c * L + i : c * L + i + 1, :] = ident[c][i]
-
-        def body(mm, carry):
-            bt = out_ref[0]  # (C·L, lanes) uint32, limbs < 2**16
-            row = slice(mm, mm + 1) if isinstance(mm, int) else pl.dslice(mm, 1)
-            dig = digs_ref[0, row, :]
-            # point mm is a (1, C·L) sublane row (a dynamic width-1 LANE
-            # slice is not provably 128-aligned for Mosaic); turn it into
-            # a column through the diagonal of its broadcast
-            ptrow = jnp.broadcast_to(_u2f(pts_ref[0, row, :]), (C * L, C * L))
-            ptcol = _f2u(
-                jnp.sum(jnp.where(eye, ptrow, jnp.float32(0)), axis=1, keepdims=True)
-            )
-            # dig_exp[0, q] = digit of window q//entries — exact f32
-            dig_exp = jnp.dot(
-                dig.astype(jnp.float32), expand, preferred_element_type=jnp.float32
-            )
-            mask = eid == dig_exp  # (1, lanes): this point's bucket per window
-            # gather the selected bucket per window: exactly one nonzero
-            # per (row, window), limb values < 2**16 — exact f32 matmul
-            cur = _f2u(
-                jnp.dot(
-                    jnp.where(mask, _u2f(bt), jnp.float32(0)),
-                    gather,
-                    preferred_element_type=jnp.float32,
-                )
-            )  # (C·L, nw)
-            cur_rows = tuple(
-                [cur[c * L + i : c * L + i + 1, :] for i in range(L)] for c in range(C)
-            )
-            pt = jnp.broadcast_to(ptcol, (C * L, nw))
-            pt_rows = tuple(
-                [pt[c * L + i : c * L + i + 1, :] for i in range(L)] for c in range(C)
-            )
-            new_rows = _add_rows(cs, cur_rows, pt_rows)
-            new_mat = jnp.concatenate(
-                [r for coord in new_rows for r in coord], axis=0
-            )  # (C·L, nw)
-            # scatter back: expand each window's sum across its lanes,
-            # commit only the masked lane (digit-0 lands in bucket 0,
-            # ignored downstream exactly like the XLA scan leg)
-            new_exp = _f2u(
-                jnp.dot(_u2f(new_mat), expand, preferred_element_type=jnp.float32)
-            )
-            out_ref[0] = jnp.where(mask, new_exp, bt)
-            return carry
-
-        with pf.rows_mul_context(cs.field, rest[:-1]):
-            if interpret and m_pad <= _BUCKET_UNROLL_MAX:
-                for i in range(m_pad):
-                    body(i, 0)
-            else:
-                jax.lax.fori_loop(0, m_pad, body, 0)
-
-    B = pts_t.shape[0]
-    return pl.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, m_pad, C * L), lambda i: (i, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m_pad, nw), lambda i: (i, 0, 0), memory_space=pltpu.VMEM),
-        ]
-        + extra_specs,
-        out_specs=pl.BlockSpec(
-            (1, C * L, lanes), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, C * L, lanes), jnp.uint32),
-        interpret=interpret,
-    )(pts_t, digs_t, *extra)
-
-
-def bucket_accumulate(
-    cs,
-    points: jax.Array,
-    digits: jax.Array,
-    window: int,
-    nw: int,
-    *,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Pippenger scatter pass with VMEM-resident buckets.
-
-    points (..., m, C, L), digits (..., m, nw) ->
-    buckets (..., nw, 2**window, C, L) — bit-identical to the XLA scan
-    leg's bucket tensor (same add order through the same complete
-    formulas), so groups.device's bucket-close and window-combine
-    passes run unchanged on either leg.
-    """
-    metrics.REGISTRY.inc("pallas_calls_total", kernel="bucket_accumulate")
-    L, C = cs.field.limbs, cs.ncoords
-    entries = 1 << window
-    batch = points.shape[:-3]
-    m = points.shape[-3]
-    b = 1
-    for d in batch:
-        b *= int(d)
-    pts = jnp.reshape(jnp.asarray(points, jnp.uint32), (b, m, C * L))
-    digs = jnp.reshape(jnp.asarray(digits, jnp.int32), (b, m, nw))
-    interp = _want_interpret() if interpret is None else interpret
-    m_pad = m if interp else max(BLOCK, -(-m // BLOCK) * BLOCK)
-    if m_pad != m:
-        # sentinel digit == entries never matches a bucket lane, so the
-        # padding points are computed but never committed
-        pts = jnp.pad(pts, [(0, 0), (0, m_pad - m), (0, 0)])
-        digs = jnp.pad(digs, [(0, 0), (0, m_pad - m), (0, 0)], constant_values=entries)
-    out = _bucket_call(cs, pts, digs, window, nw, interp)  # (B, C·L, lanes)
-    buckets = jnp.reshape(out, (b, C, L, nw, entries))
-    buckets = jnp.transpose(buckets, (0, 3, 4, 1, 2))
-    return jnp.reshape(buckets, batch + (nw, entries, C, L))

@@ -21,7 +21,6 @@ it to the caller.
 from __future__ import annotations
 
 import functools
-import os
 import time
 
 import jax
@@ -42,44 +41,20 @@ def _shard_map_nocheck(f, *, mesh, in_specs, out_specs):
 
 from ..dkg import ceremony as ce
 from ..groups import device as gd
+from ..utils import envknobs
 from jax import lax
 
 PARTY_AXIS = "parties"
 
-# Env knobs whose values are read at TRACE time and baked into the
-# compiled sharded programs (chunk widths, field mul/reduce/carry
-# formulation, MSM/window schedule, fused tiers, digest dispatch).  The
-# memoized program builders below put a snapshot of these values into
-# their cache key, so flipping a knob between calls retraces — the
-# semantics per-call eager tracing always had — while a steady-state
-# rerun at stable knobs reuses the jitted executable instead of
-# recompiling the whole sharded program set: before this cache the
-# north-star warm run cost the same as the cold one (NORTHSTAR r01
-# measured warm 135.6 s vs cold 126.0 s at (16, 5) on the CPU mesh —
-# pure retrace).
-_TRACE_KNOBS = (
-    "DKG_TPU_DEAL_CHUNK",
-    "DKG_TPU_VERIFY_CHUNK",
-    "DKG_TPU_RLC_CHUNK",
-    "DKG_TPU_MSM",
-    "DKG_TPU_FB_WINDOW",
-    "DKG_TPU_FUSED_MULTI",
-    "DKG_TPU_ED_FUSED_LADDER",
-    "DKG_TPU_ED_FUSED_DOUBLES",
-    "DKG_TPU_PALLAS",
-    "DKG_TPU_ASSUME_BACKEND",
-    "DKG_TPU_REDUCE",
-    "DKG_TPU_CARRY",
-    "DKG_TPU_MUL",
-    "DKG_TPU_MXU",
-    "DKG_TPU_DIGEST",
-)
-
-
-def _knob_state() -> tuple:
-    """Snapshot of the trace-relevant knobs (empty == unset, matching
-    envknobs' convention) — the program builders' cache-key tail."""
-    return tuple(os.environ.get(k) or None for k in _TRACE_KNOBS)
+# The memoized program builders below put envknobs.program_shape() —
+# the knobs read at TRACE time and baked into the compiled sharded
+# programs — into their cache key, so flipping a knob between calls
+# retraces (the semantics per-call eager tracing always had) while a
+# steady-state rerun at stable knobs reuses the jitted executable
+# instead of recompiling the whole sharded program set: before this
+# cache the north-star warm run cost the same as the cold one
+# (NORTHSTAR r01 measured warm 135.6 s vs cold 126.0 s at (16, 5) on
+# the CPU mesh — pure retrace).
 
 
 def _verify_env_chunk() -> int | None:
@@ -177,7 +152,7 @@ def sharded_deal_commitments(
     in one outer jit — that fuses them back into one program.
     """
     _check_mesh(cfg, mesh)
-    step = _deal_commitments_prog(cfg, mesh, _knob_state())
+    step = _deal_commitments_prog(cfg, mesh, envknobs.program_shape())
     return step(coeffs_a, coeffs_b, g_table, h_table)
 
 
@@ -212,7 +187,7 @@ def sharded_deal_shares(
     """Round-1 share program: (s, r), dealer-sharded (second of the two
     sequential deal programs; see :func:`sharded_deal_commitments`)."""
     _check_mesh(cfg, mesh)
-    return _deal_shares_prog(cfg, mesh, _knob_state())(coeffs_a, coeffs_b)
+    return _deal_shares_prog(cfg, mesh, envknobs.program_shape())(coeffs_a, coeffs_b)
 
 
 @functools.lru_cache(maxsize=None)
@@ -271,7 +246,7 @@ def sharded_verify_finalise(
     recipient-sharded, master replicated.
     """
     _check_mesh(cfg, mesh)
-    step = _verify_finalise_prog(cfg, mesh, rho_bits, _knob_state())
+    step = _verify_finalise_prog(cfg, mesh, rho_bits, envknobs.program_shape())
     return step(a0, e, s, r, g_table, h_table, rho)
 
 
@@ -428,7 +403,7 @@ def sharded_finalise(
     (the blame path re-finalise: no verification work — the pairwise
     checks already determined exactly which dealers are out)."""
     _check_mesh(cfg, mesh)
-    return _finalise_prog(cfg, mesh, _knob_state())(a0, s, qualified)
+    return _finalise_prog(cfg, mesh, envknobs.program_shape())(a0, s, qualified)
 
 
 @functools.lru_cache(maxsize=None)
@@ -474,7 +449,7 @@ def sharded_blame(
     mults per shard.
     """
     _check_mesh(cfg, mesh)
-    return _blame_prog(cfg, mesh, _knob_state())(e, s, r, g_table, h_table)
+    return _blame_prog(cfg, mesh, envknobs.program_shape())(e, s, r, g_table, h_table)
 
 
 @functools.lru_cache(maxsize=None)

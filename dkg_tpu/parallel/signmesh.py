@@ -96,7 +96,7 @@ def sign_folded_sharded(curve: str, sigma_limbs, h_dev, mesh: pm.Mesh):
             [hh, jnp.zeros((pad,) + hh.shape[1:], hh.dtype)], axis=0
         )
 
-    out = _ladder_prog(curve, mesh, pm._knob_state())(kk, hh)
+    out = _ladder_prog(curve, mesh, envknobs.program_shape())(kk, hh)
     return out[:b] if pad else out
 
 

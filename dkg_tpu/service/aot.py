@@ -80,29 +80,6 @@ _TRACED_SOURCES = (
     "poly",
 )
 
-#: Knobs that change the traced program at fixed shapes: two processes
-#: with different tiers must never serve each other's executables, so
-#: the resolved tier string is bound into every artifact digest.
-_TIER_KNOBS = (
-    "DKG_TPU_REDUCE",
-    "DKG_TPU_CARRY",
-    "DKG_TPU_MUL",
-    "DKG_TPU_MXU",
-    "DKG_TPU_PALLAS",
-    "DKG_TPU_FUSED_MULTI",
-    "DKG_TPU_ED_FUSED_LADDER",
-    "DKG_TPU_ED_FUSED_DOUBLES",
-    "DKG_TPU_MSM",
-    "DKG_TPU_FB_WINDOW",
-    "DKG_TPU_DIGEST",
-    "DKG_TPU_DEAL_CHUNK",
-    "DKG_TPU_VERIFY_CHUNK",
-    "DKG_TPU_RLC",
-    "DKG_TPU_RLC_CHUNK",
-    "DKG_TPU_DEM",
-    "DKG_TPU_DEM_CHUNK",
-)
-
 #: Build-stage buckets: one program's trace or compile runs from
 #: milliseconds to many minutes (a (1024,341) verify on a serving host).
 _BUILD_BUCKETS = (0.1, 1.0, 5.0, 15.0, 30.0, 60.0, 120.0, 300.0, 600.0, 1200.0)
@@ -144,13 +121,11 @@ def cache_dir() -> str:
 
 
 def knob_tier() -> str:
-    """Canonical ``k=v`` string of every set program-shaping knob."""
-    parts = []
-    for name in _TIER_KNOBS:
-        v = envknobs.string(name, "program-shaping knob (AOT tier)")
-        if v is not None:
-            parts.append(f"{name}={v}")
-    return ",".join(parts)
+    """Canonical ``k=v`` string of every set program-shaping knob
+    (``envknobs.program_shape``): two processes with different tiers
+    must never serve each other's executables, so it is bound into
+    every artifact digest."""
+    return ",".join(f"{k}={v}" for k, v in envknobs.program_shape())
 
 
 def spec_sig(args: tuple) -> tuple:

@@ -1,28 +1,18 @@
-"""Validated environment-knob parsing shared across modules.
+"""Validated environment-knob parsing shared across modules, and the
+one list of the knobs that shape a traced program.
 
 Every DKG_TPU_* knob that silently mis-parsing could turn into a wrong
 (possibly OOM or wrong-kernel) compiled program goes through here, so
-the validate-and-raise behavior cannot drift between copies (knobs:
-DKG_TPU_DEAL_CHUNK / DKG_TPU_VERIFY_CHUNK / DKG_TPU_RLC_CHUNK via
-dkg.ceremony._env_chunk, DKG_TPU_DEM / DKG_TPU_DEM_CHUNK via
-dkg.hybrid_batch, DKG_TPU_RLC via dkg.ceremony._point_rlc,
-DKG_TPU_MSM / DKG_TPU_FB_WINDOW / DKG_TPU_FUSED_MULTI /
-DKG_TPU_ED_FUSED_LADDER / DKG_TPU_ED_FUSED_DOUBLES via groups.device,
-DKG_TPU_PALLAS / DKG_TPU_ASSUME_BACKEND / DKG_TPU_REDUCE
-(fold|linear|barrett — force a wide-reduction algorithm; inadmissible
-choices raise at trace time) / DKG_TPU_CARRY (scan|lookahead carry
-propagation in normalize) / DKG_TPU_MUL (auto|gemm|classic — the
-fd.mul formulation: fused GEMM multiply-reduce twin vs
-mul_wide+reduce_wide; gemm raises at trace time on fields that fail
-the spec.mulred admission proofs) via fields.device,
-DKG_TPU_MXU via fields.matmul, DKG_TPU_TABLE_CACHE via
+the validate-and-raise behavior cannot drift between copies.  The
+knobs read under a tracer are :data:`PROGRAM_SHAPING`, each with its
+reader beside it; the others: DKG_TPU_DEM (scalar|batch host DEM leg)
+via dkg.hybrid_batch, DKG_TPU_TABLE_CACHE via
 groups.precompute, DKG_TPU_NET_* transport knobs via net.channel,
 DKG_TPU_SIGN_BATCH (device message-chunk size) and
 DKG_TPU_SIGN_DISPATCH (device|host partial-signature leg) via
 sign.partial — lint rule DKG009 bans raw environment access and
 per-message scalar-mul loops in dkg_tpu/sign/ hot paths,
 DKG_TPU_CHECKPOINT_DIR via net.checkpoint,
-DKG_TPU_DIGEST via crypto.device_hash.digest_dispatch,
 DKG_TPU_OBSLOG flight-recorder log directory via utils.obslog,
 DKG_TPU_SERVICE_CONCURRENCY / DKG_TPU_SERVICE_QUEUE_DEPTH /
 DKG_TPU_SERVICE_BATCH_MAX / DKG_TPU_SERVICE_DEADLINE_S /
@@ -82,6 +72,38 @@ the default path, not raise.
 from __future__ import annotations
 
 import os
+
+#: Every knob a traced function reads: its value is baked into the
+#: compiled program at fixed shapes.  Whatever keeps a program past the
+#: trace that made it — the memoized sharded builders of parallel/mesh
+#: and parallel/signmesh, the AOT executable store's digest header
+#: (service/aot) — keys on :func:`program_shape`, so a knob flipped
+#: between calls, or between two processes sharing a store, retraces
+#: instead of being served the other side's program.  A new knob read
+#: under a tracer is added HERE and nowhere else
+#: (tests/test_program_shape.py scans the traced sources for names
+#: missing from this tuple).
+PROGRAM_SHAPING = (
+    "DKG_TPU_ASSUME_BACKEND",  # fields.device._on_tpu: every backend-matched default
+    "DKG_TPU_PALLAS",  # fields.device.fused_kernels_active
+    "DKG_TPU_MUL",  # fields.device.mul_dispatch_mode, ops.pallas_field
+    "DKG_TPU_MXU",  # fields.matmul.mxu_matmul_active
+    "DKG_TPU_MSM",  # groups.device: MSM algorithm
+    "DKG_TPU_ED_FUSED_LADDER",  # groups.device: Edwards fused ladder
+    "DKG_TPU_ED_FUSED_DOUBLES",  # groups.device: Edwards split-fused window
+    "DKG_TPU_RLC",  # dkg.ceremony._point_rlc schedule
+    "DKG_TPU_RLC_CHUNK",  # dkg.ceremony._point_rlc column chunk
+    "DKG_TPU_VERIFY_CHUNK",  # parallel.mesh: recipient-axis chunk
+    "DKG_TPU_DIGEST",  # crypto.device_hash.digest_dispatch
+)
+
+
+def program_shape() -> tuple:
+    """``(name, value)`` of every SET knob of :data:`PROGRAM_SHAPING`,
+    in its order (empty is unset, as everywhere here).  Raw values: a
+    key has to tell two programs apart, not validate — the reader
+    raises on a typo when it traces."""
+    return tuple((name, v) for name in PROGRAM_SHAPING if (v := os.environ.get(name)))
 
 
 def choice(name: str, options: tuple, what: str) -> str | None:

@@ -17,7 +17,7 @@ Usage (no chip needed — the TPU compiler runs against a described topology):
 
 Knobs (utils.envknobs): ``DKG_TPU_AOT_TOPOLOGY`` picks the chip-less topology to compile for
 (default ``v5e:2x2``), ``DKG_TPU_ASSUME_BACKEND`` the flag-resolution
-backend, ``DKG_TPU_FB_WINDOW`` the fixed-base window.
+backend.
 
 Prints one JSON line per compiled phase with memory analysis.
 """
@@ -55,7 +55,7 @@ from dkg_tpu.dkg import ceremony as ce
 N = int(sys.argv[1]) if len(sys.argv) > 1 else 4096
 T = int(sys.argv[2]) if len(sys.argv) > 2 else 1365
 CURVE = sys.argv[3] if len(sys.argv) > 3 else "secp256k1"
-WINDOW = envknobs.pos_int("DKG_TPU_FB_WINDOW", "fixed-base window bits") or 16
+WINDOW = 16  # the fixed-base window of the on-chip default (gd.default_fixed_window)
 TOPOLOGY = (
     envknobs.string("DKG_TPU_AOT_TOPOLOGY", "chip-less AOT compile topology")
     or "v5e:2x2"

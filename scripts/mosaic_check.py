@@ -3,8 +3,7 @@
 
 Compiles and executes the fused Pallas kernels (mod_mul, mod_madd,
 pt_add, pt_window_step, pt_ladder_mul_add, plus the MXU tier's
-mxu_mod_mul fused multiply-reduce and the Pippenger bucket_accumulate
-scatter kernel) at the smallest real shapes
+mxu_mod_mul fused multiply-reduce) at the smallest real shapes
 on the chip, BEFORE any bench rung touches them — so a BlockSpec/layout
 rejection or a pathological Mosaic compile surfaces as a five-minute
 diagnosis instead of a lost bench run (the round-3 48-minute silent
@@ -157,23 +156,6 @@ def main() -> int:
         got = [int(v) for v in fh.decode(fs, np.asarray(out))]
         return got == [x * y % fs.modulus for x, y in zip(xs, ys)]
 
-    def chk_bucket():
-        # Pippenger scatter pass with VMEM-resident buckets, vs the XLA
-        # scan leg bit-for-bit; m=20 exercises the sentinel-digit
-        # padding (m rounds up to a BLOCK multiple on Mosaic)
-        m, window, nw = 20, 4, 4
-        entries = 1 << window
-        bp_host = [group.scalar_mul(rng.randrange(1, 100), g) for _ in range(m)]
-        bp_dev = gd.from_host(cs, bp_host)
-        digs = jnp.asarray(
-            [[rng.randrange(entries) for _ in range(nw)] for _ in range(m)],
-            jnp.int32,
-        )
-        out = pm.bucket_accumulate(cs, bp_dev, digs, window, nw, interpret=False)
-        sync(out)
-        want = gd._bucket_scan(cs, bp_dev, digs, entries)
-        return bool(jnp.all(out == want))
-
     results = [
         step("mod_mul", chk_mul),
         step("mod_madd", chk_madd),
@@ -181,7 +163,6 @@ def main() -> int:
         step("pt_window_step", chk_window),
         step("pt_ladder_mul_add", chk_ladder),
         step("mxu_mod_mul", chk_mxu_mul),
-        step("bucket_accumulate", chk_bucket),
     ]
     ok = all(results)
     print(json.dumps({"mosaic_check": "pass" if ok else "fail"}), flush=True)

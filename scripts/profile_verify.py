@@ -4,12 +4,12 @@ each stage ends in block_until_ready.
 
 Usage:  python scripts/profile_verify.py [N] (from the repo root, on a
 machine with the chip).  Feature flags come from the environment exactly as
-in production (DKG_TPU_PALLAS / DKG_TPU_MXU / DKG_TPU_FB_WINDOW), so
+in production (DKG_TPU_PALLAS / DKG_TPU_MXU), so
 one run per flag set isolates a regression:
 
     python scripts/profile_verify.py 256                     # defaults
     DKG_TPU_PALLAS=0 python scripts/profile_verify.py 256    # no fused kernels
-    DKG_TPU_PALLAS=0 DKG_TPU_MXU=0 DKG_TPU_FB_WINDOW=8 DKG_TPU_RLC=bits \
+    DKG_TPU_PALLAS=0 DKG_TPU_MXU=0 DKG_TPU_RLC=bits \
         python scripts/profile_verify.py 256                 # round-1 config
     DKG_TPU_RLC=straus|bits  # force the point-RLC schedule independently
 
@@ -44,27 +44,14 @@ _sync = jax.block_until_ready
 
 print(
     f"flags: PALLAS={os.environ.get('DKG_TPU_PALLAS', '<default>')} "
-    f"MXU={os.environ.get('DKG_TPU_MXU', '<default>')} "
-    f"FB_WINDOW={os.environ.get('DKG_TPU_FB_WINDOW', '<default>')}",
+    f"MXU={os.environ.get('DKG_TPU_MXU', '<default>')}",
     flush=True,
 )
 
-# Stage order is failure-ordered: the ceremony stages run on the SAFE
-# host-built 8-bit tables first (unless the caller forced a width), and
-# the window-16 DEVICE build — the stage that stalled the whole round-4
-# default profile — is attempted LAST, so a build stall costs only the
-# final stage, not the profile.
-_forced_window = os.environ.get("DKG_TPU_FB_WINDOW")
-if _forced_window is None:
-    os.environ["DKG_TPU_FB_WINDOW"] = "8"
 _t0 = time.perf_counter()
 c = ce.BatchedCeremony("secp256k1", N, T, b"bench", random.Random(7))
 _sync(c.h_table)
-print(
-    f"{'setup: tables+coeffs':26s} {time.perf_counter()-_t0:8.3f} s   "
-    f"(fb_window={os.environ['DKG_TPU_FB_WINDOW']})",
-    flush=True,
-)
+print(f"{'setup: tables+coeffs':26s} {time.perf_counter()-_t0:8.3f} s", flush=True)
 cfg = c.cfg
 cs = cfg.cs
 fs = cs.scalar
@@ -142,12 +129,3 @@ lhs = timed(
 )
 ok = timed("verify: eq", jax.jit(lambda p, q: gd.eq(cs, p, q)), lhs, rhs)
 print("all ok:", bool(jnp.all(ok)), flush=True)
-
-# --- LAST: the wide-window device table build (round-4 stall suspect) ------
-if _forced_window is None:
-    from dkg_tpu.groups import host as gh
-
-    _t0 = time.perf_counter()
-    t16 = gd.fixed_base_table_dev(cs, gh.ALL_GROUPS["secp256k1"].generator(), 16)
-    _sync(t16)
-    print(f"{'table build w16 (device)':26s} {time.perf_counter()-_t0:8.3f} s", flush=True)

@@ -10,14 +10,13 @@ toy programs compile in well under a second):
   math the kernel runs, no pallas machinery — plus dispatch-rule unit
   tests and the full ``mxu_mod_mul`` pallas_call on the toy field.
 * **Slow tier**: interpret-mode pallas_call parity on the real fields
-  (``mxu_mod_mul``: edge lanes, ragged broadcast batches) and the
-  bucket-accumulate kernel vs the XLA scan leg on toy curves.
+  (``mxu_mod_mul``: edge lanes, ragged broadcast batches).
   ``DKG_TPU_MUL=gemm`` forced through toy field/point kernels covers
   the ``rows_mul_context`` seam the fused point kernels chain the MXU
   core through (``auto`` keeps Barrett under interpret precisely
   because of the compile pathology above).
-* **TPU tier** (Mosaic compiles these in seconds): real-curve bucket
-  parity and per-field ``mxu_mod_mul`` on the hardware path.
+* **TPU tier** (Mosaic compiles these in seconds): per-field
+  ``mxu_mod_mul`` on the hardware path.
 """
 
 import os
@@ -33,7 +32,6 @@ from dkg_tpu.fields import device as fd
 from dkg_tpu.fields import host as fh
 from dkg_tpu.fields.spec import ALL_FIELDS, FieldSpec
 from dkg_tpu.groups import device as gd
-from dkg_tpu.groups import host as gh
 from dkg_tpu.ops import pallas_field as pf
 from dkg_tpu.ops import pallas_mxu as pm
 from dkg_tpu.ops import pallas_point as pp
@@ -241,36 +239,6 @@ def test_point_kernel_gemm_forced_toy(cs, monkeypatch):
     assert jnp.all(got == gd._add_xla(cs, p, q))
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("cs", TOY_CURVES, ids=lambda c: c.kind)
-def test_bucket_accumulate_toy_matches_scan(cs):
-    """Bucket-accumulate kernel vs the XLA scan leg on the toy curves:
-    bit-identical bucket tensors (same add order through the same
-    complete formulas).  Includes identity points, digit-0 lanes (land
-    in bucket 0, ignored downstream), and a batched shape."""
-    window, nw = 4, 3
-    entries = 1 << window
-    m = 6
-    pts = np.asarray(_toy_points_dev(cs, m)).copy()
-    pts[2] = np.asarray(gd.identity(cs, ()))  # an identity point mid-stream
-    pts = jnp.asarray(pts)
-    rng = np.random.default_rng(3)
-    digs = rng.integers(0, entries, size=(m, nw))
-    digs[4, :] = 0  # digit-0 lanes
-    digs = jnp.asarray(digs, jnp.int32)
-    got = pm.bucket_accumulate(cs, pts, digs, window, nw, interpret=True)
-    want = gd._bucket_scan(cs, pts, digs, entries)
-    assert got.shape == want.shape == (nw, entries, cs.ncoords, cs.field.limbs)
-    assert jnp.all(got == want)
-
-    # batched: leading axis threads through the flattened kernel grid
-    bpts = jnp.stack([pts[:5], pts[1:6]])
-    bdigs = jnp.stack([digs[:5, :2], digs[1:6, :2]])
-    got_b = pm.bucket_accumulate(cs, bpts, bdigs, window, 2, interpret=True)
-    want_b = gd._bucket_scan(cs, bpts, bdigs, entries)
-    assert jnp.all(got_b == want_b)
-
-
 # --------------------------------------------------------------------------
 # TPU tier: Mosaic kernel parity on real curves/fields
 # --------------------------------------------------------------------------
@@ -285,33 +253,3 @@ def test_kernel_mxu_mod_mul_all_fields_tpu():
         got = fh.decode(fs, np.asarray(pm.mxu_mod_mul(fs, a, b, interpret=False)))
         for g, x, y in zip(got, xs, ys):
             assert int(g) == x * y % fs.modulus, name
-
-
-@needs_tpu
-@pytest.mark.parametrize("curve", ["secp256k1"])
-def test_kernel_bucket_matches_scan_tpu(curve):
-    # FAILS on a v5e as of PR 22 (the kernel compiles, the buckets
-    # differ): that failure is why _msm_pippenger_core runs the scan leg
-    # on every backend — put the kernel back only when this passes on
-    # the chip (ROADMAP S3).
-    # Edwards is deliberately absent for the same reason as
-    # test_pallas_point.py's ladder test: Mosaic hung compiling the
-    # multi-op Edwards kernel body on v5e, and the bucket kernel is a
-    # multi-op body.  m=20 also exercises the sentinel-digit padding
-    # (m_pad rounds up to a BLOCK multiple on the Mosaic path).
-    cs = gd.ALL_CURVES[curve]
-    host_group = gh.ALL_GROUPS[curve]
-    m, window, nw = 20, 4, 4
-    entries = 1 << window
-    pts = gd.from_host(
-        cs,
-        [
-            host_group.scalar_mul(host_group.random_scalar(RNG), host_group.generator())
-            for _ in range(m)
-        ],
-    )
-    rng = np.random.default_rng(9)
-    digs = jnp.asarray(rng.integers(0, entries, size=(m, nw)), jnp.int32)
-    got = pm.bucket_accumulate(cs, pts, digs, window, nw, interpret=False)
-    want = gd._bucket_scan(cs, pts, digs, entries)
-    assert jnp.all(got == want)

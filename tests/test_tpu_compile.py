@@ -28,7 +28,6 @@ from dkg_tpu.ops import pallas_mxu as pm
 from dkg_tpu.ops import pallas_point as pp
 
 B = 1024  # batch lanes
-M = 1024  # bucket kernel: points per MSM (pippenger_window picks w=8)
 
 
 @pytest.fixture(scope="module")
@@ -56,13 +55,6 @@ def _kernel(name: str, cs: gd.CurveSpec):
     """(function, operand shapes) for one kernel at the smoke's widths."""
     fs, L, C = cs.field, cs.field.limbs, cs.ncoords
     elem, point = (B, L), (B, C, L)
-    if name == "bucket_accumulate":
-        w = gd.pippenger_window(M, cs.name)
-        nw = -(-cs.scalar.limbs * 16 // w)
-        return (
-            lambda p, d: pm.bucket_accumulate(cs, p, d, w, nw, interpret=False),
-            [((4, M, C, L), jnp.uint32), ((4, M, nw), jnp.int32)],
-        )
     table = {
         "mod_mul": (lambda a, b: pf.mod_mul(fs, a, b, interpret=False), [elem] * 2),
         "mod_madd": (lambda a, b, c: pf.mod_madd(fs, a, b, c, interpret=False), [elem] * 3),
@@ -101,7 +93,6 @@ def _compiles_to_a_tpu_kernel(one_chip, curve: str, name: str) -> None:
         ("secp256k1", "pt_add"),
         ("secp256k1", "pt_madd"),
         ("secp256k1", "pt_double"),
-        ("secp256k1", "bucket_accumulate"),
         ("ristretto255", "pt_add"),
         ("secp256k1", "mod_pow_const"),
         ("ristretto255", "mod_pow_const"),
