@@ -299,6 +299,47 @@ def _field_dot(fs, weights: jax.Array, values: jax.Array) -> jax.Array:
     return acc
 
 
+def _straus_tiles(cs, weights: jax.Array, points: jax.Array, nbits: int, fused: bool) -> jax.Array:
+    """The Straus schedule of :func:`_point_rlc` in the point kernels'
+    lane-block form (``ops.pallas_point.to_tiles``): ``points`` is
+    converted once, (dealer, column) row-major onto lanes, the table's
+    entries 2P..15P, the tree over the dealers (``gd._tree_tiles``) and
+    the accumulator stay (nb, C·L, BLOCK) blocks, and the accumulator is
+    converted once at the end.  A lane's entry is a 16-way select on
+    its dealer's digit, one fusion over the table's entries: no gather.  The
+    packing follows from the shape: at (1024, 64) every level of the
+    tree but the last halves whole blocks; a (16, 6) ceremony is one
+    block, its levels lane slices of it.
+    """
+    from ..ops import pallas_point as pp
+
+    m, window = points.shape[0], gd.WINDOW
+    nd = -(-nbits // window)  # windows that can be non-zero
+    p_t, _, lanes = pp.to_tiles(cs, points)
+    nb, cols = p_t.shape[0], lanes // m
+    ident = pp.identity_tiles(cs)
+
+    def entry(prev, _):
+        nxt = pp.add_tiles(cs, prev, p_t)
+        return nxt, nxt
+
+    _, rest = lax.scan(entry, p_t, None, length=14)  # 2P..15P: (14, nb, C·L, BLOCK)
+    digits = gd.scalar_windows(cs, weights, window)[:, :nd]  # (m, nd)
+    lane_digits = jnp.repeat(jnp.moveaxis(digits, -1, 0)[::-1], cols, axis=1)  # (nd, lanes) MSB first
+    lane_digits = jnp.pad(lane_digits, ((0, 0), (0, nb * pp.BLOCK - lanes)))
+
+    def step(acc, dig):
+        dig = dig.reshape(nb, 1, pp.BLOCK)
+        contribs = jnp.where(dig == 1, p_t, ident)
+        for k in range(14):
+            contribs = jnp.where(dig == k + 2, rest[k], contribs)
+        total = gd._tree_tiles(cs, contribs, m, cols)
+        return gd.window_step(cs, acc, total, window, fused, tiles=True), None
+
+    acc, _ = lax.scan(step, pp.identity_tiles(cs, -(-cols // pp.BLOCK)), lane_digits)
+    return pp.from_tiles(cs, acc, points.shape[1:-2], cols)
+
+
 def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Array:
     """sum_j weights[j]·P[j, ...] for nbits-wide public weights.
 
@@ -318,6 +359,8 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
       TPU; the window step is the fused Pallas kernel when those are
       active, a plain XLA 4-double+add otherwise — so the conservative
       (no-Pallas) TPU configuration still gets the cheaper schedule.
+      With the fused kernels active the schedule runs in their lane-block
+      form (:func:`_straus_tiles`): one conversion in, one out.
     * **Bit-at-a-time ladder** — the compile-cheapest schedule, kept as
       the cross-platform parity leg (bench parity_check).
 
@@ -328,6 +371,13 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
     shape, so flipping the env var after a same-shape call reuses the
     already-traced schedule — set flags before the first call of a
     process (the bench's child-per-rung design exists exactly for this).
+
+    The schedules, and the two forms of Straus, add the dealers in
+    different orders: the result is the same group element, its
+    projective coordinates are NOT canonical.  Compare it with
+    ``gd.eq`` (as :func:`verify_batch` does); nothing reads its limbs.
+    Each traced schedule body books ``point_rlc_traced_total{schedule,
+    form}`` (a chunked call traces two: the map's body and the tail).
     """
     from ..utils import envknobs
 
@@ -344,6 +394,7 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
             if gd.fused_kernels_active() or fd._on_tpu()
             else "pippenger"
         )
+    tiles = mode == "straus" and gd.fused_kernels_active()
     if mode != "bits" and points.ndim > 3:
         # Chunk the first trailing batch axis so the per-chunk temps
         # (per-point Straus tables / Pippenger buckets) stay under
@@ -365,6 +416,8 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
         chunk = _env_chunk("DKG_TPU_RLC_CHUNK")
         if chunk is None:
             chunk = max(1, (256 << 20) // per_col)
+            if tiles:  # a power of two: the dealers' halves then fall on block edges
+                chunk = 1 << (chunk.bit_length() - 1)
         ncols = points.shape[1]
         if chunk and ncols > chunk:
             from ..utils.scanchunk import map_chunked
@@ -374,6 +427,12 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
                 return _point_rlc(cs, weights, cols, nbits)
 
             return map_chunked(ncols, chunk, col_chunk)
+
+    REGISTRY.inc(
+        "point_rlc_traced_total", schedule=mode, form="blocks" if tiles else "tensor"
+    )
+    if tiles:
+        return _straus_tiles(cs, weights, points, nbits, fused)
 
     if mode == "pippenger":
         # weights broadcast over the column axes; the m axis moves last

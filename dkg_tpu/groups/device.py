@@ -1097,7 +1097,8 @@ def encode_batch(cs: CurveSpec, pts) -> np.ndarray:
 
 
 def window_step(
-    cs: CurveSpec, acc: jax.Array, entry: jax.Array, window: int, fused: bool
+    cs: CurveSpec, acc: jax.Array, entry: jax.Array, window: int, fused: bool,
+    *, tiles: bool = False,
 ) -> jax.Array:
     """One Straus window step: ``window`` doublings then add ``entry``.
 
@@ -1105,21 +1106,27 @@ def window_step(
     :func:`msm`, :func:`_scalar_mul_core` and the ceremony point-RLC —
     with the fused kernels active the whole step is one Pallas launch
     (intermediates never touch HBM); otherwise plain XLA ops.
+
+    ``tiles``: ``acc``, ``entry`` and the result are the kernels' lane
+    blocks (``pallas_point.to_tiles``), for a caller that runs with the
+    fused kernels active and converts once; where the multi-op kernel
+    is not dispatched (Edwards) the step is then single-op launches,
+    one doubling each unless DKG_TPU_ED_FUSED_DOUBLES says more.
     """
-    if fused:
-        from ..ops import pallas_point
-
-        return pallas_point.pt_window_step(cs, acc, entry, window)
     k = _ed_fused_doubles() if cs.kind == "edwards" and fused_kernels_active() else 0
-    if k:
-        from ..ops import pallas_point
+    if fused or k or tiles:
+        from ..ops import pallas_point as pp
 
+        if fused:
+            step = pp.window_step_tiles if tiles else pp.pt_window_step
+            return step(cs, acc, entry, window)
+        dbl, add_ = (pp.double_tiles, pp.add_tiles) if tiles else (pp.pt_double, pp.pt_add)
         d = window
         while d > 0:
-            c = min(k, d)
-            acc = pallas_point.pt_double(cs, acc, c)
+            c = min(k or 1, d)
+            acc = dbl(cs, acc, c)
             d -= c
-        return pallas_point.pt_add(cs, acc, entry)
+        return add_(cs, acc, entry)
     for _ in range(window):
         acc = _double_xla(cs, acc)
     return _add_xla(cs, acc, entry)
@@ -1136,6 +1143,40 @@ def _tree_reduce(cs: CurveSpec, pts: jax.Array, axis_len: int) -> jax.Array:
         pts = add(cs, pts[..., 0::2, :, :], pts[..., 1::2, :, :])
         m //= 2
     return pts[..., 0, :, :]
+
+
+def _tree_tiles(cs: CurveSpec, x: jax.Array, m: int, cols: int) -> jax.Array:
+    """:func:`_tree_reduce` on lane blocks: ``x`` (nb, C·L, BLOCK) holds
+    m·cols lanes ordered (dealer, column) and the identity after them;
+    the result's first ``cols`` lanes are the sums over the dealers.
+
+    Each level adds the upper half of the dealers onto the lower (dealer
+    j + ceil(m/2) onto j: another pairing than :func:`_tree_reduce`'s
+    neighbours, so the same group element in other coordinates).  The
+    upper half is a slice of whole blocks where ceil(m/2)·cols is a
+    multiple of BLOCK, and of blocks and lanes where it is not; nothing
+    leaves the block form.  Lanes past the live ones hold sums nobody
+    reads, except before a level with an odd count, whose unpaired
+    dealer adds the lanes after the last: those are set to the identity.
+    """
+    from ..ops import pallas_point as pp
+
+    B = pp.BLOCK
+    while m > 1:
+        top = (m + 1) // 2
+        nout = -(-top * cols // B)
+        qb, r = divmod(top * cols, B)
+        short = qb + nout + (r > 0) - x.shape[0]
+        ext = jnp.concatenate([x, pp.identity_tiles(cs, short)]) if short > 0 else x
+        hi = ext[qb : qb + nout]
+        if r:
+            hi = jnp.concatenate([hi[..., r:], ext[qb + 1 : qb + nout + 1, :, :r]], axis=-1)
+        x = pp.add_tiles(cs, x[:nout], hi)
+        if top > 1 and top % 2:
+            lane = jnp.arange(nout * B).reshape(nout, 1, B)
+            x = jnp.where(lane < top * cols, x, pp.identity_tiles(cs))
+        m = top
+    return x
 
 
 def msm(cs: CurveSpec, scalars: jax.Array, points: jax.Array) -> jax.Array:

@@ -391,8 +391,29 @@ def _lane_blocks(flat: jax.Array) -> jax.Array:
     return jnp.swapaxes(jnp.reshape(flat, (m // BLOCK, BLOCK, rows)), 1, 2)
 
 
-def _to_tiles(cs: CurveSpec, pts: jax.Array) -> tuple[jax.Array, tuple, int]:
-    """(..., C, L) -> ((nb, C·L, BLOCK) lane blocks, batch_shape, n)."""
+def _identity_flat(cs: CurveSpec) -> np.ndarray:
+    ident = np.zeros((cs.ncoords, cs.field.limbs), np.uint32)
+    ident[1, 0] = 1
+    if cs.kind == "edwards":
+        ident[2, 0] = 1
+    return ident.reshape(-1)
+
+
+def identity_tiles(cs: CurveSpec, nb: int = 1) -> jax.Array:
+    """``nb`` lane blocks of the identity."""
+    flat = _identity_flat(cs)
+    return jnp.broadcast_to(jnp.asarray(flat)[None, :, None], (nb, flat.size, BLOCK))
+
+
+def to_tiles(cs: CurveSpec, pts: jax.Array) -> tuple[jax.Array, tuple, int]:
+    """(..., C, L) -> ((nb, C·L, BLOCK) lane blocks, batch_shape, n).
+
+    The form every point kernel works on: the batch flattened row-major
+    onto lanes, BLOCK to a block, the tail padded with the identity (so
+    padding lanes stay on-curve).  With :func:`from_tiles` the seam
+    between the tensor form and the ``*_tiles`` twins below, for a
+    caller that strings kernels together and converts once each way
+    (``dkg.ceremony._point_rlc``)."""
     L, C = cs.field.limbs, cs.ncoords
     batch = pts.shape[:-2]
     n = 1
@@ -401,40 +422,56 @@ def _to_tiles(cs: CurveSpec, pts: jax.Array) -> tuple[jax.Array, tuple, int]:
     m = max(BLOCK, ((n + BLOCK - 1) // BLOCK) * BLOCK)
     flat = jnp.reshape(pts, (n, C * L))
     if m != n:
-        # pad with the identity so padding lanes stay on-curve
-        ident = np.zeros((C, L), np.uint32)
-        ident[1, 0] = 1
-        if cs.kind == "edwards":
-            ident[2, 0] = 1
         flat = jnp.concatenate(
-            [flat, jnp.broadcast_to(jnp.asarray(ident.reshape(-1)), (m - n, C * L))]
+            [flat, jnp.broadcast_to(jnp.asarray(_identity_flat(cs)), (m - n, C * L))]
         )
     return _lane_blocks(flat), batch, n
 
 
-def _from_tiles(cs: CurveSpec, t: jax.Array, batch: tuple, n: int) -> jax.Array:
+def from_tiles(cs: CurveSpec, t: jax.Array, batch: tuple, n: int) -> jax.Array:
+    """The first ``n`` lanes of (nb, C·L, BLOCK) blocks as ``batch + (C, L)``."""
     L, C = cs.field.limbs, cs.ncoords
     flat = jnp.reshape(jnp.swapaxes(t, 1, 2), (-1, C * L))
     return jnp.reshape(flat[:n], batch + (C, L))
 
 
-def _interp() -> bool:
+def _interp(interpret: bool | None = None) -> bool:
     from ..fields import device as fd
 
-    return not fd._on_tpu()
+    return (not fd._on_tpu()) if interpret is None else interpret
+
+
+def add_tiles(cs: CurveSpec, p_t: jax.Array, q_t: jax.Array, *, interpret: bool | None = None) -> jax.Array:
+    """:func:`pt_add` on lane blocks: (nb, C·L, BLOCK) in and out."""
+    metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_add")
+    interp = _interp(interpret)
+    return jax.vmap(lambda pb, qb: _add_call(cs, pb, qb, interp))(p_t, q_t)
+
+
+def double_tiles(cs: CurveSpec, p_t: jax.Array, n_doubles: int = 1, *, interpret: bool | None = None) -> jax.Array:
+    """:func:`pt_double` on lane blocks."""
+    metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_double")
+    interp = _interp(interpret)
+    return jax.vmap(lambda pb: _double_call(cs, pb, n_doubles, interp))(p_t)
+
+
+def window_step_tiles(
+    cs: CurveSpec, acc_t: jax.Array, entry_t: jax.Array, n_doubles: int = 4, *, interpret: bool | None = None
+) -> jax.Array:
+    """:func:`pt_window_step` on lane blocks."""
+    metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_window_step")
+    interp = _interp(interpret)
+    return jax.vmap(lambda ab, eb: _window_call(cs, ab, n_doubles, interp, eb))(acc_t, entry_t)
 
 
 def pt_add(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Fused-kernel twin of groups.device.add (both curve kinds).
 
     p, q: (..., C, L) projective/extended points (batches broadcast)."""
-    metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_add")
     p, q = jnp.broadcast_arrays(jnp.asarray(p, jnp.uint32), jnp.asarray(q, jnp.uint32))
-    p_t, batch, n = _to_tiles(cs, p)
-    q_t, _, _ = _to_tiles(cs, q)
-    interp = _interp() if interpret is None else interpret
-    out = jax.vmap(lambda pb, qb: _add_call(cs, pb, qb, interp))(p_t, q_t)
-    return _from_tiles(cs, out, batch, n)
+    p_t, batch, n = to_tiles(cs, p)
+    out = add_tiles(cs, p_t, to_tiles(cs, q)[0], interpret=interpret)
+    return from_tiles(cs, out, batch, n)
 
 
 def pt_madd(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None = None) -> jax.Array:
@@ -442,38 +479,29 @@ def pt_madd(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None
     callers must not pass q = identity (see groups/device.madd)."""
     metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_madd")
     p, q = jnp.broadcast_arrays(jnp.asarray(p, jnp.uint32), jnp.asarray(q, jnp.uint32))
-    p_t, batch, n = _to_tiles(cs, p)
-    q_t, _, _ = _to_tiles(cs, q)
-    interp = _interp() if interpret is None else interpret
+    p_t, batch, n = to_tiles(cs, p)
+    q_t, _, _ = to_tiles(cs, q)
+    interp = _interp(interpret)
     out = jax.vmap(lambda pb, qb: _madd_call(cs, pb, qb, interp))(p_t, q_t)
-    return _from_tiles(cs, out, batch, n)
+    return from_tiles(cs, out, batch, n)
 
 
 def pt_double(cs: CurveSpec, p: jax.Array, n_doubles: int = 1, *, interpret: bool | None = None) -> jax.Array:
     """Fused 2^n_doubles·P in one launch."""
-    metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_double")
-    p = jnp.asarray(p, jnp.uint32)
-    p_t, batch, n = _to_tiles(cs, p)
-    interp = _interp() if interpret is None else interpret
-    out = jax.vmap(lambda pb: _double_call(cs, pb, n_doubles, interp))(p_t)
-    return _from_tiles(cs, out, batch, n)
+    p_t, batch, n = to_tiles(cs, jnp.asarray(p, jnp.uint32))
+    return from_tiles(cs, double_tiles(cs, p_t, n_doubles, interpret=interpret), batch, n)
 
 
 def pt_window_step(
     cs: CurveSpec, acc: jax.Array, entry: jax.Array, n_doubles: int = 4, *, interpret: bool | None = None
 ) -> jax.Array:
     """acc <- 2^n_doubles · acc + entry, fused in one kernel launch."""
-    metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_window_step")
     acc, entry = jnp.broadcast_arrays(
         jnp.asarray(acc, jnp.uint32), jnp.asarray(entry, jnp.uint32)
     )
-    acc_t, batch, n = _to_tiles(cs, acc)
-    entry_t, _, _ = _to_tiles(cs, entry)
-    interp = _interp() if interpret is None else interpret
-    out = jax.vmap(lambda ab, eb: _window_call(cs, ab, n_doubles, interp, eb))(
-        acc_t, entry_t
-    )
-    return _from_tiles(cs, out, batch, n)
+    acc_t, batch, n = to_tiles(cs, acc)
+    out = window_step_tiles(cs, acc_t, to_tiles(cs, entry)[0], n_doubles, interpret=interpret)
+    return from_tiles(cs, out, batch, n)
 
 
 def pt_ladder_mul_add(
@@ -496,8 +524,8 @@ def pt_ladder_mul_add(
         jnp.asarray(p, jnp.uint32), jnp.asarray(addend, jnp.uint32)
     )
     x = jnp.broadcast_to(jnp.asarray(x, jnp.uint32), p.shape[:-2])
-    p_t, batch, n = _to_tiles(cs, p)
-    a_t, _, _ = _to_tiles(cs, addend)
+    p_t, batch, n = to_tiles(cs, p)
+    a_t, _, _ = to_tiles(cs, addend)
     B = p_t.shape[0] * BLOCK
     xf = jnp.reshape(x, (n,))
     if B != n:
@@ -505,11 +533,11 @@ def pt_ladder_mul_add(
     # MSB-first bit rows per lane: bit (nbits-1-i) of x in row i
     shifts = jnp.arange(nbits - 1, -1, -1, dtype=jnp.uint32)
     bits_t = _lane_blocks((xf[:, None] >> shifts[None, :]) & jnp.uint32(1))
-    interp = _interp() if interpret is None else interpret
+    interp = _interp(interpret)
     out = jax.vmap(lambda pb, ab, bb: _ladder_call(cs, pb, ab, nbits, interp, bb))(
         p_t, a_t, bits_t
     )
-    return _from_tiles(cs, out, batch, n)
+    return from_tiles(cs, out, batch, n)
 
 
 # Backwards-compatible Edwards aliases (round-1 API).

@@ -17,9 +17,11 @@ ONE file for the same reason.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from dkg_tpu.groups import device as gd
@@ -94,6 +96,7 @@ def _compiles_to_a_tpu_kernel(one_chip, curve: str, name: str) -> None:
         ("secp256k1", "pt_madd"),
         ("secp256k1", "pt_double"),
         ("ristretto255", "pt_add"),
+        ("ristretto255", "pt_double"),  # the Edwards window step of the point-RLC's block form
         ("secp256k1", "mod_pow_const"),
         ("ristretto255", "mod_pow_const"),
     ],
@@ -138,3 +141,54 @@ def test_affine_canon_lowers_to_the_kernel_for_v5e(one_chip, shape, monkeypatch)
     zi = jax.ShapeDtypeStruct(shape[:-2] + (1, 16), jnp.uint32, sharding=one_chip)
     closing = jax.jit(lambda a, b: fd.mul(cs.field, a, b)).lower(xy, zi).compile().as_text()
     assert text.count(" while(") == closing.count(" while(") > 0
+
+
+def _layout_changes(text: str, min_elems: int) -> list[tuple[str, str]]:
+    """(computation, instruction) of every ``transpose``, and of every
+    ``copy`` whose operand has another minor-to-major order, of a u32
+    array of at least ``min_elems`` elements in a compiled module's text."""
+    layouts = {m[1]: m[2] for m in re.finditer(r"%([\w.\-]+) = u32\[[\d,]*\](\{[\d,]*)", text)}
+    found, comp = [], ""
+    for line in text.split("\n"):
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            comp = head[1]
+            continue
+        m = re.match(
+            r"\s*(?:ROOT )?%[\w.\-]+ = u32\[([\d,]*)\](\{[\d,]*)\S* (copy|transpose)\(%([\w.\-]+)\)", line
+        )
+        if m and np.prod([int(d) for d in m[1].split(",") if d]) >= min_elems:
+            if m[3] == "transpose" or layouts.get(m[4]) != m[2]:
+                found.append((comp, line.strip()[:120]))
+    return found
+
+
+@pytest.mark.parametrize("curve", ["ristretto255", "secp256k1"])
+def test_point_rlc_block_form_keeps_its_layout_for_v5e(one_chip, curve, monkeypatch):
+    """The point-RLC as the chip traces it (fused kernels on, Straus) at
+    8 dealers x 32 columns, two lane blocks: no ``gather``, and a point
+    tensor changes layout at the ends alone: the argument's relayout
+    and the one transposition to lane blocks in the entry computation
+    (the way out is a fusion), nothing inside a loop.  The parent of PR
+    31 is the counter-example: the same shape compiled to 5 ``gather``
+    and 16 such copies and transposes, 13 of them inside the window
+    loop (the table's concatenate, the ``take_along_axis``, and two
+    conversions a tree level), and that without the ``copy_bitcast``
+    fusions this count does not see.  ristretto255 takes the Edwards
+    step (``pt_double`` + ``pt_add`` on blocks), secp256k1 the fused
+    window kernel."""
+    from dkg_tpu.dkg import ceremony as ce
+
+    monkeypatch.setenv("DKG_TPU_ASSUME_BACKEND", "tpu")
+    for name in ("DKG_TPU_PALLAS", "DKG_TPU_MUL", "DKG_TPU_RLC", "DKG_TPU_RLC_CHUNK"):
+        monkeypatch.delenv(name, raising=False)
+    cs = gd.ALL_CURVES[curve]
+    m, cols, nbits = 8, 32, 8
+    w = jax.ShapeDtypeStruct((m, cs.scalar.limbs), jnp.uint32, sharding=one_chip)
+    p = jax.ShapeDtypeStruct((m, cols, cs.ncoords, cs.field.limbs), jnp.uint32, sharding=one_chip)
+    text = jax.jit(lambda w_, p_: ce._point_rlc(cs, w_, p_, nbits)).lower(w, p).compile().as_text()
+    assert "tpu_custom_call" in text and " while(" in text
+    assert " gather(" not in text
+    changes = _layout_changes(text, cs.ncoords * cs.field.limbs * pp.BLOCK)
+    assert len(changes) <= 2, changes
+    assert all(comp.startswith("main") for comp, _ in changes), changes
