@@ -9,6 +9,7 @@ result line) when that is not a TPU.  No CPU path, no caught phase.
     python chip_smoke.py            # one chip: phases `served`, `ceremony`
     python chip_smoke.py --digest   # one chip: canonicalisation, digest and rho only
     python chip_smoke.py --mesh     # four chips: the sharded ceremony only
+    python chip_smoke.py --curve bls12_381_g1 [--digest]   # the same on another curve
 
 * ``served`` — an in-process ``CeremonyScheduler`` over one
   ``WarmRuntime`` (examples/serve.py's shape, one worker): three seeded
@@ -23,7 +24,7 @@ result line) when that is not a TPU.  No CPU path, no caught phase.
   the loads, and the digest leg's first call.  The requests use the
   ceremony's own (n, t): a first call of any new shape costs a build,
   and the whole script must fit 1200 s cold.
-* ``ceremony`` — ``BatchedCeremony("secp256k1", n=1024, t=341)`` from a
+* ``ceremony`` — ``BatchedCeremony(--curve, n=1024, t=341)`` from a
   fixed seed (BASELINE.json config 3), run twice; every batch check
   passes, no complaints, and the master key equals the host oracle
   (sum of the seeded constant coefficients times the generator, big-int
@@ -65,7 +66,6 @@ import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 
-CURVE = "secp256k1"
 SHARED = b"chip_smoke"
 
 
@@ -161,7 +161,7 @@ def phase_ceremony(args, dev) -> None:
     n, t = args.n, args.t
     snap0 = runtimeobs.snapshot()
     t0 = time.perf_counter()
-    cer = ce.BatchedCeremony(CURVE, n, t, SHARED, random.Random(args.seed))
+    cer = ce.BatchedCeremony(args.curve, n, t, SHARED, random.Random(args.seed))
     jax.block_until_ready((cer.g_table, cer.h_table, cer.coeffs_a, cer.coeffs_b))
     setup_s = time.perf_counter() - t0
     snap1 = runtimeobs.snapshot()
@@ -176,14 +176,14 @@ def phase_ceremony(args, dev) -> None:
     _note(f"ceremony warm call done: {warm.timings_s}")
 
     cs = cer.cfg.cs
-    want = _host_pubkey(CURVE, _seeded_secret(cs.scalar, n, t, args.seed))
+    want = _host_pubkey(args.curve, _seeded_secret(cs.scalar, n, t, args.seed))
     got, got_warm = _encode_point(cs, out["master"]), _encode_point(cs, out_warm["master"])
     stats = dev.memory_stats() or {}
     phases = ("deal", "fiat_shamir", "verify", "finalise")
     _emit(
         {
             "phase": "ceremony",
-            "curve": CURVE,
+            "curve": args.curve,
             "n": n,
             "t": t,
             "setup_s": round(setup_s, 3),
@@ -212,14 +212,18 @@ def phase_ceremony(args, dev) -> None:
 
 def _setup_split() -> dict:
     """What the served path's set-up cost so far, from the program's own
-    series: each stored program's build by stage, the loads, and the digest
-    leg's first call per shape (seconds; docs/observability.md)."""
+    series: each stored program's build by stage, the loads, the digest
+    leg's first call per shape, and the fixed-base tables by source
+    (seconds; docs/observability.md)."""
     from dkg_tpu.utils.metrics import REGISTRY
 
     split: dict = {}
     for series, h in sorted(REGISTRY.snapshot()["histograms"].items()):
         name, _, labels = series.partition("{")
-        if name in ("aot_build_stage_seconds", "aot_load_seconds", "digest_leg_first_call_seconds"):
+        if name in (
+            "aot_build_stage_seconds", "aot_load_seconds", "digest_leg_first_call_seconds",
+            "fixed_base_table_seconds",
+        ):
             split.setdefault(name, {})[labels.rstrip("}") or "all"] = round(h["sum"], 3)
     return split
 
@@ -236,9 +240,9 @@ def phase_served(args, dev) -> None:
     os.environ.setdefault("DKG_TPU_AOT_DIR", aot.cache_dir())
 
     n, t = args.served_n or args.n, args.served_t or args.t
-    group = gh.ALL_GROUPS[CURVE]
+    group = gh.ALL_GROUPS[args.curve]
     reqs = [
-        CeremonyRequest(CURVE, n, t, shared_string=SHARED, seed=args.seed + 1 + i)
+        CeremonyRequest(args.curve, n, t, shared_string=SHARED, seed=args.seed + 1 + i)
         for i in range(args.served_requests)
     ]
     msgs = [b"chip_smoke message %d" % i for i in range(4)]
@@ -273,10 +277,10 @@ def phase_served(args, dev) -> None:
     snap3 = runtimeobs.snapshot()
     _note(f"{len(refs)} reference ceremonies in {reference_s:.1f}s")
 
-    fs = gd.ALL_CURVES[CURVE].scalar
+    fs = gd.ALL_CURVES[args.curve].scalar
     secrets = [_seeded_secret(fs, n, t, r.seed) for r in reqs]
     masters_ref = [o.master == ref for o, ref in zip(outs, refs)]
-    masters_host = [o.master == _host_pubkey(CURVE, s) for o, s in zip(outs, secrets)]
+    masters_host = [o.master == _host_pubkey(args.curve, s) for o, s in zip(outs, secrets)]
     want_sigs = [
         group.encode(group.scalar_mul_vartime(secrets[0], hash_to_curve_host(group, m)))
         for m in msgs
@@ -285,7 +289,7 @@ def phase_served(args, dev) -> None:
     _emit(
         {
             "phase": "served",
-            "curve": CURVE,
+            "curve": args.curve,
             "n": n,
             "t": t,
             "requests": len(reqs),
@@ -361,7 +365,7 @@ def phase_digest(args, dev) -> None:
     from dkg_tpu.groups import host as gh
     from dkg_tpu.service import CeremonyRequest, WarmRuntime, aot, engine
 
-    cs = gd.ALL_CURVES[CURVE]
+    cs = gd.ALL_CURVES[args.curve]
     n, t = args.served_n or 16, args.served_t or 5
     k = 2 if args.rehearse else 8
     # the convoy's two shapes (one row: no Montgomery scan), then lane
@@ -402,7 +406,7 @@ def phase_digest(args, dev) -> None:
     # both build the table right print one hash
     window = 8 if args.rehearse else 16
     t0 = time.perf_counter()
-    table = np.asarray(gd.fixed_base_table_dev(cs, gh.ALL_GROUPS[CURVE].generator(), window))
+    table = np.asarray(gd.fixed_base_table_dev(cs, gh.ALL_GROUPS[args.curve].generator(), window))
     _emit(
         {
             "phase": "table",
@@ -414,7 +418,7 @@ def phase_digest(args, dev) -> None:
     )
 
     reqs = [
-        CeremonyRequest(CURVE, n, t, shared_string=SHARED, seed=args.seed + 100 + i)
+        CeremonyRequest(args.curve, n, t, shared_string=SHARED, seed=args.seed + 100 + i)
         for i in range(k)
     ]
     runtime = WarmRuntime()
@@ -426,7 +430,7 @@ def phase_digest(args, dev) -> None:
     after = _canon_counts()
     fs = cs.scalar
     masters = [
-        o.master == _host_pubkey(CURVE, _seeded_secret(fs, n, t, r.seed))
+        o.master == _host_pubkey(args.curve, _seeded_secret(fs, n, t, r.seed))
         for o, r in zip(outs, reqs)
     ]
 
@@ -452,7 +456,7 @@ def phase_digest(args, dev) -> None:
     _emit(
         {
             "phase": "digest",
-            "curve": CURVE,
+            "curve": args.curve,
             "n": n,
             "t": t,
             "width": k,
@@ -484,7 +488,7 @@ def phase_mesh(args, dev) -> None:
     n, t = args.n, args.t
     _require(jax.device_count() == 4, f"--mesh needs 4 devices, found {jax.device_count()}")
     mesh = pm.make_mesh(4)
-    cer = ce.BatchedCeremony(CURVE, n, t, SHARED, random.Random(args.seed))
+    cer = ce.BatchedCeremony(args.curve, n, t, SHARED, random.Random(args.seed))
     cs = cer.cfg.cs
 
     # place the inputs the way run_sharded_ceremony does and look at
@@ -516,7 +520,7 @@ def phase_mesh(args, dev) -> None:
     res, warm_s = sharded()
     warm_phases = {k: round(v, 3) for k, v in res["phases_s"].items()}
     snap2 = runtimeobs.snapshot()
-    want = _host_pubkey(CURVE, _seeded_secret(cs.scalar, n, t, args.seed))
+    want = _host_pubkey(args.curve, _seeded_secret(cs.scalar, n, t, args.seed))
     _note(
         f"sharded warm call done in {warm_s:.1f}s: {warm_phases}; master == host oracle: "
         f"{_encode_point(cs, res['master']) == want}"
@@ -534,7 +538,7 @@ def phase_mesh(args, dev) -> None:
     _emit(
         {
             "phase": "mesh",
-            "curve": CURVE,
+            "curve": args.curve,
             "n": n,
             "t": t,
             "mesh_devices": [d.id for d in mesh.devices.flat],
@@ -579,6 +583,10 @@ def main() -> int:
     ap.add_argument("--served-t", type=int, default=None, help="default: --t")
     ap.add_argument("--served-requests", type=int, default=3)
     ap.add_argument("--seed", type=int, default=22)
+    ap.add_argument(
+        "--curve", default="secp256k1", choices=("secp256k1", "ristretto255", "bls12_381_g1"),
+        help="the curve of every phase (the default is the north star's)",
+    )
     ap.add_argument("--mesh", action="store_true", help="four chips: the sharded ceremony only")
     ap.add_argument(
         "--digest", action="store_true",

@@ -1238,12 +1238,17 @@ def msm_straus(cs: CurveSpec, scalars: jax.Array, points: jax.Array) -> jax.Arra
     return acc
 
 
-# Measured c=4 -> c=8 crossover per curve (CPU probe, jit-cached steady
-# state; msm at m = 64/256/512).  BLS12-381's 24-limb field mul makes
-# every bucket-closing add ~2.3x a 16-limb add, but the scatter pass
-# grows by the same factor, so its crossover sits HIGHER than the
+# Measured c=4 -> c=8 crossover per curve.  Every figure here is a CPU
+# timing (XLA:CPU probe, jit-cached steady state; msm at m =
+# 64/256/512), none is from the chip: BLS12-381's 24-limb field mul
+# makes every bucket-closing add ~2.3x a 16-limb add, but the scatter
+# pass grows by the same factor, so its crossover sits HIGHER than the
 # 256-bit curves' — w=4 still won at m=256 (704 vs 781 ms) and only
-# loses at m=512 (1483 vs 1292 ms).
+# loses at m=512 (1483 vs 1292 ms).  The chip's default schedule is not
+# Pippenger at all: verify's point-RLC is Straus in the kernels'
+# lane-block form there (dkg/ceremony.py ``_straus_tiles``, PR 31) and
+# never asks for a bucket width; this table steers the CPU default and
+# an explicit ``DKG_TPU_RLC=pippenger``.
 _PIPPENGER_CROSSOVER: dict[str, int] = {"bls12_381_g1": 512}
 
 

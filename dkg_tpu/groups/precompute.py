@@ -26,7 +26,12 @@ doublings — all doubling work was hoisted into the precomputation.
 
 ``stats()`` exposes build/load counters and seconds so callers
 (utils/tracing.py CeremonyTrace, bench.py's ``warm`` flag) can attribute
-table-build cost vs steady-state cost.
+table-build cost vs steady-state cost.  The registry carries the same
+split as ``fixed_base_table_seconds{curve=,source=}``, one observation
+per table and source: ``disk`` (a validated load of the host table),
+``build`` (the host table computed from scratch), ``compose`` (a width
+> 8 composed on the device from the half-width host table, waited
+for); a process-cache hit books nothing.
 
 Concurrency: both caches are guarded by one process-wide build lock, so
 N threads warming the same curve's tables (the multi-tenant service's
@@ -49,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.metrics import REGISTRY
 from . import device as gd
 
 _FORMAT_VERSION = 1
@@ -74,6 +80,18 @@ _STATS = {
     "disk_rejects": 0,  # on-disk files that failed validation
     "proc_hits": 0,  # served from the in-process caches
 }
+
+
+#: One table's load, build or composition: from a few milliseconds (an
+#: 8-bit host table from the disk) to tens of seconds (a 16-bit table's
+#: first composition in a process, its program's compile included).
+_TABLE_BUCKETS = (0.01, 0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 60.0, 300.0)
+
+
+def _book(cs: gd.CurveSpec, source: str, seconds: float) -> None:
+    REGISTRY.observe(
+        "fixed_base_table_seconds", seconds, _TABLE_BUCKETS, curve=cs.name, source=source
+    )
 
 
 def stats() -> dict:
@@ -192,15 +210,19 @@ def host_table(
         t0 = time.perf_counter()
         table = _load_disk(cs, key, window)
         if table is not None:
+            dt = time.perf_counter() - t0
             _STATS["disk_loads"] += 1
-            _STATS["load_s"] += time.perf_counter() - t0
+            _STATS["load_s"] += dt
+            _book(cs, "disk", dt)
         else:
             t0 = time.perf_counter()
             # the undecorated builder: gd's lru_cache would double-count
             # memory and hide rebuilds from the counters
             table = gd._fixed_table_np.__wrapped__(cs, key, window)
+            dt = time.perf_counter() - t0
             _STATS["builds"] += 1
-            _STATS["build_s"] += time.perf_counter() - t0
+            _STATS["build_s"] += dt
+            _book(cs, "build", dt)
             _persist(cs, key, window, table)
         _HOST[ck] = table
         return table
@@ -215,7 +237,10 @@ def base_table(cs: gd.CurveSpec, base, window: int | None = None) -> jax.Array:
     :func:`host_table` (disk + process cache) and the resulting device
     array is cached per ``(curve, base, window)`` for the process.
     Widths > 8 are composed on device from the persisted half-width
-    host table (one batched add + one batched inversion).
+    host table (one batched add + one batched inversion); the
+    composition is waited for here, so that its seconds can be booked
+    (``fixed_base_table_seconds{source="compose"}``) apart from the
+    host table's.
     """
     if window is None:
         window = gd.default_fixed_window()
@@ -231,7 +256,11 @@ def base_table(cs: gd.CurveSpec, base, window: int | None = None) -> jax.Array:
             if window % 2 or half > 8 or 16 % window:
                 raise ValueError(f"unsupported fixed-base window width {window}")
             t_half = jnp.asarray(host_table(cs, key, half))
-            table = gd.affine_canon(cs, gd._compose_table_dev(cs, t_half, window))
+            t0 = time.perf_counter()
+            table = jax.block_until_ready(
+                gd.affine_canon(cs, gd._compose_table_dev(cs, t_half, window))
+            )
+            _book(cs, "compose", time.perf_counter() - t0)
         else:
             table = jnp.asarray(host_table(cs, key, window))
         _TABLES[ck] = table

@@ -243,7 +243,7 @@ def _load_blob(path: str, key: tuple):
         _STATS["disk_loads"] += 1
         _STATS["load_s"] += dt
     REGISTRY.inc("aot_disk_loads_total")
-    REGISTRY.observe("aot_load_seconds", dt)
+    REGISTRY.observe("aot_load_seconds", dt, curve=str(key[1]))
     return fn
 
 
@@ -275,24 +275,30 @@ def _persist(path: str, key: tuple, blob: bytes) -> None:
         pass
 
 
+def _book_stage(key: tuple, stage: str, seconds: float) -> None:
+    """``aot_build_stage_seconds{curve=,kind=,stage=}``: ``kind`` and
+    ``curve`` are the program key's first two fields."""
+    REGISTRY.observe(
+        "aot_build_stage_seconds",
+        seconds,
+        _BUILD_BUCKETS,
+        kind=str(key[0]),
+        curve=str(key[1]),
+        stage=stage,
+    )
+
+
 @contextlib.contextmanager
-def _stage(kind: str, stage: str):
-    """One stage of one program's build, into
-    ``aot_build_stage_seconds{kind=,stage=}``."""
+def _stage(key: tuple, stage: str):
+    """One stage of one program's build, timed and booked."""
     t0 = time.perf_counter()
     try:
         yield
     finally:
-        REGISTRY.observe(
-            "aot_build_stage_seconds",
-            time.perf_counter() - t0,
-            _BUILD_BUCKETS,
-            kind=kind,
-            stage=stage,
-        )
+        _book_stage(key, stage, time.perf_counter() - t0)
 
 
-def _build_staged(kind: str, build):
+def _build_staged(key: tuple, build):
     """Run ``build`` and whatever stages its result still lacks, each
     timed apart: ``trace`` (Python tracing to a jaxpr: the thunk itself,
     where it returns a ``Traced``), ``lower`` (jaxpr to StableHLO, the
@@ -304,14 +310,12 @@ def _build_staged(kind: str, build):
     own = time.perf_counter() - t0
     # a Traced can be lowered, a Lowered compiled, a Compiled neither
     first = "trace" if hasattr(obj, "lower") else "lower" if hasattr(obj, "compile") else "compile"
-    REGISTRY.observe(
-        "aot_build_stage_seconds", own, _BUILD_BUCKETS, kind=kind, stage=first
-    )
+    _book_stage(key, first, own)
     if first == "trace":
-        with _stage(kind, "lower"):
+        with _stage(key, "lower"):
             obj = obj.lower()
     if first != "compile":
-        with _stage(kind, "compile"):
+        with _stage(key, "compile"):
             obj = obj.compile()
     return obj
 
@@ -343,9 +347,8 @@ def get_or_build(key: tuple, build):
         path = _path(key)
         fn = _load_blob(path, key)
         if fn is None:
-            kind = str(key[0])
             t0 = time.perf_counter()
-            fn = _build_staged(kind, build)
+            fn = _build_staged(key, build)
             dt = time.perf_counter() - t0
             with _LOCK:
                 _STATS["builds"] += 1
@@ -353,7 +356,7 @@ def get_or_build(key: tuple, build):
             REGISTRY.inc("aot_builds_total")
             REGISTRY.observe("aot_build_seconds", dt)
             try:
-                with _stage(kind, "serialize"):
+                with _stage(key, "serialize"):
                     _persist(path, key, serialize(fn))
             except Exception as exc:
                 # some backends can't serialize; the compiled program

@@ -37,7 +37,7 @@ def _must_not_build():
 def _stage_counts(kind):
     hist = REGISTRY.snapshot()["histograms"]
     return {
-        stage: hist.get(f'aot_build_stage_seconds{{kind="{kind}",stage="{stage}"}}', {"count": 0})["count"]
+        stage: hist.get(f'aot_build_stage_seconds{{curve="testcurve",kind="{kind}",stage="{stage}"}}', {"count": 0})["count"]
         for stage in ("trace", "lower", "compile", "serialize")
     }
 

@@ -89,6 +89,45 @@ def test_base_table_matches_device_builder(table_cache):
     np.testing.assert_array_equal(via_cache, direct)
 
 
+def _table_seconds():
+    from dkg_tpu.utils.metrics import REGISTRY
+
+    hist = REGISTRY.snapshot()["histograms"]
+    return {
+        k.partition("source=")[2].strip('"}'): v["count"]
+        for k, v in hist.items()
+        if k.startswith("fixed_base_table_seconds{") and 'curve="secp256k1"' in k
+    }
+
+
+def test_table_seconds_are_booked_once_a_table_by_source(table_cache, monkeypatch):
+    """``fixed_base_table_seconds{curve,source}``: a host table books
+    ``build`` or ``disk`` where it is made, a 16-bit table ``compose`` on
+    top of its half-width host table's, a process-cache hit nothing."""
+    import jax.numpy as jnp
+
+    before = _table_seconds()
+
+    def delta():
+        return {k: v - before.get(k, 0) for k, v in _table_seconds().items() if v != before.get(k, 0)}
+
+    gp.host_table(CS, _gen_key(), window=4)
+    assert delta() == {"build": 1}
+    gp.host_table(CS, _gen_key(), window=4)  # process cache
+    assert delta() == {"build": 1}
+    gp.reset()  # keep the disk
+    gp.base_table(CS, gd._gen_host(CS), window=4)
+    gp.base_table(CS, gd._gen_host(CS), window=4)  # process cache
+    assert delta() == {"build": 1, "disk": 1}
+    # the composition itself is a TPU-scale job (65536 entries a window):
+    # stand-ins here, the booking around them is what is held
+    monkeypatch.setattr(gd, "_compose_table_dev", lambda cs, t_half, window: t_half[:, :1])
+    monkeypatch.setattr(gd, "affine_canon", lambda cs, x: x + jnp.uint32(0))
+    gp.base_table(CS, gd._gen_host(CS), window=16)
+    gp.base_table(CS, gd._gen_host(CS), window=16)  # process cache
+    assert delta() == {"build": 2, "disk": 1, "compose": 1}
+
+
 def test_concurrent_warmers_build_exactly_once(table_cache):
     """N threads racing to warm the SAME table (the multi-tenant
     service's workers all ask for g/h at startup) serialize into exactly

@@ -99,6 +99,15 @@ def _compiles_to_a_tpu_kernel(one_chip, curve: str, name: str) -> None:
         ("ristretto255", "pt_double"),  # the Edwards window step of the point-RLC's block form
         ("secp256k1", "mod_pow_const"),
         ("ristretto255", "mod_pow_const"),
+        # the 24-limb base field (72-row point blocks, 576 partial products a
+        # multiply): what `ceremony_bls_n1024.closed` runs on the chip
+        ("bls12_381_g1", "mod_mul"),
+        ("bls12_381_g1", "mod_madd"),
+        ("bls12_381_g1", "mxu_mod_mul"),
+        ("bls12_381_g1", "mod_pow_const"),
+        ("bls12_381_g1", "pt_add"),
+        ("bls12_381_g1", "pt_madd"),
+        ("bls12_381_g1", "pt_double"),
     ],
 )
 def test_kernel_compiles_for_v5e(one_chip, curve, name, monkeypatch):
@@ -107,11 +116,12 @@ def test_kernel_compiles_for_v5e(one_chip, curve, name, monkeypatch):
     _compiles_to_a_tpu_kernel(one_chip, curve, name)
 
 
-@pytest.mark.slow  # 20-30 s each in the sandbox
+@pytest.mark.slow  # 20-30 s each in the sandbox at 16 limbs
+@pytest.mark.parametrize("curve", ["secp256k1", "bls12_381_g1"])
 @pytest.mark.parametrize("name", ["pt_window_step", "pt_ladder_mul_add"])
-def test_multi_op_kernel_compiles_for_v5e(one_chip, name, monkeypatch):
+def test_multi_op_kernel_compiles_for_v5e(one_chip, name, curve, monkeypatch):
     monkeypatch.delenv("DKG_TPU_MUL", raising=False)
-    _compiles_to_a_tpu_kernel(one_chip, "secp256k1", name)
+    _compiles_to_a_tpu_kernel(one_chip, curve, name)
 
 
 @pytest.mark.parametrize("shape", [(128, 6, 3, 16), (8, 3, 16)], ids=str)
@@ -163,7 +173,7 @@ def _layout_changes(text: str, min_elems: int) -> list[tuple[str, str]]:
     return found
 
 
-@pytest.mark.parametrize("curve", ["ristretto255", "secp256k1"])
+@pytest.mark.parametrize("curve", ["ristretto255", "secp256k1", "bls12_381_g1"])
 def test_point_rlc_block_form_keeps_its_layout_for_v5e(one_chip, curve, monkeypatch):
     """The point-RLC as the chip traces it (fused kernels on, Straus) at
     8 dealers x 32 columns, two lane blocks: no ``gather``, and a point
@@ -175,8 +185,8 @@ def test_point_rlc_block_form_keeps_its_layout_for_v5e(one_chip, curve, monkeypa
     loop (the table's concatenate, the ``take_along_axis``, and two
     conversions a tree level), and that without the ``copy_bitcast``
     fusions this count does not see.  ristretto255 takes the Edwards
-    step (``pt_double`` + ``pt_add`` on blocks), secp256k1 the fused
-    window kernel."""
+    step (``pt_double`` + ``pt_add`` on blocks), secp256k1 and
+    bls12_381_g1 the fused window kernel (72-row blocks at 24 limbs)."""
     from dkg_tpu.dkg import ceremony as ce
 
     monkeypatch.setenv("DKG_TPU_ASSUME_BACKEND", "tpu")
