@@ -137,6 +137,7 @@ def _path_facts(cs, table) -> dict:
     from dkg_tpu.fields import device as fd
     from dkg_tpu.groups import device as gd
     from dkg_tpu.ops import pallas_field as pf
+    from dkg_tpu.utils.metrics import REGISTRY
 
     fused, interpret = bool(fd.fused_kernels_active()), not fd._on_tpu()
     return {
@@ -146,6 +147,9 @@ def _path_facts(cs, table) -> dict:
         "kernel_mul_core": pf.rows_mul_dispatch(cs.field, interpret) if fused else None,
         "xla_mul": fd.mul_dispatch_mode(cs.field),
         "table_window_bits": int(math.log2(table.shape[1])),
+        "fixed_base_traced": {
+            k: v for k, v in REGISTRY.snapshot()["counters"].items() if k.startswith("fixed_base_traced_total")
+        },
         "native_library": bool(native.available()),
     }
 

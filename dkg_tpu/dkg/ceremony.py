@@ -156,6 +156,11 @@ def _deal_chunk_default(cfg: CeremonyConfig, m: int | None = None) -> int:
     chunk = budget / ((t+1) * 8 * 128 * 4 B) padded-carry bytes per
     dealer, floored to a power of two so all full chunks share one
     compiled program (a ragged last chunk compiles once more).
+
+    Since PR 35 the fused path gathers a window's entries as C·L-word
+    rows (512 B a lane) and carries lane blocks, so the 4 KiB a lane
+    this rule budgets for is no longer written there; its numbers are
+    left as they are until the chip re-derives them (ROADMAP S6/B3).
     """
     if m is None:
         m = cfg.n

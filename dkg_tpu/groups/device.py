@@ -714,10 +714,15 @@ def fixed_base_mul(cs: CurveSpec, table: jax.Array, k: jax.Array) -> jax.Array:
     the workhorse for coefficient commitments g·a + h·b (reference hot
     loop committee.rs:151-159) and KEM first components g·r (reference:
     elgamal.rs:138-142).  Eager calls are flattened + power-of-two
-    padded (see _canon_batch).
+    padded (see _canon_batch).  With the fused kernels active the
+    windows run in the point kernels' lane-block form (see
+    :func:`_fixed_base_mul_core`); the observation is part of the
+    jitted program's key, so a process that flips ``DKG_TPU_PALLAS``
+    never answers one form from the other's trace.
     """
+    blocks = fused_kernels_active()
     if isinstance(k, jax.core.Tracer) or isinstance(table, jax.core.Tracer):
-        return _fixed_base_mul_core(cs, table, k)
+        return _fixed_base_mul_core(cs, blocks, table, k)
     batch = k.shape[:-1]
     n = 1
     for d in batch:
@@ -726,25 +731,49 @@ def fixed_base_mul(cs: CurveSpec, table: jax.Array, k: jax.Array) -> jax.Array:
     kf = jnp.reshape(k, (n, k.shape[-1]))
     if m != n:
         kf = jnp.concatenate([kf, jnp.zeros((m - n,) + kf.shape[1:], kf.dtype)])
-    out = _fixed_base_mul_core(cs, table, kf)
+    out = _fixed_base_mul_core(cs, blocks, table, kf)
     return jnp.reshape(out[:n], batch + out.shape[-2:])
 
 
-@_jit_static0
-def _fixed_base_mul_core(cs: CurveSpec, table: jax.Array, k: jax.Array) -> jax.Array:
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _fixed_base_mul_core(cs: CurveSpec, blocks: bool, table: jax.Array, k: jax.Array) -> jax.Array:
+    """One gathered mixed add a window, in one of two forms, same group
+    element limb for limb:
+
+    * ``blocks`` (the fused kernels are active): the accumulator is
+      (nb, C·L, BLOCK) lane blocks (``ops.pallas_point``) from the first
+      window to the last and is converted once, at the end.  A window's
+      entries are gathered as C·L-word ROWS of its table and go rows ->
+      blocks, the one layout change of a point-sized array a step; they
+      are never written in the ``(..., C, L)`` form, whose minor dims
+      the TPU pads to a (4, 128) tile, 2 KiB a lane (717 MB a step at
+      350 k lanes, read back once for the entry and once for its Z).
+      Lanes past the batch take digit 0, the identity entry, and stay
+      the identity.
+    * tensor form elsewhere: ``(..., C, L)`` through ``madd``.
+
+    Table entries are affine-normalised (Z = 1), so each window is a
+    mixed add.  Weierstrass identity entries are NOT affine — they are
+    stored (0, 1, 0) — so the step keeps the old accumulator where the
+    gathered entry's Z = 0 (covers both the digit-0 entry and every
+    entry of an identity-base table); the Edwards identity (0, 1, 1, 0)
+    is affine and flows through the unified madd.  Each traced body
+    books ``fixed_base_traced_total{form, window}``.
+    """
+    from ..utils import metrics  # utils imports dkg, which imports this module
+
     # window width is encoded in the table's entry count (16 -> 4-bit,
     # 256 -> 8-bit, 65536 -> 16-bit); all divide the 16-bit limb width.
     window = int(table.shape[1]).bit_length() - 1
+    metrics.REGISTRY.inc(
+        "fixed_base_traced_total", form="blocks" if blocks else "tensor", window=str(window)
+    )
     digits = scalar_windows(cs, k, window)  # (..., NW)
+    if blocks:
+        return _fixed_base_blocks(cs, table, digits)
     sel = jnp.moveaxis(digits, -1, 0)  # (NW, ...)
 
     def step(acc, args):
-        # Table entries are affine-normalised (Z = 1), so each window is
-        # a mixed add.  Weierstrass identity entries are NOT affine —
-        # they are stored (0, 1, 0) — so mask on the gathered entry's
-        # Z = 0 (covers both the digit-0 entry and every entry of an
-        # identity-base table); the Edwards identity (0, 1, 1, 0) is
-        # affine and flows through the unified madd.
         tab_w, dig = args  # (2**window, C, L), (...)
         entry = _gather_table(tab_w, dig)
         nxt = madd(cs, acc, entry)
@@ -755,6 +784,32 @@ def _fixed_base_mul_core(cs: CurveSpec, table: jax.Array, k: jax.Array) -> jax.A
     init = identity(cs, k.shape[:-1])
     acc, _ = lax.scan(step, init, (table, sel))
     return acc
+
+
+def _fixed_base_blocks(cs: CurveSpec, table: jax.Array, digits: jax.Array) -> jax.Array:
+    """The window loop of :func:`_fixed_base_mul_core` on lane blocks:
+    table (NW, 2**w, C, L), digits (..., NW) -> (..., C, L)."""
+    from ..ops import pallas_point as pp
+
+    L, C = cs.field.limbs, cs.ncoords
+    batch = digits.shape[:-1]
+    n = int(np.prod(batch, dtype=np.int64))
+    nb = max(1, -(-n // pp.BLOCK))
+    sel = jnp.moveaxis(jnp.reshape(digits, (n, -1)), -1, 0)
+    sel = jnp.pad(sel, ((0, 0), (0, nb * pp.BLOCK - n)))  # (NW, lanes), unsigned and < 2**window
+
+    def step(acc_t, args):
+        tab_w, dig = args  # (2**window, C, L), (lanes,)
+        rows = jnp.reshape(tab_w, (tab_w.shape[0], C * L))
+        entry_t = pp.lane_blocks(rows.at[dig].get(mode="promise_in_bounds"))
+        nxt = pp.madd_tiles(cs, acc_t, entry_t)
+        if cs.kind != "edwards":
+            live = jnp.any(entry_t[:, 2 * L : 3 * L, :] != 0, axis=1, keepdims=True)  # (nb, 1, BLOCK)
+            nxt = jnp.where(live, nxt, acc_t)
+        return nxt, None
+
+    acc_t, _ = lax.scan(step, pp.identity_tiles(cs, nb), (table, sel))
+    return pp.from_tiles(cs, acc_t, batch, n)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))

@@ -384,7 +384,7 @@ def _ladder_call(
     )
 
 
-def _lane_blocks(flat: jax.Array) -> jax.Array:
+def lane_blocks(flat: jax.Array) -> jax.Array:
     """(m, rows) with m a BLOCK multiple -> (m // BLOCK, rows, BLOCK):
     lanes on the lane axis, one kernel block each."""
     m, rows = flat.shape
@@ -425,7 +425,7 @@ def to_tiles(cs: CurveSpec, pts: jax.Array) -> tuple[jax.Array, tuple, int]:
         flat = jnp.concatenate(
             [flat, jnp.broadcast_to(jnp.asarray(_identity_flat(cs)), (m - n, C * L))]
         )
-    return _lane_blocks(flat), batch, n
+    return lane_blocks(flat), batch, n
 
 
 def from_tiles(cs: CurveSpec, t: jax.Array, batch: tuple, n: int) -> jax.Array:
@@ -446,6 +446,14 @@ def add_tiles(cs: CurveSpec, p_t: jax.Array, q_t: jax.Array, *, interpret: bool 
     metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_add")
     interp = _interp(interpret)
     return jax.vmap(lambda pb, qb: _add_call(cs, pb, qb, interp))(p_t, q_t)
+
+
+def madd_tiles(cs: CurveSpec, p_t: jax.Array, q_t: jax.Array, *, interpret: bool | None = None) -> jax.Array:
+    """:func:`pt_madd` on lane blocks (``groups.device._fixed_base_mul_core``'s
+    window step: the accumulator never leaves this form)."""
+    metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_madd")
+    interp = _interp(interpret)
+    return jax.vmap(lambda pb, qb: _madd_call(cs, pb, qb, interp))(p_t, q_t)
 
 
 def double_tiles(cs: CurveSpec, p_t: jax.Array, n_doubles: int = 1, *, interpret: bool | None = None) -> jax.Array:
@@ -477,12 +485,9 @@ def pt_add(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None 
 def pt_madd(cs: CurveSpec, p: jax.Array, q: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Fused mixed add: q affine-normalised (Z = 1).  Weierstrass
     callers must not pass q = identity (see groups/device.madd)."""
-    metrics.REGISTRY.inc("pallas_calls_total", kernel="pt_madd")
     p, q = jnp.broadcast_arrays(jnp.asarray(p, jnp.uint32), jnp.asarray(q, jnp.uint32))
     p_t, batch, n = to_tiles(cs, p)
-    q_t, _, _ = to_tiles(cs, q)
-    interp = _interp(interpret)
-    out = jax.vmap(lambda pb, qb: _madd_call(cs, pb, qb, interp))(p_t, q_t)
+    out = madd_tiles(cs, p_t, to_tiles(cs, q)[0], interpret=interpret)
     return from_tiles(cs, out, batch, n)
 
 
@@ -532,7 +537,7 @@ def pt_ladder_mul_add(
         xf = jnp.concatenate([xf, jnp.zeros((B - n,), jnp.uint32)])
     # MSB-first bit rows per lane: bit (nbits-1-i) of x in row i
     shifts = jnp.arange(nbits - 1, -1, -1, dtype=jnp.uint32)
-    bits_t = _lane_blocks((xf[:, None] >> shifts[None, :]) & jnp.uint32(1))
+    bits_t = lane_blocks((xf[:, None] >> shifts[None, :]) & jnp.uint32(1))
     interp = _interp(interpret)
     out = jax.vmap(lambda pb, ab, bb: _ladder_call(cs, pb, ab, nbits, interp, bb))(
         p_t, a_t, bits_t

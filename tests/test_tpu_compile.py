@@ -154,9 +154,10 @@ def test_affine_canon_lowers_to_the_kernel_for_v5e(one_chip, shape, monkeypatch)
 
 
 def _layout_changes(text: str, min_elems: int) -> list[tuple[str, str]]:
-    """(computation, instruction) of every ``transpose``, and of every
-    ``copy`` whose operand has another minor-to-major order, of a u32
-    array of at least ``min_elems`` elements in a compiled module's text."""
+    """(computation, instruction) of every ``transpose`` that permutes,
+    and of every ``copy`` (or ``transpose`` in place) whose operand has
+    another minor-to-major order, of a u32 array of at least
+    ``min_elems`` elements in a compiled module's text."""
     layouts = {m[1]: m[2] for m in re.finditer(r"%([\w.\-]+) = u32\[[\d,]*\](\{[\d,]*)", text)}
     found, comp = [], ""
     for line in text.split("\n"):
@@ -165,12 +166,34 @@ def _layout_changes(text: str, min_elems: int) -> list[tuple[str, str]]:
             comp = head[1]
             continue
         m = re.match(
-            r"\s*(?:ROOT )?%[\w.\-]+ = u32\[([\d,]*)\](\{[\d,]*)\S* (copy|transpose)\(%([\w.\-]+)\)", line
+            r"\s*(?:ROOT )?%[\w.\-]+ = u32\[([\d,]*)\](\{[\d,]*)\S* (copy|transpose)\(%([\w.\-]+)\)(?:, dimensions=\{([\d,]*)\})?",
+            line,
         )
         if m and np.prod([int(d) for d in m[1].split(",") if d]) >= min_elems:
-            if m[3] == "transpose" or layouts.get(m[4]) != m[2]:
+            permutes = m[3] == "transpose" and m[5] != ",".join(map(str, range(m[5].count(",") + 1)))
+            if permutes or layouts.get(m[4]) != m[2]:
                 found.append((comp, line.strip()[:120]))
     return found
+
+
+def _called_from(text: str, root: str) -> set[str]:
+    """``root`` and every computation it reaches (fusions, nested loops)."""
+    calls, comp = {}, ""
+    for line in text.split("\n"):
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            comp = head[1]
+        else:
+            calls.setdefault(comp, set()).update(
+                re.findall(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", line)
+            )
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo.extend(calls.get(c, ()))
+    return seen
 
 
 @pytest.mark.parametrize("curve", ["ristretto255", "secp256k1", "bls12_381_g1"])
@@ -202,3 +225,42 @@ def test_point_rlc_block_form_keeps_its_layout_for_v5e(one_chip, curve, monkeypa
     changes = _layout_changes(text, cs.ncoords * cs.field.limbs * pp.BLOCK)
     assert len(changes) <= 2, changes
     assert all(comp.startswith("main") for comp, _ in changes), changes
+
+
+@pytest.mark.parametrize("curve", ["ristretto255", "secp256k1", "bls12_381_g1"])
+def test_fixed_base_block_form_keeps_its_layout_for_v5e(one_chip, curve, monkeypatch):
+    """The fixed-base multiply as the chip traces it (fused kernels on,
+    the 16-bit table of the on-chip default) at 8 x 40 lanes, three lane
+    blocks: the window loop carries the accumulator as (nb, C·L, BLOCK)
+    blocks and nothing in the loop, its fusions included, changes the
+    layout of the accumulator.  What does change layout in a step is
+    the gathered entry, once (rows -> blocks), and the window's slice
+    of the table (the device keeps a ``(NW, 65536, C, L)`` argument with
+    the entries minor; a row gather wants the rows minor), once.  The
+    parent of PR 35 is the counter-example: the same shape carried
+    ``u32[8,40,3,16]`` in tensor form and changed a point-sized array's
+    layout four times a step (the gathered entry out of the ``(C, L)``
+    minor form, tile-padded to (4, 128), that the gather wrote it in;
+    the accumulator and the entry to blocks; the result back) and the
+    table's slice once, into that padded form, on all three curves; at
+    (1024, 342) the entry's Z left the padded form by a copy of its
+    own, a fifth."""
+    monkeypatch.setenv("DKG_TPU_ASSUME_BACKEND", "tpu")
+    for name in ("DKG_TPU_PALLAS", "DKG_TPU_MUL"):
+        monkeypatch.delenv(name, raising=False)
+    cs = gd.ALL_CURVES[curve]
+    rows = cs.ncoords * cs.field.limbs
+    k = jax.ShapeDtypeStruct((8, 40, cs.scalar.limbs), jnp.uint32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct(
+        (cs.scalar.limbs, 1 << 16, cs.ncoords, cs.field.limbs), jnp.uint32, sharding=one_chip
+    )
+    text = jax.jit(lambda t_, k_: gd.fixed_base_mul(cs, t_, k_)).lower(table, k).compile().as_text()
+    assert "tpu_custom_call" in text
+    loops = re.findall(r"= \((.*)\) while\(.*body=%([\w.\-]+)", text)
+    assert len(loops) == 1, loops
+    carry, body = loops[0]
+    assert f"u32[3,{rows},{pp.BLOCK}]{{2,1,0" in carry, carry
+    inside = _called_from(text, body)
+    changes = [c for c in _layout_changes(text, rows * pp.BLOCK) if c[0] in inside]
+    of_the_table = [c for c in changes if "65536" in c[1]]
+    assert len(of_the_table) <= 1 and len(changes) - len(of_the_table) <= 1, changes
