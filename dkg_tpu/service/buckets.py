@@ -66,6 +66,12 @@ class Bucket:
     n: int
     t: int
 
+    @property
+    def label(self) -> str:
+        """``"{n}x{t}"``: the ``bucket`` label of the scheduler's series
+        (docs/observability.md), which the benchmark's readers match."""
+        return f"{self.n}x{self.t}"
+
 
 def _next_pow2(v: int) -> int:
     return 1 << max(v - 1, 1).bit_length()

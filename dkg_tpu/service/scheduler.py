@@ -1274,8 +1274,7 @@ class CeremonyScheduler:
             )
             convoy = mates[:width]
             now = time.monotonic()
-            b = head.req.bucket()
-            label = f"{b.n}x{b.t}"
+            label = head.req.bucket().label
             for p in convoy:
                 self._queue.remove(p)
                 self._status[p.cid] = "running"
@@ -1499,8 +1498,22 @@ class CeremonyScheduler:
                 self._finish_one(
                     out, durable=p.req.durable, admitted_at=p.admitted_at
                 )
+        # by bucket: a width-1 (64,16) convoy and a width-8 (16,5) stack
+        # share the workers and the chip and cost them differently
+        b = convoy[0].req.bucket()
         self.metrics.observe(
-            "service_convoy_seconds", dt, width=str(len(convoy))
+            "service_convoy_seconds", dt, bucket=b.label,
+            width=str(len(convoy)),
+        )
+        # dealer lanes the convoy's programs ran: the members' real
+        # committees, and the phantom lanes that pad them to the bucket
+        real = sum(p.req.n for p in convoy)
+        self.metrics.inc(
+            "service_convoy_lanes_total", real, bucket=b.label, kind="real"
+        )
+        self.metrics.inc(
+            "service_convoy_lanes_total", len(convoy) * b.n - real,
+            bucket=b.label, kind="phantom",
         )
         if self._log is not None and trace is not None:
             # a worker thread has no ambient recorder (as in the sign

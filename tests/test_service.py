@@ -322,6 +322,57 @@ def test_convoys_batch_same_key_in_ladder_widths(fake_engine):
     assert fake_engine.widths == [1, 2, 1, 1]
 
 
+@pytest.mark.parametrize(
+    "shapes,bucket,width,real",
+    [
+        ([(24, 8), (32, 8)], "32x8", 2, 56),  # two real sizes in one stack
+        ([(48, 16)], "64x16", 1, 48),  # WIDTH_CAP_N: a convoy of its own
+        ([(16, 5)] * 4, "16x5", 4, 64),  # exact: no phantom lane
+    ],
+    ids=["padded_stack", "padded_heavy", "exact_stack"],
+)
+def test_convoy_seconds_and_lanes_by_bucket(fake_engine, shapes, bucket, width, real):
+    reg = MetricsRegistry()
+    sch = CeremonyScheduler(
+        concurrency=1, queue_depth=16, batch_max=8, runtime=object(), metrics=reg
+    )
+    try:
+        held = sch.submit(CeremonyRequest(CURVE, 5, 2, seed=0))
+        _wait_status(sch, held, "running")  # the rest queue up behind it
+        cids = [
+            sch.submit(CeremonyRequest(CURVE, n, t, seed=1 + i))
+            for i, (n, t) in enumerate(shapes)
+        ]
+    finally:
+        fake_engine.gate.set()
+    assert all(sch.result(c, timeout=10).status == "done" for c in cids + [held])
+    sch.close()
+    snap = reg.snapshot()
+    # one observation a convoy, under its bucket and its width
+    convoys = {
+        k: v["count"]
+        for k, v in snap["histograms"].items()
+        if k.startswith("service_convoy_seconds")
+    }
+    assert convoys == {
+        'service_convoy_seconds{bucket="8x2",width="1"}': 1,
+        f'service_convoy_seconds{{bucket="{bucket}",width="{width}"}}': 1,
+    }
+    assert snap["counters"]["service_convoys_total"] == 2  # unlabelled, as before
+    lanes = {
+        kind: snap["counters"][
+            f'service_convoy_lanes_total{{bucket="{bucket}",kind="{kind}"}}'
+        ]
+        for kind in ("real", "phantom")
+    }
+    bucket_n = int(bucket.partition("x")[0])
+    assert lanes["real"] == real
+    assert lanes["real"] + lanes["phantom"] == width * bucket_n
+    held_lanes = 'service_convoy_lanes_total{bucket="8x2",kind="%s"}'
+    assert snap["counters"][held_lanes % "real"] == 5
+    assert snap["counters"][held_lanes % "phantom"] == 3
+
+
 def test_close_without_drain_fails_queued_work(fake_engine):
     sch = CeremonyScheduler(concurrency=1, queue_depth=8, batch_max=1, runtime=object())
     held = sch.submit(CeremonyRequest(CURVE, 5, 2, seed=0))
