@@ -806,26 +806,35 @@ def fiat_shamir_rho(cfg: CeremonyConfig, transcript: bytes, rho_bits: int) -> np
     round-1 broadcast — use :func:`transcript_digest`.  Returns (n, L)
     uint32 limbs with rho_bits entropy.
 
-    One ``crypto.blake2.blake2b_batch`` call derives all n lanes — at
-    n=4096 the former per-dealer ``hashlib`` loop was 4096 sequential
-    host hashes; now it is one (n, 36)-byte array op, byte-identical
-    per lane (tests/test_digest_dispatch.py pins pre-vectorization
-    golden outputs)."""
-    from ..crypto.blake2 import blake2b_batch
-
+    Lane j is ``hashlib.blake2b(transcript || j)``: one call of the C
+    library a lane, joined into the (n, nbytes) array that the tail
+    masks and splits into limbs.  A lane is 36 bytes, one compression;
+    the numpy form this replaced (``crypto.blake2.blake2b_batch``) pays
+    some 3,800 array operations a compression whatever n is, all under
+    the interpreter lock, and lost to the C library at every lane count
+    (the figures: PERF.md section 6, PR 37).  It stays in the tests as
+    the independent reference, byte for byte
+    (tests/test_digest_dispatch.py).  Books ``rho_lanes_total``, n a
+    call."""
     fs = cfg.cs.scalar
     nbytes = (rho_bits + 7) // 8
     # mask to EXACTLY rho_bits: the point side (_point_rlc) consumes only
     # the low rho_bits, while the field side (_field_dot) consumes every
     # set bit — they must see the same weights for any rho_bits.
     mask = (1 << rho_bits) - 1
-    tlen = len(transcript)
-    msgs = np.zeros((cfg.n, tlen + 4), np.uint8)
-    msgs[:, :tlen] = np.frombuffer(transcript, np.uint8)
-    msgs[:, tlen:] = (
-        np.arange(cfg.n, dtype="<u4").reshape(cfg.n, 1).view(np.uint8)
-    )
-    dig = blake2b_batch(msgs, digest_size=nbytes, person=b"dkgtpu-rlc")
+    blake2b = hashlib.blake2b
+    dig = np.frombuffer(
+        b"".join(
+            blake2b(
+                transcript + j.to_bytes(4, "little"),
+                digest_size=nbytes,
+                person=b"dkgtpu-rlc",
+            ).digest()
+            for j in range(cfg.n)
+        ),
+        np.uint8,
+    ).reshape(cfg.n, nbytes)
+    REGISTRY.inc("rho_lanes_total", cfg.n)
     out = np.zeros((cfg.n, fs.limbs), np.uint32)
     if (1 << rho_bits) > fs.modulus:
         # masked value may exceed the scalar modulus: reduce per lane

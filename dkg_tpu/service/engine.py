@@ -390,7 +390,11 @@ def derive_rho_convoy(
     The transcript row digests are per-dealer and row-independent, so
     the convoy's (k, n, ...) tensors fold into ONE (k*n, ...) row-digest
     pass — one dispatch per tensor family instead of 3*k — and only the
-    outer fold (3 small arrays through one blake2b) stays per ceremony.
+    outer fold stays per ceremony (``rho_fold``): three small arrays
+    through one blake2b, then :func:`~dkg_tpu.dkg.ceremony.
+    fiat_shamir_rho`'s n lanes through ``hashlib``.  With the lanes in
+    numpy the stage held the interpreter lock long enough to keep the
+    other workers off the chip (the figures: PERF.md section 6, PR 37).
     This is the digest's share of the dispatch amortization that makes
     the stacked lane pay: per-ceremony digest calls were ~40% of a small
     convoy's wall clock.  A width-1 convoy takes the same path: its

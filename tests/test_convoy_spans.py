@@ -187,6 +187,24 @@ def test_width_one_rho_is_derive_rho(runtime):
     assert list(fl.trace.timings_s) == ["convoy.draw", "convoy.deal_dispatch"]
 
 
+def test_stacked_rho_is_derive_rho_on_every_lane(runtime):
+    """Width 2, on the fixture's programs: the convoy's one row-digest pass
+    and its per-ceremony folds give each member the rho it would get alone."""
+    fl = engine.start_convoy(runtime, _requests()[:2])
+    a, e, s, r = (np.asarray(x) for x in (fl.a, fl.e, fl.s, fl.r))
+    k, n = s.shape[:2]
+    assert (k, fl.trace.meta["width"]) == (2, 2)
+    want = [ce.derive_rho(fl.cfg_pad, a[i], e[i], s[i], r[i], 128) for i in range(k)]
+    series = "rho_lanes_total"
+    before = REGISTRY.snapshot()["counters"][series]
+    got = engine.derive_rho_convoy(fl.cfg_pad, a, e, s, r, 128)
+    assert REGISTRY.snapshot()["counters"][series] == before + k * n
+    assert got.shape == (k,) + want[0].shape
+    for i in range(k):
+        assert np.array_equal(got[i], want[i]), i
+    assert not np.array_equal(got[0], got[1])  # two transcripts, two rhos
+
+
 def test_an_engine_stand_in_without_a_trace_is_served(monkeypatch):
     """tests/test_service.py's stand-ins return a dict: no trace, no span, no stage."""
     monkeypatch.setattr(scheduler_mod, "start_convoy", lambda rt, reqs, ids=None: {"reqs": reqs, "ids": ids})

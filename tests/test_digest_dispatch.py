@@ -20,8 +20,10 @@ import jax.numpy as jnp
 
 from chip_smoke import projective_batch  # the on-chip proof's batches, at the repo's root
 from dkg_tpu.crypto import device_hash as dh
+from dkg_tpu.crypto.blake2 import blake2b_batch
 from dkg_tpu.dkg import ceremony as ce
 from dkg_tpu.fields import host as fh
+from dkg_tpu.utils.metrics import REGISTRY
 
 RNG = random.Random(0xD15B)
 
@@ -137,11 +139,11 @@ def test_transcript_and_rho_golden_ristretto255(monkeypatch):
     _check_goldens("ristretto255", monkeypatch)
 
 
-# --- vectorized fiat_shamir_rho ---------------------------------------
+# --- fiat_shamir_rho: the hashlib lanes, the scalar loop, the numpy BLAKE2b ---
 
 
 def _rho_reference(cfg, transcript: bytes, rho_bits: int) -> np.ndarray:
-    """The pre-vectorization per-dealer hashlib loop, verbatim."""
+    """The per-dealer hashlib loop with ``fh.encode`` a lane, as the seed had it."""
     fs = cfg.cs.scalar
     nbytes = (rho_bits + 7) // 8
     mask = (1 << rho_bits) - 1
@@ -156,13 +158,44 @@ def _rho_reference(cfg, transcript: bytes, rho_bits: int) -> np.ndarray:
     return out
 
 
-# 280 > the 256-bit scalar field: exercises the reduce-per-lane fallback
-@pytest.mark.parametrize("rho_bits", [8, 24, 64, 128, 255, 280])
-def test_fiat_shamir_rho_matches_scalar_loop(rho_bits):
-    cfg = ce.CeremonyConfig("secp256k1", 6, 2)
+def _rho_lanes_numpy(cfg, transcript: bytes, rho_bits: int) -> np.ndarray:
+    """The lanes' digests through the numpy BLAKE2b, the form
+    ``fiat_shamir_rho`` had until PR 37: an implementation of RFC 7693
+    that shares no code with ``hashlib``."""
+    msgs = np.zeros((cfg.n, len(transcript) + 4), np.uint8)
+    msgs[:, :-4] = np.frombuffer(transcript, np.uint8)
+    msgs[:, -4:] = np.arange(cfg.n, dtype="<u4").reshape(cfg.n, 1).view(np.uint8)
+    return blake2b_batch(msgs, digest_size=(rho_bits + 7) // 8, person=b"dkgtpu-rlc")
+
+
+# 280 > the 256-bit scalar field: exercises the reduce-per-lane fallback;
+# the shapes after the six are the cells' (16 lanes a fleet ceremony, 64
+# the mix's heavy bucket, 1024 on both curves of the large ones) and a
+# 253-bit order
+@pytest.mark.parametrize(
+    "curve,n,t,rho_bits",
+    [("secp256k1", 6, 2, bits) for bits in (8, 24, 64, 128, 255, 280)]
+    + [
+        ("secp256k1", 16, 5, 128),
+        ("secp256k1", 64, 16, 128),
+        ("secp256k1", 1024, 341, 128),
+        ("bls12_381_g1", 1024, 341, 128),
+        ("ristretto255", 16, 5, 253),
+    ],
+)
+def test_fiat_shamir_rho_matches_scalar_loop(curve, n, t, rho_bits):
+    cfg = ce.CeremonyConfig(curve, n, t)
+    fs = cfg.cs.scalar
     transcript = bytes(RNG.randrange(256) for _ in range(32))
+    before = REGISTRY.snapshot()["counters"].get("rho_lanes_total", 0)
     got = ce.fiat_shamir_rho(cfg, transcript, rho_bits)
+    assert REGISTRY.snapshot()["counters"]["rho_lanes_total"] == before + n
     np.testing.assert_array_equal(got, _rho_reference(cfg, transcript, rho_bits))
+    # the same lanes from the numpy BLAKE2b, masked and reduced as ints
+    mask = (1 << rho_bits) - 1
+    lanes = _rho_lanes_numpy(cfg, transcript, rho_bits)
+    want = fh.encode(fs, [int.from_bytes(lane.tobytes(), "little") & mask for lane in lanes])
+    np.testing.assert_array_equal(got, want)
 
 
 def test_fiat_shamir_rho_golden_128():
@@ -204,7 +237,6 @@ def test_affine_canon_host_matches_device(curve, shape):
     (canon must map them to the canonical identity encoding, not divide
     by zero)."""
     from dkg_tpu.groups import device as gd
-    from dkg_tpu.utils.metrics import REGISTRY
 
     cs = gd.ALL_CURVES[curve]
     n_lanes = int(np.prod(shape))
@@ -243,7 +275,6 @@ def test_affine_canon_path_keys_the_compiled_program(monkeypatch):
     entered) and books the path it runs, where a key without it would
     run the cached program under the other label."""
     from dkg_tpu.groups import device as gd
-    from dkg_tpu.utils.metrics import REGISTRY
 
     cs = gd.SECP256K1
     pts = projective_batch(cs, (3,), random.Random(0xCA9))

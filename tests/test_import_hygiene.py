@@ -96,6 +96,40 @@ def test_lint_dkg005_bans_raw_writes_in_net():
     assert "DKG005" not in codes, codes
 
 
+def test_lint_dkg004_allows_the_measured_blake2b_loop_in_fiat_shamir_rho():
+    """DKG004's second half: a ``hashlib.blake2b`` in a loop (or a
+    comprehension) of a dkg/ hot module is flagged, but for the legs where
+    the loop was timed against ``blake2b_batch`` and won, or is the oracle:
+    ``fiat_shamir_rho`` (PR 37) and ``_dealer_row_digests``."""
+    import ast
+    import pathlib
+
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent / "scripts"))
+    try:
+        import lint_lite
+    finally:
+        sys.path.pop(0)
+
+    def codes(src, path):
+        checker = lint_lite._Checker(pathlib.Path(path), ast.parse(src), src)
+        return [c for _, c, _ in checker.finish()]
+
+    body = (
+        "    blake2b = hashlib.blake2b\n"
+        "    return b''.join(blake2b(t + bytes([j])).digest() for j in range(n))\n"
+    )
+    src = "import hashlib\ndef {name}(t, n):\n" + body
+    hot = "dkg_tpu/dkg/ceremony.py"
+    assert "DKG004" not in codes(src.format(name="fiat_shamir_rho"), hot)
+    assert "DKG004" not in codes(src.format(name="_dealer_row_digests"), hot)
+    assert codes(src.format(name="derive_rho"), hot).count("DKG004") == 1
+    # one hash outside any loop is no loop, and the rule is scoped to the
+    # batch hot modules of dkg_tpu/dkg/
+    once = "import hashlib\ndef derive_rho(t):\n    return hashlib.blake2b(t).digest()\n"
+    assert "DKG004" not in codes(once, hot)
+    assert "DKG004" not in codes(src.format(name="derive_rho"), "dkg_tpu/net/elsewhere.py")
+
+
 def test_lint_dkg012_bans_raw_socket_io_in_net():
     """DKG012: every socket send/receive in dkg_tpu/net/ flows through
     the counted wire helpers so net_wire_bytes_total stays exact —
