@@ -74,8 +74,9 @@ def _note_fault(
     """Every injected fault is observable: a per-kind counter plus a
     flight-recorder event in the victim party's log, so a chaos failure
     can be replayed from its logs alone (module docstring).  Delay
-    faults carry their injected ``seconds`` so forensics can attribute
-    the lost wall-clock (obslog.critical_path)."""
+    faults carry the ``seconds`` they slept, and are noted when the
+    sleep ends, so forensics can attribute the lost wall-clock
+    (obslog.critical_path)."""
     REGISTRY.inc("dkg_faults_injected_total", kind=kind)
     obslog.emit_current(
         "fault_injected", round=round_no, fault=kind, sender=sender,
@@ -112,6 +113,8 @@ class FaultPlan:
         # (sender, round) restarts already fired: each scheduled restart
         # kills exactly one incarnation, else respawn would loop forever
         self._restarts_fired: set[tuple[int, int]] = set()
+        #: (round, sender) -> seconds a ``delay`` fault actually slept
+        self.slept: dict[tuple[int, int], float] = {}
 
     # -- builders -----------------------------------------------------------
 
@@ -269,14 +272,18 @@ class FaultyChannel:
         plan = self._plan
         publishes = [payload]
         for kind, arg in plan.faults_for(round_no, sender):
-            _note_fault(
-                kind, round_no, sender,
-                seconds=float(arg) if kind == "delay" else None,  # type: ignore[arg-type]
-            )
+            if kind == "delay":
+                # a sleep only overshoots, and by more on a loaded host:
+                # the plan and the event record the delay as it was slept
+                t0 = time.monotonic()
+                time.sleep(float(arg))  # type: ignore[arg-type]
+                slept = time.monotonic() - t0
+                plan.slept[(round_no, sender)] = slept
+                _note_fault(kind, round_no, sender, seconds=slept)
+                continue
+            _note_fault(kind, round_no, sender)
             if kind == "drop":
                 return
-            elif kind == "delay":
-                time.sleep(float(arg))  # type: ignore[arg-type]
             elif kind == "garbage":
                 publishes = [plan.garbage_bytes(round_no, sender, arg)]  # type: ignore[arg-type]
             elif kind == "truncate":

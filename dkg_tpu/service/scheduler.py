@@ -1458,7 +1458,7 @@ class CeremonyScheduler:
         # before, between this convoy's dispatch and now: a stage of
         # this convoy in which nothing ran for it
         tracing.book_phase(
-            trace, "convoy.hold", time.perf_counter() - held_from
+            trace, "convoy.hold", held_from, time.perf_counter()
         )
         try:
             outcomes = self._engine_finish(fl, [p.req for p in convoy])
@@ -1473,6 +1473,7 @@ class CeremonyScheduler:
         # shared by w ceremonies (the whole-convoy time goes to the
         # service_convoy_seconds histogram below)
         share = dt / max(1, len(convoy))
+        members = []
         for p, out in zip(convoy, outcomes):
             out.seconds = share
             out.convoy_width = len(convoy)
@@ -1498,6 +1499,7 @@ class CeremonyScheduler:
                 self._finish_one(
                     out, durable=p.req.durable, admitted_at=p.admitted_at
                 )
+            members.append((p.cid, p.admitted_at, out.completed_at, out.status))
         # by bucket: a width-1 (64,16) convoy and a width-8 (16,5) stack
         # share the workers and the chip and cost them differently
         b = convoy[0].req.bucket()
@@ -1515,29 +1517,64 @@ class CeremonyScheduler:
             "service_convoy_lanes_total", len(convoy) * b.n - real,
             bucket=b.label, kind="phantom",
         )
-        if self._log is not None and trace is not None:
-            # a worker thread has no ambient recorder (as in the sign
-            # lane), so the convoy's span goes to the scheduler's own:
-            # the stages' seconds as subs, in the order they ran
-            self._log.emit_span(
-                "convoy",
-                ts0=time.time() - dt,
-                mono0=t0,
-                dur_s=dt,
-                subs={
-                    k.removeprefix("convoy."): v
-                    for k, v in trace.timings_s.items()
-                },
-                convoy=trace.meta.get("convoy"),
-                width=len(convoy),
-                bucket=trace.meta.get("bucket"),
-                slot=trace.meta.get("slot"),
-                ceremonies=[p.cid for p in convoy],
-                queue_wait_s=[p.queue_s for p in convoy],
-            )
+        if trace is not None:
+            self._book_timeline(convoy, members, trace, t0, dt)
         # device/host memory watermarks at the convoy boundary (no-op
         # unless runtimeobs is installed; internally throttled)
         runtimeobs.maybe_sample(phase="convoy_finish")
+
+    def _book_timeline(self, convoy, members, trace, t0, dt) -> None:
+        """One convoy's record onto ``tracing.TIMELINE`` (the fields:
+        :class:`~dkg_tpu.utils.tracing.Timeline`) and, where the
+        scheduler has a flight recorder, its ``convoy`` span from the
+        same record.  **One clock**: the trace's spans are on
+        ``time.perf_counter()`` and stay as they are; the scheduler's
+        stamps (``_Pending.admitted_at``, the pop ``queue_s`` after it,
+        ``CeremonyOutcome.completed_at``, the worker's ``t0``) are taken
+        on ``time.monotonic()`` and are converted here by the two
+        clocks' difference, a constant the process reads once
+        (``tracing.MONOTONIC_TO_SPAN_CLOCK``)."""
+        shift = tracing.MONOTONIC_TO_SPAN_CLOCK
+        record = {
+            "convoy": trace.meta.get("convoy"),
+            "slot": trace.meta.get("slot"),
+            "bucket": trace.meta.get("bucket"),
+            "width": len(convoy),
+            "popped": convoy[0].admitted_at + convoy[0].queue_s + shift,
+            "spans": trace.spans,
+            "members": [
+                (cid, admitted + shift, completed + shift, status)
+                for cid, admitted, completed, status in members
+            ],
+        }
+        tracing.TIMELINE.append(record)
+        if self._log is None:
+            return
+        # a worker thread has no ambient recorder (as in the sign lane),
+        # so the span goes to the scheduler's own: the stages' seconds
+        # as subs, in the order they first ran, and every stage's
+        # interval as spans, [stage, start, end] in seconds from ts0
+        start = t0 + shift
+        self._log.emit_span(
+            "convoy",
+            ts0=time.time() - dt,
+            mono0=t0,
+            dur_s=dt,
+            subs={
+                k.removeprefix("convoy."): v
+                for k, v in trace.timings_s.items()
+            },
+            spans=[
+                [phase.removeprefix("convoy."), a - start, b - start]
+                for phase, a, b in record["spans"]
+            ],
+            convoy=record["convoy"],
+            width=record["width"],
+            bucket=record["bucket"],
+            slot=record["slot"],
+            ceremonies=[m[0] for m in record["members"]],
+            queue_wait_s=[p.queue_s for p in convoy],
+        )
 
     # -- blast-radius isolation ---------------------------------------------
 

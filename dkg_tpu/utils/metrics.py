@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import bisect
 import threading
+import time
 
 # Latency buckets (seconds): spans ~1 ms RPCs to ~minute-long phases.
 # Fixed so concurrent ceremonies and successive processes aggregate —
@@ -122,7 +123,9 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         """One JSON-able dict of every series.  Histogram buckets are
         cumulative (Prometheus ``le`` semantics) so the snapshot and the
-        text exposition describe the identical distribution."""
+        text exposition describe the identical distribution.  ``at`` is
+        when it was taken, on ``time.perf_counter()``, the clock of
+        ``tracing.TIMELINE``'s records."""
         with self._lock:
             counters = {_series(n, li): v for (n, li), v in self._counters.items()}
             gauges = {_series(n, li): v for (n, li), v in self._gauges.items()}
@@ -138,7 +141,10 @@ class MetricsRegistry:
                     "sum": total,
                     "count": count,
                 }
-        return {"counters": counters, "gauges": gauges, "histograms": hists}
+        return {
+            "counters": counters, "gauges": gauges, "histograms": hists,
+            "at": time.perf_counter(),
+        }
 
     def prometheus_text(self) -> str:
         """Prometheus text exposition (``# TYPE`` headers, cumulative

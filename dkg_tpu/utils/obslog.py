@@ -303,11 +303,13 @@ EVENT_SCHEMA: dict[str, dict[str, tuple | None]] = {
     # spans name the convoy (sequence number, width, bucket, worker
     # slot), its members (``ceremonies`` is there the list of their ids,
     # ``queue_wait_s`` each one's seconds queued, in the same order) and
-    # carry the stage seconds of service/engine.CONVOY_STAGES as subs.
+    # carry the stage seconds of service/engine.CONVOY_STAGES as subs,
+    # and as ``spans`` every stage's interval, ``[stage, start, end]`` in
+    # seconds from ``ts0``, in the order they closed.
     "span": {
         "required": ("name", "ts0", "mono0", "dur_s"),
         "optional": (
-            "subs", "curve", "requests", "messages", "ceremonies",
+            "subs", "spans", "curve", "requests", "messages", "ceremonies",
             "proved", "reason", "errors",
             "convoy", "width", "bucket", "slot", "queue_wait_s",
         ),
@@ -405,7 +407,8 @@ def to_chrome_trace(events: Iterable[dict]) -> dict:
     Mapping: one *process* per ceremony_id, one *thread* per party (the
     hub is tid 0); ``span`` events become complete ("X") slices with
     their ``subs`` rendered as nested child slices laid out sequentially
-    from the parent's start; runtimeobs ``jax_compile`` events become
+    from the parent's start (a span that carries ``spans``, its stages'
+    own intervals, has each child where it ran instead); runtimeobs ``jax_compile`` events become
     "X" slices on a dedicated per-process "jax compile" thread (so
     compiles visibly overlap — or starve — ceremony phases);
     ``counter_sample`` events become Chrome counter ("C") tracks; every
@@ -444,7 +447,7 @@ def to_chrome_trace(events: Iterable[dict]) -> dict:
             for k, v in ev.items()
             if k
             not in ("ts", "mono", "ts0", "mono0", "dur_s", "kind", "name",
-                    "ceremony_id", "party", "subs")
+                    "ceremony_id", "party", "subs", "spans")
         }
         if ev.get("kind") == "span":
             start_us = (wall0(ev) - t0) * 1e6
@@ -460,10 +463,20 @@ def to_chrome_trace(events: Iterable[dict]) -> dict:
                     "args": args,
                 }
             )
-            # nested sub-slices laid out back-to-back from the parent start
-            sub_ts = start_us
-            for sub, sec in (ev.get("subs") or {}).items():
-                sub_dur = float(sec) * 1e6
+            # nested sub-slices: where the span kept its stages' own
+            # intervals, each where it ran; else laid out back-to-back
+            # from the parent start
+            if ev.get("spans"):
+                placed = [
+                    (sub, start_us + float(a) * 1e6, (float(b) - float(a)) * 1e6)
+                    for sub, a, b in ev["spans"]
+                ]
+            else:
+                placed, sub_ts = [], start_us
+                for sub, sec in (ev.get("subs") or {}).items():
+                    placed.append((sub, sub_ts, float(sec) * 1e6))
+                    sub_ts += float(sec) * 1e6
+            for sub, sub_ts, sub_dur in placed:
                 trace.append(
                     {
                         "name": f"{ev.get('name', 'span')}.{sub}",
@@ -475,7 +488,6 @@ def to_chrome_trace(events: Iterable[dict]) -> dict:
                         "args": {},
                     }
                 )
-                sub_ts += sub_dur
         elif ev.get("kind") == "jax_compile":
             # runtimeobs compile-stage events: their own thread per
             # process, so recompiles read as a parallel track next to
@@ -637,8 +649,10 @@ def _attributed(
 ) -> tuple[float, float]:
     """(retry_s, fault_s) chargeable to ``party`` inside the wall-clock
     window [lo, hi]: recorded RPC backoff sleeps plus injected delay
-    faults for this round.  Each sum is clamped to the window width —
-    attribution can never exceed the time it is explaining."""
+    faults for this round, each charged to the window it was noted in
+    (a straggler that also closes the round is not charged its delay a
+    second time after its publish).  Each sum is clamped to the window
+    width — attribution can never exceed the time it is explaining."""
     width = max(0.0, hi - lo)
     retry = fault = 0.0
     for ev in evs:
@@ -653,6 +667,7 @@ def _attributed(
             and ev.get("fault") == "delay"
             and ev.get("round") == round_no
             and ev.get("seconds") is not None
+            and lo <= ts <= hi
         ):
             fault += float(ev.get("seconds"))
     retry = min(retry, width)

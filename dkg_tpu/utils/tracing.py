@@ -15,12 +15,18 @@ errors are the only signal).  Here observability is first-class:
   span event carrying the sub-timings accumulated during the phase.
 * :func:`book_phase` — what a completed span books (trace entry +
   histogram observation), for time in which nothing ran to annotate.
+* :data:`TIMELINE` — the process's last :data:`TIMELINE_DEPTH` convoy
+  records (service/scheduler.py assembles one where a convoy ends):
+  every stage's start and end, and every member's admission, pop and
+  completion, on one clock.  Always on; read when a run ends.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
+import threading
 import time
 from dataclasses import dataclass, field
 
@@ -37,6 +43,10 @@ class CeremonyTrace:
     # phase -> {sub -> seconds}; finer-grained than timings_s and kept
     # OUT of it so rates()/total_s never double-count a phase
     subtimings_s: dict = field(default_factory=dict)
+    # (phase, start, end) of every span booked, in the order they
+    # closed, on time.perf_counter(); timings_s is their sum by phase
+    # (a phase that ran twice is two entries here and one there)
+    spans: list = field(default_factory=list)
 
     def bump(self, name: str, by: int = 1) -> None:
         self.counters[name] = self.counters.get(name, 0) + by
@@ -103,6 +113,69 @@ class CeremonyTrace:
         return json.dumps(self.as_dict(), sort_keys=True)
 
 
+#: Convoy records the process keeps.  Not a knob: a benchmark window
+#: finishes some 2300 convoys at most, and a reader that finds the
+#: oldest record younger than its window's start says that the ring
+#: wrapped and reads nothing.
+TIMELINE_DEPTH = 8192
+
+
+class Timeline:
+    """The last :data:`TIMELINE_DEPTH` convoy records of the process, in
+    the order the convoys ended.  A record is plain data (ids, labels
+    and times: nothing a request carried), all its times on
+    ``time.perf_counter()``:
+
+    * ``convoy`` (the engine's sequence number), ``slot`` (the worker
+      that ran it; None off the worker's lane), ``bucket``, ``width``;
+    * ``popped``: when the scheduler took its members off the queue;
+    * ``spans``: the convoy trace's ``(phase, start, end)`` tuples;
+    * ``members``: ``(ceremony id, admitted, completed, status)`` each.
+
+    Process-wide like ``metrics.REGISTRY``, whose ``snapshot()["at"]``
+    is on the same clock: two snapshots cut the ring to a window."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._ring: collections.deque = collections.deque(maxlen=TIMELINE_DEPTH)
+
+    def append(self, record: dict) -> None:
+        with self._lock:
+            self._ring.append(record)
+
+    def snapshot(self) -> list:
+        with self._lock:
+            return list(self._ring)
+
+    def reset(self) -> None:
+        with self._lock:
+            self._ring.clear()
+
+
+#: The process-wide ring the scheduler appends to.
+TIMELINE = Timeline()
+
+
+def _clock_shift() -> float:
+    """``time.perf_counter() - time.monotonic()``: both clocks are
+    steady, so their difference is a constant of the process.  Read
+    here, once, as the reading of the tightest of a few pairs, so that
+    a thread switch between the two reads cannot shift a record."""
+    best = None
+    for _ in range(8):
+        m0 = time.monotonic()
+        p = time.perf_counter()
+        m1 = time.monotonic()
+        if best is None or m1 - m0 < best[0]:
+            best = (m1 - m0, p - (m0 + m1) / 2)
+    return best[1]
+
+
+#: Added to a ``time.monotonic()`` stamp, gives the same instant on
+#: ``time.perf_counter()``, the clock of the spans and of the records.
+MONOTONIC_TO_SPAN_CLOCK = _clock_shift()
+
+
 # jax.profiler availability, probed once per process: None = unprobed,
 # False = unavailable, else the TraceAnnotation class.  phase_span runs
 # per round in tight loops; the per-span import-and-try was measurable
@@ -122,15 +195,19 @@ def _annotation_cls():
     return _ANNOTATION_CLS
 
 
-def book_phase(trace: CeremonyTrace | None, phase: str, seconds: float) -> None:
-    """Book ``seconds`` to ``phase`` as a completed :func:`phase_span`
-    does: an entry of ``trace`` and a ``dkg_phase_seconds`` observation.
-    Called directly for time that is a phase but holds no work to
-    annotate (a dispatched convoy waiting for its worker to come back:
-    ``convoy.hold``, service/scheduler.py)."""
+def book_phase(
+    trace: CeremonyTrace | None, phase: str, start: float, end: float
+) -> None:
+    """Book the interval ``start``..``end`` (``time.perf_counter()``) to
+    ``phase`` as a completed :func:`phase_span` does: its seconds into
+    ``trace.timings_s``, the interval onto ``trace.spans``, and a
+    ``dkg_phase_seconds`` observation.  Called directly for time that is
+    a phase but holds no work to annotate (a dispatched convoy waiting
+    for its worker to come back: ``convoy.hold``, service/scheduler.py)."""
     if trace is not None:
-        trace.record(phase, seconds)
-    metrics.REGISTRY.observe("dkg_phase_seconds", seconds, phase=phase)
+        trace.record(phase, end - start)
+        trace.spans.append((phase, start, end))
+    metrics.REGISTRY.observe("dkg_phase_seconds", end - start, phase=phase)
 
 
 @contextlib.contextmanager
@@ -141,7 +218,15 @@ def phase_span(trace: CeremonyTrace | None, phase: str, annotate_device: bool = 
     if annotate_device:
         cls = _annotation_cls()
         if cls:
-            ann = cls(f"dkg/{phase}")
+            # the convoy's sequence number as the event's metadata (its
+            # name stays): the key a profile's host event and a
+            # TIMELINE record share
+            seq = trace.meta.get("convoy") if trace is not None else None
+            ann = (
+                cls(f"dkg/{phase}")
+                if seq is None
+                else cls(f"dkg/{phase}", convoy=seq)
+            )
     recorder = obslog.current()
     if recorder is not None and trace is not None:
         subs0 = dict(trace.subtimings_s.get(phase) or {})
@@ -149,8 +234,9 @@ def phase_span(trace: CeremonyTrace | None, phase: str, annotate_device: bool = 
     t0 = time.perf_counter()
     with ann:
         yield
-    dt = time.perf_counter() - t0
-    book_phase(trace, phase, dt)
+    t1 = time.perf_counter()
+    dt = t1 - t0
+    book_phase(trace, phase, t0, t1)
     # device/host memory watermark at the phase boundary (no-op unless
     # runtimeobs is installed; internally throttled)
     runtimeobs.maybe_sample(phase=phase)

@@ -1,6 +1,7 @@
 """Stage spans inside a convoy, the scheduler's queue wait, hold and request
 latency, the flight recorder's `convoy` span, and the benchmark's readers of
-them (ISSUE 25).
+them (ISSUE 25); every span's interval, the convoy's record in
+`tracing.TIMELINE` and the readers of the timeline (ISSUE 38).
 
 One scheduler run on the CPU at the suite's smallest bucket (ristretto255
 (5,2) -> (8,2)): a dozen seeded requests through one worker at convoy widths
@@ -38,6 +39,10 @@ READERS = (
     "queue_wait_program_ms", "convoy_hold_ms.steady",
     "convoy_device_wait_ms.steady",
 )
+TAIL_READERS = (
+    "tail_queue_wait_ms", "tail_hold_ms", "tail_device_wait_ms", "tail_host_ms", "tail_rest_ms",
+)
+TIMELINE_READERS = ("latency_p95_program_ms",) + TAIL_READERS + ("device_unfed_share", "longest_stall_ms")
 # every stage but `blame`, which runs only where a dealer cheated
 HONEST_STAGES = tuple(s for s in engine.CONVOY_STAGES if s != "blame")
 
@@ -88,11 +93,14 @@ def run(runtime):
         outs = [sch.result(cid, timeout=300) for cid in [first] + rest]
     finally:
         sch.close()
+    cids = {o.ceremony_id for o in outs}
     return {
         "outs": outs,
         "before": before,
         "after": REGISTRY.snapshot(),
         "events": log.events(),
+        # the ring is the process's: this run's records are those of its requests
+        "timeline": [r for r in tracing.TIMELINE.snapshot() if cids & {m[0] for m in r["members"]}],
     }
 
 
@@ -230,8 +238,8 @@ def test_book_phase_books_trace_and_registry():
     trace = tracing.CeremonyTrace()
     series = 'dkg_phase_seconds{phase="test.book_phase"}'
     before = REGISTRY.snapshot()["histograms"].get(series, {"count": 0})["count"]
-    tracing.book_phase(trace, "test.book_phase", 0.25)
-    tracing.book_phase(None, "test.book_phase", 0.5)
+    tracing.book_phase(trace, "test.book_phase", 1.0, 1.25)
+    tracing.book_phase(None, "test.book_phase", 2.0, 2.5)
     assert trace.timings_s == {"test.book_phase": 0.25}
     assert REGISTRY.snapshot()["histograms"][series]["count"] == before + 2
 
@@ -242,7 +250,7 @@ def readers():
     sys.path.insert(0, bench)  # the readers import their sibling bench_spans
     try:
         loaded = {}
-        for name in READERS:
+        for name in READERS + TIMELINE_READERS:
             spec = importlib.util.spec_from_file_location(
                 f"reader_{name.replace('.', '_')}", ROOT / "benchmark" / "layer_metrics" / f"{name}.py"
             )
@@ -290,3 +298,129 @@ def test_a_convoy_in_flight_at_the_windows_end_does_not_inflate_the_mean(readers
     ctx = {"counters": {"before": snap([], [], []), "after": snap([0.010] * 3, [1.0] * 3, [0.020] * 2)}}
     assert readers["convoy_hold_ms"].read(ctx) == pytest.approx(1000.0)
     assert readers["convoy_host_ms"].read(ctx) == pytest.approx(30.0)
+
+
+def _convoy_spans(run):
+    return {e["convoy"]: e for e in run["events"] if e["kind"] == "span" and e["name"] == "convoy"}
+
+
+def test_one_timeline_record_per_convoy_with_its_members(run):
+    records, spans = run["timeline"], _convoy_spans(run)
+    assert sorted(r["convoy"] for r in records) == sorted(spans)
+    by_cid = {o.ceremony_id: o for o in run["outs"]}
+    assert sorted(m[0] for r in records for m in r["members"]) == sorted(by_cid)
+    for r in records:
+        s = spans[r["convoy"]]
+        assert [m[0] for m in r["members"]] == s["ceremonies"]
+        assert (r["width"], r["bucket"], r["slot"]) == (len(r["members"]), "8x2", 0)
+        assert all(m[3] == "done" for m in r["members"])
+        for (cid, admitted, completed, _), waited in zip(r["members"], s["queue_wait_s"]):
+            assert r["popped"] - admitted == pytest.approx(waited, abs=1e-6)
+            assert completed - admitted >= by_cid[cid].queue_seconds + by_cid[cid].seconds * r["width"] * 0.95
+
+
+def test_a_convoys_spans_are_in_order_and_add_up_to_its_stage_seconds(run):
+    spans = _convoy_spans(run)
+    for r in run["timeline"]:
+        phases = [p for p, _, _ in r["spans"]]
+        assert phases == [f"convoy.{s}" for s in HONEST_STAGES]  # each once, in the order they ran
+        for (_, start, end), (_, nxt, _) in zip(r["spans"], r["spans"][1:]):
+            assert start <= end <= nxt  # none overlaps the next: hold covers no stage of its own convoy
+        for stage, seconds in spans[r["convoy"]]["subs"].items():  # the trace's timings_s
+            assert sum(b - a for p, a, b in r["spans"] if p == f"convoy.{stage}") == pytest.approx(seconds, abs=1e-12)
+
+
+def test_the_records_stamps_and_spans_are_on_one_clock(run):
+    for r in run["timeline"]:
+        first, last = r["spans"][0][1], r["spans"][-1][2]
+        for _, admitted, completed, _ in r["members"]:
+            assert admitted <= r["popped"] <= first
+            assert last <= completed
+        # a snapshot says when it was taken on the same clock: the run's two bracket its records
+        assert run["before"]["at"] <= min(m[1] for m in r["members"])
+        assert max(m[2] for m in r["members"]) <= run["after"]["at"]
+
+
+def test_the_convoy_span_places_every_stage_where_it_ran(run):
+    for r in run["timeline"]:
+        s = _convoy_spans(run)[r["convoy"]]
+        assert [x[0] for x in s["spans"]] == list(s["subs"])
+        start = r["spans"][0][1] - s["spans"][0][1]  # the span's own start on the record's clock
+        assert r["popped"] <= start + 1e-6
+        for (stage, a, b), (phase, t0, t1) in zip(s["spans"], r["spans"]):
+            assert phase == f"convoy.{stage}" and 0 <= a <= b <= s["dur_s"]
+            assert (a, b) == (pytest.approx(t0 - start, abs=1e-9), pytest.approx(t1 - start, abs=1e-9))
+    held = next(s for s in _convoy_spans(run).values() if s["subs"]["hold"] > 0.01)
+    slices = {e["name"]: e for e in obslog.to_chrome_trace([held])["traceEvents"] if e["ph"] == "X"}
+    offset = next(a for stage, a, _ in held["spans"] if stage == "hold")
+    assert slices["convoy.hold"]["ts"] == pytest.approx(slices["convoy"]["ts"] + offset * 1e6)
+    assert slices["convoy.hold"]["dur"] == pytest.approx(held["subs"]["hold"] * 1e6)
+
+
+def test_the_ring_is_bounded_and_reset_empties_it():
+    ring = tracing.Timeline()
+    for i in range(tracing.TIMELINE_DEPTH + 5):
+        ring.append({"convoy": i})
+    kept = ring.snapshot()
+    assert len(kept) == tracing.TIMELINE_DEPTH == 8192
+    assert (kept[0]["convoy"], kept[-1]["convoy"]) == (5, tracing.TIMELINE_DEPTH + 4)
+    ring.reset()
+    assert ring.snapshot() == []
+    assert isinstance(tracing.TIMELINE, tracing.Timeline)
+
+
+def test_the_clock_shift_is_one_constant_of_the_process():
+    """The scheduler's monotonic stamps go onto the spans' clock by a difference read
+    once at import, not once a convoy: a thread switch cannot shift a record."""
+    now = min(abs(time.perf_counter() - time.monotonic() - tracing.MONOTONIC_TO_SPAN_CLOCK) for _ in range(8))
+    assert now < 1e-3
+    assert tracing._clock_shift() == pytest.approx(tracing.MONOTONIC_TO_SPAN_CLOCK, abs=1e-3)
+
+
+def test_phase_span_keeps_every_interval_and_timings_stay_their_sum():
+    trace = tracing.CeremonyTrace(meta={"convoy": 7})
+    t0 = time.perf_counter()
+    for _ in range(2):  # a phase that runs twice: two entries, one sum
+        with tracing.phase_span(trace, "test.twice"):
+            pass
+    tracing.book_phase(trace, "test.held", t0, t0 + 0.5)
+    assert [p for p, _, _ in trace.spans] == ["test.twice", "test.twice", "test.held"]
+    assert trace.timings_s["test.twice"] == sum(b - a for p, a, b in trace.spans if p == "test.twice")
+    assert trace.spans[2] == ("test.held", t0, t0 + 0.5) and trace.timings_s["test.held"] == 0.5
+    assert t0 <= trace.spans[0][1] <= trace.spans[0][2] <= trace.spans[1][1] <= time.perf_counter()
+    assert "spans" not in trace.as_dict()  # as_dict is what it was
+
+
+def _timeline_ctx(run):
+    return {
+        "counters": {"before": run["before"], "after": run["after"]},
+        "seconds": run["after"]["at"] - run["before"]["at"],
+        "cell": {"trace_seconds": 1.0, "drain_s": 60.0},
+        "records": [], "trace": None,
+    }
+
+
+@pytest.mark.parametrize("name", TIMELINE_READERS)
+def test_timeline_reader_reads_the_real_run_and_nothing_without_the_stamp(run, readers, name, capsys):
+    value = readers[name].read(_timeline_ctx(run))
+    assert value is not None and math.isfinite(value) and value > 0
+    assert "[timeline] " in capsys.readouterr().out  # each reader says what it read on a line of the log
+    old = {k: v for k, v in run["after"].items() if k != "at"}  # a program whose snapshots carry no "at"
+    assert readers[name].read({**_timeline_ctx(run), "counters": {"before": old, "after": old}}) is None
+
+
+def test_timeline_readers_agree_with_the_outcomes(run, readers):
+    ctx = _timeline_ctx(run)
+    latencies = sorted(m[2] - m[1] for r in run["timeline"] for m in r["members"])
+    assert readers["latency_p95_program_ms"].read(ctx) == pytest.approx(latencies[-1] * 1e3)  # ceil(0.95 x 12) = 12
+    # the tail is that one request: its five parts are its latency, to the float
+    parts = {n: readers[n].read(ctx) for n in TAIL_READERS}
+    assert sum(parts.values()) == pytest.approx(latencies[-1] * 1e3, abs=1e-9)
+    worst = max(run["outs"], key=lambda o: o.queue_seconds)
+    assert parts["tail_queue_wait_ms"] == pytest.approx(worst.queue_seconds * 1e3, abs=1e-3)
+    # one worker: while it draws, folds rho or sits between convoys nobody feeds the chip
+    assert 0.0 < readers["device_unfed_share"].read(ctx) < 1.0
+    # a dozen requests admitted at once and finished convoy by convoy: the longest
+    # stretch without a completion is about a convoy, and under the whole run
+    stall_ms = readers["longest_stall_ms"].read(ctx)
+    assert 0.0 < stall_ms < (run["after"]["at"] - run["before"]["at"]) * 1e3

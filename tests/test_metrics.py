@@ -2,6 +2,7 @@
 
 import json
 import threading
+import time
 
 from dkg_tpu.utils.metrics import (
     DEFAULT_BUCKETS,
@@ -84,7 +85,9 @@ def test_reset_drops_every_series():
     reg.set_gauge("z", 1)
     reg.reset()
     snap = reg.snapshot()
+    at = snap.pop("at")
     assert snap == {"counters": {}, "gauges": {}, "histograms": {}}
+    assert 0 < at <= time.perf_counter()  # when it was taken, on the timeline's clock
 
 
 def test_observe_trace_feeds_phases_subs_and_counters():
