@@ -315,13 +315,31 @@ def _straus_tiles(cs, weights: jax.Array, points: jax.Array, nbits: int, fused: 
     packing follows from the shape: at (1024, 64) every level of the
     tree but the last halves whole blocks; a (16, 6) ceremony is one
     block, its levels lane slices of it.
+
+    A convoy's stack (weights (k, m, L), points (k, m, cols, C, L)) is
+    the same schedule with the ceremony axis joined to the columns:
+    lanes ordered (dealer, ceremony, column), which is the order the
+    tree halves on, and a lane's digit its (ceremony, dealer)'s.  Eight
+    (16, 6) ceremonies are then 768 lanes in 6 blocks and 48 accumulator
+    lanes in 1, where one padded block a ceremony made 8 and 8; the body
+    books what it packed (``point_rlc_lanes_traced_total``).
     """
     from ..ops import pallas_point as pp
 
+    stack = weights.shape[:-2]  # () or a convoy's (k,)
+    if stack:
+        points = jnp.moveaxis(points, len(stack), 0)  # (m, k, cols, C, L)
     m, window = points.shape[0], gd.WINDOW
     nd = -(-nbits // window)  # windows that can be non-zero
     p_t, _, lanes = pp.to_tiles(cs, points)
-    nb, cols = p_t.shape[0], lanes // m
+    nb, cols = p_t.shape[0], lanes // m  # cols: a dealer's lanes, every ceremony's columns
+    nb_acc = -(-cols // pp.BLOCK)
+    width = str(stack[0] if stack else 1)
+    for part, live, blocks in (("points", lanes, nb), ("acc", cols, nb_acc)):
+        REGISTRY.inc("point_rlc_lanes_traced_total", live, part=part, kind="live", stack=width)
+        REGISTRY.inc(
+            "point_rlc_lanes_traced_total", blocks * pp.BLOCK, part=part, kind="block", stack=width
+        )
     ident = pp.identity_tiles(cs)
 
     def entry(prev, _):
@@ -329,8 +347,12 @@ def _straus_tiles(cs, weights: jax.Array, points: jax.Array, nbits: int, fused: 
         return nxt, nxt
 
     _, rest = lax.scan(entry, p_t, None, length=14)  # 2P..15P: (14, nb, C·L, BLOCK)
-    digits = gd.scalar_windows(cs, weights, window)[:, :nd]  # (m, nd)
-    lane_digits = jnp.repeat(jnp.moveaxis(digits, -1, 0)[::-1], cols, axis=1)  # (nd, lanes) MSB first
+    digits = gd.scalar_windows(cs, weights, window)[..., :nd]  # (m, nd), a stack's (k, m, nd)
+    if stack:
+        digits = jnp.reshape(jnp.moveaxis(digits, -2, 0), (-1, nd))  # (m·k, nd), dealer-major as the lanes
+    lane_digits = jnp.repeat(
+        jnp.moveaxis(digits, -1, 0)[::-1], lanes // digits.shape[0], axis=1
+    )  # (nd, lanes) MSB first
     lane_digits = jnp.pad(lane_digits, ((0, 0), (0, nb * pp.BLOCK - lanes)))
 
     def step(acc, dig):
@@ -341,7 +363,7 @@ def _straus_tiles(cs, weights: jax.Array, points: jax.Array, nbits: int, fused: 
         total = gd._tree_tiles(cs, contribs, m, cols)
         return gd.window_step(cs, acc, total, window, fused, tiles=True), None
 
-    acc, _ = lax.scan(step, pp.identity_tiles(cs, -(-cols // pp.BLOCK)), lane_digits)
+    acc, _ = lax.scan(step, pp.identity_tiles(cs, nb_acc), lane_digits)
     return pp.from_tiles(cs, acc, points.shape[1:-2], cols)
 
 
@@ -349,7 +371,12 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
     """sum_j weights[j]·P[j, ...] for nbits-wide public weights.
 
     weights (m, L) limb arrays with only the low nbits set;
-    points (m, ..., C, L) -> (..., C, L).
+    points (m, ..., C, L) -> (..., C, L).  A convoy's stack carries
+    its ceremony axis in front of both, each ceremony with weights of
+    its own: weights (k, m, L), points (k, m, ..., C, L) ->
+    (k, ..., C, L).  The block form packs the stack's lanes jointly
+    (:func:`_straus_tiles`); the tensor forms have no lanes to pack
+    and map the ceremonies.
 
     Three schedules, same sum:
 
@@ -382,11 +409,13 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
     projective coordinates are NOT canonical.  Compare it with
     ``gd.eq`` (as :func:`verify_batch` does); nothing reads its limbs.
     Each traced schedule body books ``point_rlc_traced_total{schedule,
-    form}`` (a chunked call traces two: the map's body and the tail).
+    form, stack}`` (a chunked call traces two: the map's body and the
+    tail; ``stack`` is the ceremonies the body holds, 1 without the axis).
     """
     from ..utils import envknobs
 
-    m = points.shape[0]
+    stack = weights.shape[:-2]  # () or a convoy's (k,)
+    m = points.shape[len(stack)]
     mode = envknobs.choice(
         "DKG_TPU_RLC",
         ("straus", "bits", "pippenger"),
@@ -400,7 +429,10 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
             else "pippenger"
         )
     tiles = mode == "straus" and gd.fused_kernels_active()
-    if mode != "bits" and points.ndim > 3:
+    if stack and not tiles:
+        return jax.vmap(lambda w, p: _point_rlc(cs, w, p, nbits))(weights, points)
+    col_axis = len(stack) + 1
+    if mode != "bits" and points.ndim > col_axis + 2:
         # Chunk the first trailing batch axis so the per-chunk temps
         # (per-point Straus tables / Pippenger buckets) stay under
         # ~256 MB regardless of (m, t); any FURTHER batch axes multiply
@@ -416,25 +448,30 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
             pwin = gd.pippenger_window(m, cs.name)
             nw = -(-nbits // pwin)
             per_col = nw * (1 << pwin) * cs.ncoords * cs.field.limbs * 4
-        for extra in points.shape[2:-2]:
+        for extra in stack + points.shape[col_axis + 1 : -2]:  # a column of every ceremony
             per_col *= extra
         chunk = _env_chunk("DKG_TPU_RLC_CHUNK")
         if chunk is None:
             chunk = max(1, (256 << 20) // per_col)
             if tiles:  # a power of two: the dealers' halves then fall on block edges
                 chunk = 1 << (chunk.bit_length() - 1)
-        ncols = points.shape[1]
+        ncols = points.shape[col_axis]
         if chunk and ncols > chunk:
             from ..utils.scanchunk import map_chunked
 
             def col_chunk(off, w):
-                cols = lax.dynamic_slice_in_dim(points, off, w, axis=1)
-                return _point_rlc(cs, weights, cols, nbits)
+                cols = lax.dynamic_slice_in_dim(points, off, w, axis=col_axis)
+                out = _point_rlc(cs, weights, cols, nbits)
+                return jnp.moveaxis(out, len(stack), 0) if stack else out  # the map joins on axis 0
 
-            return map_chunked(ncols, chunk, col_chunk)
+            out = map_chunked(ncols, chunk, col_chunk)
+            return jnp.moveaxis(out, 0, len(stack)) if stack else out
 
     REGISTRY.inc(
-        "point_rlc_traced_total", schedule=mode, form="blocks" if tiles else "tensor"
+        "point_rlc_traced_total",
+        schedule=mode,
+        form="blocks" if tiles else "tensor",
+        stack=str(stack[0] if stack else 1),
     )
     if tiles:
         return _straus_tiles(cs, weights, points, nbits, fused)
@@ -503,13 +540,23 @@ def verify_batch(
     Sound up to 2^-rho_bits per cheating dealer; on False the caller
     falls back to ``verify_pairwise`` rows for blame assignment
     (mirrors the complaint path, committee.rs:305-317).
+
+    A convoy's stack (``service.engine._verify_stack``) carries its
+    ceremony axis in front of the four tensors, ``rho`` (k, n, L) a
+    ceremony's own, and gets (k, n) bool: the same k·n equations, the
+    axis a batch axis of the three kernel users below, so that their
+    lane blocks fill with the convoy's lanes and not one ceremony's.
     """
     cs = cfg.cs
     fs = cs.scalar
+    stack = rho.shape[:-2]  # () or a convoy's (k,)
 
     # per-recipient scalar RLCs over dealers:  (n_recipients, L)
-    s_rlc = _field_dot(fs, rho, shares)  # sum_j rho_j s_{j,i}
-    r_rlc = _field_dot(fs, rho, hidings)
+    dot = functools.partial(_field_dot, fs)
+    if stack:  # scalar field, no lane blocks to fill: mapped
+        dot = jax.vmap(dot)
+    s_rlc = dot(rho, shares)  # sum_j rho_j s_{j,i}
+    r_rlc = dot(rho, hidings)
 
     # combined commitment columns D_l = sum_j rho_j E_{j,l}: (t+1, C, L)
     # (the fused path chunks the column axis internally to bound its
@@ -518,6 +565,8 @@ def verify_batch(
 
     # RHS_i = sum_l x_i^l D_l via small-x point Horner: (n, C, L)
     xs = jnp.arange(1, cfg.n + 1, dtype=jnp.uint32)
+    if stack:
+        d_comm = d_comm[..., None, :, :, :]  # a ceremony's columns, for each of its recipients
     rhs = gd.eval_point_poly(cs, d_comm, xs, cfg.index_bits)
 
     # LHS_i = g·s_rlc + h·r_rlc

@@ -310,10 +310,12 @@ def _deal_stack(cfg, coeffs_a, coeffs_b, g_table, h_table):
 
 @functools.partial(jax.jit, static_argnums=(0, 5))
 def _verify_stack(cfg, e_comm, shares, hidings, rho, rho_bits, g_table, h_table):
-    def one(e1, s1, r1, rho1):
-        return ce.verify_batch(cfg, e1, s1, r1, rho1, rho_bits, g_table, h_table)
-
-    return jax.vmap(one)(e_comm, shares, hidings, rho)
+    """(k, n, ...) stacks -> (k, n) bool.  Not a ``vmap``: ``verify_batch``
+    takes the ceremony axis itself and packs the convoy's lanes jointly
+    onto the point kernels' blocks; a map would pad each ceremony's
+    lanes to blocks of its own.  A program of this name so that the
+    store's key and the device trace (``jit__verify_stack``) stay."""
+    return ce.verify_batch(cfg, e_comm, shares, hidings, rho, rho_bits, g_table, h_table)
 
 
 @functools.partial(jax.jit, static_argnums=0)

@@ -264,3 +264,41 @@ def test_fixed_base_block_form_keeps_its_layout_for_v5e(one_chip, curve, monkeyp
     changes = [c for c in _layout_changes(text, rows * pp.BLOCK) if c[0] in inside]
     of_the_table = [c for c in changes if "65536" in c[1]]
     assert len(of_the_table) <= 1 and len(changes) - len(of_the_table) <= 1, changes
+
+
+def test_verify_stack_packs_the_convoys_lanes_for_v5e(one_chip, monkeypatch):
+    """A width-8 (16,5) convoy's verify as the chip compiles it: the
+    kernels' launches are on the convoy's JOINT lane blocks.  8 x 16 x 6
+    = 768 commitment lanes are 6 blocks (the table's ``pt_add``), the
+    tree over the dealers halves them 3, 2, 1, 1, and the window step,
+    the Horner ladder (8 x 16 = 128 recipients), the fixed-base
+    ``pt_madd`` and the closing add are one block each.  The parent of
+    PR 39 is the counter-example: a ``vmap`` over the ceremonies, all
+    ten launches on 8 blocks, one padded block a ceremony (96 and 6
+    live lanes of 128).  The program keeps the name its metric reads."""
+    from dkg_tpu.dkg import ceremony as ce
+    from dkg_tpu.service import engine
+
+    monkeypatch.setenv("DKG_TPU_ASSUME_BACKEND", "tpu")
+    for name in ("DKG_TPU_PALLAS", "DKG_TPU_MUL", "DKG_TPU_RLC", "DKG_TPU_RLC_CHUNK"):
+        monkeypatch.delenv(name, raising=False)
+    cfg = ce.CeremonyConfig("secp256k1", 16, 5)
+    cs, k = cfg.cs, 8
+    L, C, S = cs.field.limbs, cs.ncoords, cs.scalar.limbs
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
+    table = spec(S, 1 << 16, C, L)
+    try:
+        text = engine._verify_stack.lower(
+            cfg, spec(k, 16, 6, C, L), spec(k, 16, 16, S), spec(k, 16, 16, S), spec(k, 16, S), 128, table, table
+        ).compile().as_text()
+    finally:
+        # the jitted helpers inside (``eval_point_poly``) were traced as
+        # for the chip: they must not answer a CPU caller of these shapes
+        jax.clear_caches()
+    assert "HloModule jit__verify_stack" in text
+    launches = [
+        [int(d) for d in shape.split(",")]
+        for shape in re.findall(r'u32\[([\d,]*)\]\S* custom-call\([^\n]*custom_call_target="tpu_custom_call"', text)
+    ]
+    assert all(shape[-2:] == [C * L, pp.BLOCK] for shape in launches), launches
+    assert sorted(int(np.prod(shape[:-2])) for shape in launches) == [1] * 7 + [2, 3, 6], launches
