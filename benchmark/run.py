@@ -4,8 +4,10 @@
     python benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Progress goes to earlier lines of standard output; the last line is one JSON
-object (`correct`, `attempted`, `failed`, `metrics`, `device`, and with
-`--trace 1` `breakdown`).  `main` refuses to measure without a TPU;
+object (`correct`, `attempted`, `failed`, `metrics`, `device`, with
+`--trace 1` `breakdown`, and last `compared`: each count of the comparison, all with
+the limit 0 but `shares_compared_ceremonies`, at least 1; the same on the last lines of
+standard error).  `main` refuses to measure without a TPU;
 `run_cell` beneath it is the whole loop and is what the tests rehearse on
 the CPU with a tiny cell of their own.
 
@@ -40,7 +42,9 @@ T_PROCESS = time.perf_counter()
 import argparse  # noqa: E402
 import collections  # noqa: E402
 import contextlib  # noqa: E402
+import gc  # noqa: E402
 import importlib.util  # noqa: E402
+import itertools  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -58,6 +62,13 @@ for _p in (str(REPO), str(BENCH_DIR)):
 
 TERMINAL = ("done", "failed", "expired", "poisoned")
 POLL_S = 0.002
+# Every tick asks the scheduler about the oldest requests in flight, not about all of
+# them: a backlog of thousands (an open loop over its knee, or behind a hole of seconds)
+# otherwise costs the one interpreter lock half a million `poll` calls a second and slows
+# the workers it waits for (PR 41's first sweep: 16 convoys a second where 68 are served).
+# Requests finish in the order admitted but for the convoys the workers hold at once
+# (4 workers x 2 deep x 8), so every finished request is among these.
+POLL_OLDEST = 128
 
 
 def note(msg: str) -> None:
@@ -185,7 +196,7 @@ def _drive(sched, plan: dict, seconds: float, drain_s: float, to_request, tracer
             with jax.profiler.TraceAnnotation("bench:draw"):
                 pending = next(requests, None)
             now = time.perf_counter() - t0
-        for cid in [c for c in flying if sched.poll(c) in TERMINAL]:
+        for cid in [c for c in itertools.islice(flying, POLL_OLDEST) if sched.poll(c) in TERMINAL]:
             rec = flying.pop(cid)
             rec["outcome"] = sched.result(cid, timeout=5.0)
             rec["fetched_s"] = time.perf_counter() - t0
@@ -220,8 +231,10 @@ def _drive(sched, plan: dict, seconds: float, drain_s: float, to_request, tracer
 class _Tracer:
     """Profiles the last `span_s` of the window, where asked to.  Stopping the
     profiler blocks this thread for a minute and more while it writes some
-    hundred MB, so the slice ends with the window: the requests of the window
-    are sent and timed untraced, and only the drain waits for the profiler."""
+    hundred MB, so the slice ends with the window and `tick` only closes its
+    mark: the profiler is stopped by `stop`, once the drain has fetched what the
+    window left in flight (an open loop at 360/s leaves some forty requests, and
+    a stop inside the drain put 42 s without an admission into the readers' window)."""
 
     def __init__(self, log_dir: pathlib.Path | None, start_s: float, span_s: float) -> None:
         self.log_dir, self.start_s, self.span_s = log_dir, start_s, span_s
@@ -245,16 +258,51 @@ class _Tracer:
             self.mark.__enter__()
             self.began = time.perf_counter()
         elif self.began is not None and time.perf_counter() - self.began >= self.span_s:
-            self.stop()
+            self.close()
+
+    def close(self) -> None:
+        """The slice's end: what the profiler records after it is cut off by the mark."""
+        if self.began is not None and self.window_s is None:
+            self.window_s = time.perf_counter() - self.began
+            self.mark.__exit__(None, None, None)
 
     def stop(self) -> None:
-        if self.began is None or self.window_s is not None:
+        if self.began is None or self.mark is None:
             return
         import jax
 
-        self.window_s = time.perf_counter() - self.began
-        self.mark.__exit__(None, None, None)
+        self.close()
+        self.mark = None
         jax.profiler.stop_trace()
+
+
+class _GcWatch:
+    """The interpreter's collections inside the window, for one line of the log: every
+    thread stands still through a collection, and a full one walks the whole heap, the
+    programs' and the outcomes the harness keeps for the comparison.  A callback a
+    collection: the generation, when it began (seconds from `t0`) and how long it took."""
+
+    def __init__(self) -> None:
+        self.t0 = time.perf_counter()
+        self.began = 0.0
+        self.count = [0, 0, 0]
+        self.long: list[tuple[float, float, int]] = []  # (seconds, began, generation)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self.began = time.perf_counter()
+            return
+        took = time.perf_counter() - self.began
+        self.count[info["generation"]] += 1
+        if info["generation"] == 2 or took >= 0.02:
+            self.long.append((took, self.began - self.t0, info["generation"]))
+
+    def line(self) -> str:
+        worst = sorted(self.long, reverse=True)[:4]
+        return (
+            f"collections in the window and its drain by generation {self.count}; the longest (and every full one): "
+            + (", ".join(f"{took:.3f}s at {at:.3f}s (generation {gen})" for took, at, gen in worst) or "none over 0.02s")
+        )
 
 
 def _judge(records: list[dict], config: dict, seed: int) -> dict:
@@ -282,6 +330,15 @@ def _judge(records: list[dict], config: dict, seed: int) -> dict:
     return dict(totals)
 
 
+def compared_lines(compared: dict) -> list[str]:
+    """Each number the comparison counted beside its limit: every count has the limit
+    0 but the ceremonies compared, of which a run needs one."""
+    return [
+        f"compared {key} = {value} ({'at least 1' if key == 'shares_compared_ceremonies' else 'limit 0'})"
+        for key, value in compared.items()
+    ]
+
+
 def _convoys(records: list[dict]) -> None:
     """A convoy's members carry the same float `seconds` (its wall time over its
     width), so counting equals gives back the width and the convoy's time."""
@@ -301,12 +358,28 @@ def _pace(records: list[dict], seconds: float, uncounted: int) -> None:
     if len(times) < 2:
         return
     gaps = sorted((b - a, a) for a, b in zip(times, times[1:]))
-    tenths = collections.Counter(min(9, int(t / seconds * 10)) for t in times)
+    convoys = collections.Counter(min(9, int(t / seconds * 10)) for t in times)
     note(
         f"pace: {len(records)} ceremonies in {len(times)} convoys counted, {uncounted} ceremonies "
         f"not counted; first completion {times[0]:.3f}s, last {times[-1]:.3f}s; gap between "
         f"completions median {gaps[len(gaps) // 2][0]:.3f}s, longest {gaps[-1][0]:.3f}s at "
-        f"{gaps[-1][1]:.3f}s; convoys per tenth of the window {[tenths[i] for i in range(10)]}"
+        f"{gaps[-1][1]:.3f}s; convoys per tenth of the window {[convoys[i] for i in range(10)]}; "
+        f"ceremonies per tenth {bench_stats.tenths(records, seconds)}, their median as a rate "
+        f"{bench_stats.pace_median_per_s(records, seconds):.3f}/s"
+    )
+
+
+def _load(records: list[dict], seconds: float) -> None:
+    """An open-loop run's own line: whether the rate was held (bench_stats.open_loop_load)."""
+    import bench_stats
+
+    load = bench_stats.open_loop_load(records, seconds)
+    ms = lambda v: "none" if v is None else f"{v * 1e3:.3f}"  # noqa: E731
+    note(
+        f"open loop: {len(records)} requests due, {load['refused']} refused, {load['unsent']} unsent, "
+        f"{load['unfinished']} unfinished; mean latency of the requests due in each fifth of the window, ms "
+        f"[{', '.join(ms(v) for v in load['latency_by_fifth_s'])}]; generator lateness after the first "
+        f"second: p99 {ms(load['late_p99_s'])} ms, max {ms(load['late_max_s'])} ms"
     )
 
 
@@ -377,7 +450,12 @@ def run_cell(
             before, counters_before = _must_not(aot, runtimeobs), REGISTRY.snapshot()
             setup_s = time.perf_counter() - t_start
             note(f"set-up {setup_s:.2f}s; window {seconds}s")
-            drive = _drive(sched, plan, float(seconds), float(cell.get("drain_s", 60.0)), to_request, tracer)
+            watch = _GcWatch()
+            gc.callbacks.append(watch)
+            try:
+                drive = _drive(sched, plan, float(seconds), float(cell.get("drain_s", 60.0)), to_request, tracer)
+            finally:
+                gc.callbacks.remove(watch)
             after, counters_after = _must_not(aot, runtimeobs), REGISTRY.snapshot()
         finally:
             tracer.stop()
@@ -391,11 +469,15 @@ def run_cell(
     for key in before:
         compared[f"window_{key}"] = after[key] - before[key]
     note(f"reference and comparison {time.perf_counter() - t0:.2f}s over {len(records)} outcomes")
-    for key, value in compared.items():
-        limit = "at least 1" if key == "shares_compared_ceremonies" else "limit 0"
-        note(f"compared {key} = {value} ({limit})")
-    correct = compared.pop("shares_compared_ceremonies") >= 1 and not any(compared.values())
+    for line in compared_lines(compared):
+        note(line)
+    correct = compared["shares_compared_ceremonies"] >= 1 and not any(
+        value for key, value in compared.items() if key != "shares_compared_ceremonies"
+    )
     _pace(records, float(seconds), drive["uncounted"])
+    note(watch.line())
+    if plan["outstanding"] is None:
+        _load(records, float(seconds))
     late = sorted(drive["late_s"])
     if late:
         note(
@@ -457,6 +539,7 @@ def run_cell(
     if reduced is not None:
         device["busy_s"], device["window_s"] = reduced["busy_s"], reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"]}
+    result["compared"] = compared  # last: each number beside its limit (`compared_lines`)
     return result
 
 
@@ -477,6 +560,7 @@ def main(argv: list[str] | None = None) -> int:
         REPO / "BENCHMARK.json", args.workload, args.seed, args.seconds, bool(args.trace), T_PROCESS
     )
     print(json.dumps(result), flush=True)
+    print("\n".join(compared_lines(result["compared"])), file=sys.stderr, flush=True)
     return 0
 
 
