@@ -142,7 +142,7 @@ def _path_facts(cs, table) -> dict:
     fused, interpret = bool(fd.fused_kernels_active()), not fd._on_tpu()
     return {
         "fused_kernels_active": fused,
-        "fused_multi_active": bool(gd.fused_multi_active(cs)),
+        "point_kernel_tier": gd.point_kernel_tier(),
         "pallas_interpret": interpret,
         "kernel_mul_core": pf.rows_mul_dispatch(cs.field, interpret) if fused else None,
         "xla_mul": fd.mul_dispatch_mode(cs.field),

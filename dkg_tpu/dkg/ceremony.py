@@ -304,7 +304,7 @@ def _field_dot(fs, weights: jax.Array, values: jax.Array) -> jax.Array:
     return acc
 
 
-def _straus_tiles(cs, weights: jax.Array, points: jax.Array, nbits: int, fused: bool) -> jax.Array:
+def _straus_tiles(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Array:
     """The Straus schedule of :func:`_point_rlc` in the point kernels'
     lane-block form (``ops.pallas_point.to_tiles``): ``points`` is
     converted once, (dealer, column) row-major onto lanes, the table's
@@ -361,7 +361,7 @@ def _straus_tiles(cs, weights: jax.Array, points: jax.Array, nbits: int, fused: 
         for k in range(14):
             contribs = jnp.where(dig == k + 2, rest[k], contribs)
         total = gd._tree_tiles(cs, contribs, m, cols)
-        return gd.window_step(cs, acc, total, window, fused, tiles=True), None
+        return pp.window_step_tiles(cs, acc, total, window), None
 
     acc, _ = lax.scan(step, pp.identity_tiles(cs, nb_acc), lane_digits)
     return pp.from_tiles(cs, acc, points.shape[1:-2], cols)
@@ -421,14 +421,14 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
         ("straus", "bits", "pippenger"),
         "a typo would silently measure the wrong schedule",
     )
-    fused = gd.fused_multi_active(cs)
+    fused = gd.fused_kernels_active()
     if mode is None:
         mode = (
             "straus"
-            if gd.fused_kernels_active() or fd._on_tpu()
+            if fused or fd._on_tpu()
             else "pippenger"
         )
-    tiles = mode == "straus" and gd.fused_kernels_active()
+    tiles = mode == "straus" and fused
     if stack and not tiles:
         return jax.vmap(lambda w, p: _point_rlc(cs, w, p, nbits))(weights, points)
     col_axis = len(stack) + 1
@@ -474,7 +474,7 @@ def _point_rlc(cs, weights: jax.Array, points: jax.Array, nbits: int) -> jax.Arr
         stack=str(stack[0] if stack else 1),
     )
     if tiles:
-        return _straus_tiles(cs, weights, points, nbits, fused)
+        return _straus_tiles(cs, weights, points, nbits)
 
     if mode == "pippenger":
         # weights broadcast over the column axes; the m axis moves last

@@ -46,57 +46,29 @@ WINDOW = 4  # window bits for scalar decomposition (16-entry tables)
 fused_kernels_active = fd.fused_kernels_active
 
 
-def fused_multi_active(cs: "CurveSpec") -> bool:
-    """Whether MULTI-op fused kernels (the n-double window step and the
-    small-scalar ladder, ops/pallas_point.py) are dispatched.
-
-    Single-op fused kernels (add/madd/double) compile for every curve,
-    but Mosaic never returned from compiling the multi-op EDWARDS body
-    on v5e (round 4: ristretto255 pt_window_step still compiling when
-    hard-killed at ~870 s, while the same Weierstrass body compiled in
-    77 s) — so Edwards composes single-op kernels via XLA instead.
-    """
-    return fused_kernels_active() and cs.kind != "edwards"
-
-
-def _ed_fused_doubles() -> int:
-    """DKG_TPU_ED_FUSED_DOUBLES: Edwards SPLIT-fused window mode.
-
-    K > 0 composes the window step from fused pt_double launches of at
-    most K doublings each plus one fused pt_add — 2-3 kernel launches
-    instead of the one multi-op body Mosaic hangs on, but still VMEM-
-    resident per launch (vs ~9 HBM-roundtripping XLA ops).  0 (default)
-    keeps the plain XLA composition until scripts/ed_bisect.py proves
-    which fused body sizes actually compile on chip.
-    """
+def msm_mode() -> str:
+    """The MSM kernel :func:`msm` dispatches: ``DKG_TPU_MSM`` where set
+    (validated), else Straus with the multi-op kernels and the bucket
+    method without them."""
     from ..utils import envknobs
 
-    v = envknobs.nonneg_int(
-        "DKG_TPU_ED_FUSED_DOUBLES",
-        "0 disables the split-fused Edwards window",
+    mode = envknobs.choice(
+        "DKG_TPU_MSM",
+        ("straus", "pippenger"),
+        "MSM kernel: bucket method vs shared-doubling reference",
     )
-    return 0 if v is None else v
+    return mode or ("straus" if fused_kernels_active() else "pippenger")
 
 
-def fused_ladder_active(cs: "CurveSpec") -> bool:
-    """Whether the fused small-scalar ladder kernel is dispatched.
-
-    Follows :func:`fused_multi_active`, plus an Edwards-only opt-in
-    (DKG_TPU_ED_FUSED_LADDER=1): the ladder's fori_loop body is ~one
-    window step of code regardless of nbits, so it may well compile
-    where the unrolled 4-double window body hangs Mosaic —
-    scripts/ed_bisect.py measures exactly that.
-    """
-    from ..utils import envknobs
-
-    if fused_multi_active(cs):
-        return True
-    env = envknobs.choice(
-        "DKG_TPU_ED_FUSED_LADDER",
-        ("0", "1"),
-        "a typo would silently run the wrong kernel path",
-    )
-    return env == "1" and cs.kind == "edwards" and fused_kernels_active()
+def point_kernel_tier() -> dict[str, str]:
+    """What this process's selectors choose now, as labels:
+    ``tier`` (``fused``: the window step and the Horner ladder are one
+    multi-op kernel launch each, on every curve since PR 42, PERF.md
+    section 6; ``composed``: XLA operations) and the ``msm`` kernel.
+    ``service.engine.WarmRuntime.commitment`` books it as the gauge
+    ``point_kernel_tier{curve,tier,msm}``: the trace-time counters are
+    zero in a process that loads its programs."""
+    return {"tier": "fused" if fused_kernels_active() else "composed", "msm": msm_mode()}
 
 
 def _jit_static0(fn):
@@ -538,7 +510,7 @@ def _scalar_mul_core(cs: CurveSpec, k: jax.Array, p: jax.Array) -> jax.Array:
     table = _build_table(cs, p)
     digits = scalar_windows(cs, k)  # (..., NW)
     digits_rev = jnp.moveaxis(digits, -1, 0)[::-1]  # MSB first
-    fused = fused_multi_active(cs)
+    fused = fused_kernels_active()
 
     def step(acc, dig):
         entry = _gather_table(table, dig)
@@ -821,7 +793,7 @@ def scalar_mul_small(cs: CurveSpec, k: jax.Array, p: jax.Array, nbits: int) -> j
     party indices (<= n, so ~14 bits), not full field elements.  With
     the fused kernels active the whole ladder is ONE Pallas launch.
     """
-    if fused_ladder_active(cs):
+    if fused_kernels_active():
         from ..ops import pallas_point
 
         batch = jnp.broadcast_shapes(jnp.shape(k), p.shape[:-2])
@@ -858,7 +830,7 @@ def eval_point_poly(
     """
     cs_rev = jnp.moveaxis(coeffs, -3, 0)[::-1]  # (T, ..., C, L) high first
     batch = jnp.broadcast_shapes(coeffs.shape[:-3], x.shape)
-    if fused_ladder_active(cs):
+    if fused_kernels_active():
         from ..ops import pallas_point
 
         def step_fused(acc, c_l):
@@ -1151,37 +1123,20 @@ def encode_batch(cs: CurveSpec, pts) -> np.ndarray:
     return out.reshape(batch + (32,))
 
 
-def window_step(
-    cs: CurveSpec, acc: jax.Array, entry: jax.Array, window: int, fused: bool,
-    *, tiles: bool = False,
-) -> jax.Array:
+def window_step(cs: CurveSpec, acc: jax.Array, entry: jax.Array, window: int, fused: bool) -> jax.Array:
     """One Straus window step: ``window`` doublings then add ``entry``.
 
     THE single definition of the fused-vs-XLA dispatch shared by
     :func:`msm`, :func:`_scalar_mul_core` and the ceremony point-RLC —
     with the fused kernels active the whole step is one Pallas launch
-    (intermediates never touch HBM); otherwise plain XLA ops.
-
-    ``tiles``: ``acc``, ``entry`` and the result are the kernels' lane
-    blocks (``pallas_point.to_tiles``), for a caller that runs with the
-    fused kernels active and converts once; where the multi-op kernel
-    is not dispatched (Edwards) the step is then single-op launches,
-    one doubling each unless DKG_TPU_ED_FUSED_DOUBLES says more.
+    (intermediates never touch HBM); otherwise plain XLA ops.  A caller
+    that holds the kernels' lane blocks calls
+    ``pallas_point.window_step_tiles`` itself.
     """
-    k = _ed_fused_doubles() if cs.kind == "edwards" and fused_kernels_active() else 0
-    if fused or k or tiles:
+    if fused:
         from ..ops import pallas_point as pp
 
-        if fused:
-            step = pp.window_step_tiles if tiles else pp.pt_window_step
-            return step(cs, acc, entry, window)
-        dbl, add_ = (pp.double_tiles, pp.add_tiles) if tiles else (pp.pt_double, pp.pt_add)
-        d = window
-        while d > 0:
-            c = min(k or 1, d)
-            acc = dbl(cs, acc, c)
-            d -= c
-        return add_(cs, acc, entry)
+        return pp.pt_window_step(cs, acc, entry, window)
     for _ in range(window):
         acc = _double_xla(cs, acc)
     return _add_xla(cs, acc, entry)
@@ -1257,16 +1212,7 @@ def msm(cs: CurveSpec, scalars: jax.Array, points: jax.Array) -> jax.Array:
     This is the share-verification workhorse (reference seam:
     traits.rs:234-237; hot call committee.rs:292-296).
     """
-    from ..utils import envknobs
-
-    mode = envknobs.choice(
-        "DKG_TPU_MSM",
-        ("straus", "pippenger"),
-        "MSM kernel: bucket method vs shared-doubling reference",
-    )
-    if mode is None:
-        mode = "straus" if fused_multi_active(cs) else "pippenger"
-    if mode == "pippenger":
+    if msm_mode() == "pippenger":
         return msm_pippenger(cs, scalars, points)
     return msm_straus(cs, scalars, points)
 
@@ -1281,7 +1227,7 @@ def msm_straus(cs: CurveSpec, scalars: jax.Array, points: jax.Array) -> jax.Arra
     tables = _build_table(cs, points)  # (..., m, 16, C, L)
     digits = scalar_windows(cs, scalars)  # (..., m, NW)
     digits_rev = jnp.moveaxis(digits, -1, 0)[::-1]  # (NW, ..., m)
-    fused = fused_multi_active(cs)
+    fused = fused_kernels_active()
 
     def step(acc, dig):
         contribs = _gather_table(tables, dig)  # (..., m, C, L)
@@ -1411,7 +1357,7 @@ def _msm_pippenger_core(
     (_, win_sums), _ = lax.scan(close, (ident_w, ident_w), nonzero)
 
     ws_rev = jnp.moveaxis(win_sums, -3, 0)[::-1]  # (nw, ..., C, L) MSB first
-    fused = fused_multi_active(cs)
+    fused = fused_kernels_active()
 
     def combine(acc, w_sum):
         return window_step(cs, acc, w_sum, window, fused), None

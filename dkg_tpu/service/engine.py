@@ -63,6 +63,7 @@ from ..groups import device as gd
 from ..groups import host as gh
 from ..groups import precompute as gp
 from ..utils import tracing
+from ..utils.metrics import REGISTRY
 from . import aot, buckets
 from .errors import PoisonedRequest
 
@@ -185,6 +186,9 @@ class WarmRuntime:
         entry = (ck, g_table, h_table)
         with self._lock:
             self._ck.setdefault(key, entry)
+        # which tier of the point kernels this process serves the curve
+        # on: the trace-time counters stay zero where programs are loaded
+        REGISTRY.set_gauge("point_kernel_tier", 1, curve=curve, **gd.point_kernel_tier())
         return entry
 
     def warmup(self, req: CeremonyRequest, widths: tuple = (1,)) -> None:

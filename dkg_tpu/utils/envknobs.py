@@ -89,8 +89,6 @@ PROGRAM_SHAPING = (
     "DKG_TPU_MUL",  # fields.device.mul_dispatch_mode, ops.pallas_field
     "DKG_TPU_MXU",  # fields.matmul.mxu_matmul_active
     "DKG_TPU_MSM",  # groups.device: MSM algorithm
-    "DKG_TPU_ED_FUSED_LADDER",  # groups.device: Edwards fused ladder
-    "DKG_TPU_ED_FUSED_DOUBLES",  # groups.device: Edwards split-fused window
     "DKG_TPU_RLC",  # dkg.ceremony._point_rlc schedule
     "DKG_TPU_RLC_CHUNK",  # dkg.ceremony._point_rlc column chunk
     "DKG_TPU_VERIFY_CHUNK",  # parallel.mesh: recipient-axis chunk

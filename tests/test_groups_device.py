@@ -230,58 +230,73 @@ def test_affine_canon_is_representation_independent(cs):
         assert g.eq(orig, canon)
 
 
-def test_ed_split_fused_window_dispatch(monkeypatch):
-    """DKG_TPU_ED_FUSED_DOUBLES=k routes the (non-multi-fused) Edwards
-    window step through fused pt_double launches of <= k doublings plus
-    one fused pt_add — the Mosaic-hang workaround staged for
-    scripts/ed_bisect.py evidence — and the result stays bit-identical
-    to the XLA composition.  The Pallas entry points are stubbed with
-    their XLA twins so the dispatch logic is tested without compiling
-    interpret-mode kernels (pathological on CPU)."""
+def test_the_point_kernel_tier_follows_the_fused_kernels(monkeypatch):
+    """The tier of the point kernels follows the fused kernels, whatever
+    the curve (PR 42 decided Edwards on the v5e: no switch of its own),
+    and ``point_kernel_tier`` says it in the labels the engine books."""
+    monkeypatch.delenv("DKG_TPU_MSM", raising=False)
+    monkeypatch.setenv("DKG_TPU_PALLAS", "1")
+    assert gd.point_kernel_tier() == {"tier": "fused", "msm": "straus"}
+    monkeypatch.setenv("DKG_TPU_MSM", "pippenger")
+    assert gd.point_kernel_tier()["msm"] == "pippenger"
+    monkeypatch.delenv("DKG_TPU_MSM")
+    monkeypatch.setenv("DKG_TPU_PALLAS", "0")
+    assert gd.point_kernel_tier() == {"tier": "composed", "msm": "pippenger"}
+    monkeypatch.setenv("DKG_TPU_MSM", "fast")
+    with pytest.raises(ValueError, match="DKG_TPU_MSM"):
+        gd.point_kernel_tier()
+
+
+@pytest.mark.parametrize("cs", [gd.RISTRETTO255, gd.SECP256K1], ids=["ristretto255", "secp256k1"])
+def test_the_fused_tier_is_the_composition_and_the_host_group(cs, monkeypatch):
+    """With the kernels on, the window step is ONE ``pt_window_step`` and
+    every Horner step ONE ``pt_ladder_mul_add``, on Edwards as on
+    Weierstrass, and both equal the XLA composition limb for limb and
+    ``groups/host.py`` as group elements.  The two Pallas entry points
+    are answered by their XLA twins (an interpreted multi-op body is
+    pathological on the CPU); the bodies themselves are held by
+    ``test_pallas_point.py`` and, on the chip,
+    ``test_kernel_window_and_ladder_tpu``."""
     from dkg_tpu.ops import pallas_point as pp
 
-    cs = gd.RISTRETTO255
-    g = gh.ALL_GROUPS[cs.name]
-    pts = gd.from_host(
-        cs, [g.scalar_mul(g.random_scalar(RNG), g.generator()) for _ in range(4)]
-    )
-    ent = gd.from_host(
-        cs, [g.scalar_mul(g.random_scalar(RNG), g.generator()) for _ in range(4)]
-    )
+    g = hostg(cs)
     calls = []
 
-    def fake_double(c, p, n_doubles=1, **kw):
-        calls.append(("dbl", n_doubles))
+    def window(c, acc, entry, n_doubles=4, **kw):
+        calls.append(("window", n_doubles))
         for _ in range(n_doubles):
-            p = gd._double_xla(c, p)
-        return p
+            acc = gd._double_xla(c, acc)
+        return gd._add_xla(c, acc, entry)
 
-    def fake_add(c, p, q, **kw):
-        calls.append(("add",))
-        return gd._add_xla(c, p, q)
+    def ladder(c, p, addend, x, nbits, **kw):
+        calls.append(("ladder", nbits))
+        acc = gd.identity(c, p.shape[:-2])
+        for i in range(nbits - 1, -1, -1):
+            acc = gd._double_xla(c, acc)
+            acc = gd.select((x >> i) & 1 != 0, gd._add_xla(c, acc, p), acc)
+        return gd._add_xla(c, acc, addend)
 
-    monkeypatch.setattr(pp, "pt_double", fake_double)
-    monkeypatch.setattr(pp, "pt_add", fake_add)
+    monkeypatch.setattr(pp, "pt_window_step", window)
+    monkeypatch.setattr(pp, "pt_ladder_mul_add", ladder)
     monkeypatch.setenv("DKG_TPU_PALLAS", "1")
-    monkeypatch.setenv("DKG_TPU_ED_FUSED_DOUBLES", "3")
-    got = gd.window_step(cs, pts, ent, 4, False)
-    assert calls == [("dbl", 3), ("dbl", 1), ("add",)]
-    want = pts
-    for _ in range(4):
-        want = gd._double_xla(cs, want)
-    want = gd._add_xla(cs, want, ent)
-    assert (np.asarray(got) == np.asarray(want)).all()
+    ks = [g.random_scalar(RNG) for _ in range(3)]
+    es = [g.random_scalar(RNG) for _ in range(3)]
+    pts = gd.from_host(cs, [g.scalar_mul(k, g.generator()) for k in ks])
+    ent = gd.from_host(cs, [g.scalar_mul(e, g.generator()) for e in es])
+    q = g.scalar_field.modulus
 
-    # knob validation: garbage must raise, never silently dispatch
-    monkeypatch.setenv("DKG_TPU_ED_FUSED_DOUBLES", "fast")
-    with pytest.raises(ValueError, match="DKG_TPU_ED_FUSED_DOUBLES"):
-        gd.window_step(cs, pts, ent, 4, False)
+    got = gd.window_step(cs, pts, ent, 4, gd.fused_kernels_active())
+    assert calls == [("window", 4)]
+    composed = gd.window_step(cs, pts, ent, 4, False)
+    assert (np.asarray(got) == np.asarray(composed)).all()
+    for k, e, pt in zip(ks, es, gd.to_host(cs, np.asarray(got))):
+        assert g.eq(pt, g.scalar_mul((16 * k + e) % q, g.generator()))
 
-    # the Edwards ladder opt-in flips fused_ladder_active without
-    # touching the (still-gated) multi-op window
-    monkeypatch.setenv("DKG_TPU_ED_FUSED_LADDER", "1")
-    assert gd.fused_ladder_active(cs)
-    assert not gd.fused_multi_active(cs)
-    monkeypatch.setenv("DKG_TPU_ED_FUSED_LADDER", "maybe")
-    with pytest.raises(ValueError, match="DKG_TPU_ED_FUSED_LADDER"):
-        gd.fused_ladder_active(cs)
+    # Horner over 3 point coefficients at x = 1, 2, 5 (3 bits): a ladder a coefficient
+    calls.clear()
+    xs = jnp.asarray([1, 2, 5], jnp.uint32)
+    coeffs = jnp.stack([pts, ent, pts], axis=-3)  # (3 lanes, T=3, C, L), low order first
+    got = gd.eval_point_poly.__wrapped__(cs, coeffs, xs, 3)
+    assert calls == [("ladder", 3)]  # the scan's body, traced once
+    for x, k, e, pt in zip((1, 2, 5), ks, es, gd.to_host(cs, np.asarray(got))):
+        assert g.eq(pt, g.scalar_mul((k + e * x + k * x * x) % q, g.generator()))

@@ -159,13 +159,12 @@ def test_kernel_add_matches_xla_tpu(curve):
 
 
 @needs_tpu
-@pytest.mark.parametrize("curve", ["secp256k1"])
+@pytest.mark.parametrize("curve", ["secp256k1", "ristretto255"])
 def test_kernel_window_and_ladder_tpu(curve):
-    # Edwards is deliberately absent: Mosaic never returned from
-    # compiling the multi-op Edwards kernel body on v5e (round 4,
-    # >870 s before the hard kill), so production gates Edwards off the
-    # multi-op fused path (groups.device.fused_multi_active) and running
-    # it here would hang the suite the same way.
+    # ristretto255 since PR 42: the multi-op Edwards bodies compile on the
+    # v5e in seconds in their block form (the round-4 hang was of a body
+    # traced again for every batch size) and every curve runs them
+    # (groups.device.point_kernel_tier).
     cs = gd.ALL_CURVES[curve]
     host_group = gh.ALL_GROUPS[curve]
     pts = gd.from_host(

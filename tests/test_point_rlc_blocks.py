@@ -115,7 +115,7 @@ def _weights(cs, w):
         ("secp256k1", 8, 32, None),  # m a power of two, two blocks: whole-block halves, then lane slices
         ("secp256k1", 7, 21, None),  # m odd, 147 lanes: over one block with a ragged tail
         ("secp256k1", 4, 7, 3),  # DKG_TPU_RLC_CHUNK: two chunks through the map and a ragged last one
-        ("ristretto255", 5, 3, None),  # C = 4, the pt_double + pt_add window step
+        ("ristretto255", 5, 3, None),  # C = 4: extended coordinates, 64-row blocks
         ("ristretto255", 8, 20, None),  # 160 lanes
         ("bls12_381_g1", 3, 5, None),  # 24 limbs
         ("bls12_381_g1", 4, 40, None),  # 160 lanes
@@ -200,7 +200,7 @@ def _bumped(cs, shares, index):
     [("secp256k1", k, m, cols) for k in (1, 2, 8) for m, cols in ((16, 6), (32, 9), (6, 5))]
     + [
         ("secp256k1", 3, 6, 5),  # was test_block_form_under_vmap_with_a_rho_per_row: the convoy as a map
-        ("ristretto255", 1, 6, 5),  # C = 4, the pt_double + pt_add window step
+        ("ristretto255", 1, 6, 5),  # C = 4: extended coordinates, 64-row blocks
         ("ristretto255", 2, 16, 6),
         ("ristretto255", 8, 6, 5),
         ("ristretto255", 2, 32, 9),  # 576 lanes: every level of the tree a ragged slice

@@ -96,7 +96,11 @@ def _compiles_to_a_tpu_kernel(one_chip, curve: str, name: str) -> None:
         ("secp256k1", "pt_madd"),
         ("secp256k1", "pt_double"),
         ("ristretto255", "pt_add"),
-        ("ristretto255", "pt_double"),  # the Edwards window step of the point-RLC's block form
+        ("ristretto255", "pt_double"),
+        # the multi-op Edwards bodies (64-row blocks): the tier every curve runs since PR 42,
+        # and the two Mosaic was once seen not to return from (17 s and 11 s here)
+        ("ristretto255", "pt_window_step"),
+        ("ristretto255", "pt_ladder_mul_add"),
         ("secp256k1", "mod_pow_const"),
         ("ristretto255", "mod_pow_const"),
         # the 24-limb base field (72-row point blocks, 576 partial products a
@@ -207,9 +211,8 @@ def test_point_rlc_block_form_keeps_its_layout_for_v5e(one_chip, curve, monkeypa
     and 16 such copies and transposes, 13 of them inside the window
     loop (the table's concatenate, the ``take_along_axis``, and two
     conversions a tree level), and that without the ``copy_bitcast``
-    fusions this count does not see.  ristretto255 takes the Edwards
-    step (``pt_double`` + ``pt_add`` on blocks), secp256k1 and
-    bls12_381_g1 the fused window kernel (72-row blocks at 24 limbs)."""
+    fusions this count does not see.  Every curve takes the fused
+    window kernel (64-row blocks on ristretto255, 72-row at 24 limbs)."""
     from dkg_tpu.dkg import ceremony as ce
 
     monkeypatch.setenv("DKG_TPU_ASSUME_BACKEND", "tpu")
