@@ -352,6 +352,13 @@ def projective_batch(cs, shape: tuple, rng) -> "np.ndarray":
     return out.reshape(tuple(shape) + out.shape[1:])
 
 
+def _round1_host_bytes() -> int:
+    """``round1_host_bytes_total``: what the digest's host leg fetched so far."""
+    from dkg_tpu.utils.metrics import REGISTRY
+
+    return REGISTRY.snapshot()["counters"].get("round1_host_bytes_total", 0)
+
+
 def _canon_counts() -> dict:
     from dkg_tpu.utils.metrics import REGISTRY
 
@@ -364,6 +371,7 @@ def phase_digest(args, dev) -> None:
     import jax.numpy as jnp
     import numpy as np
 
+    from dkg_tpu.crypto import device_hash as dh
     from dkg_tpu.dkg import ceremony as ce
     from dkg_tpu.groups import device as gd
     from dkg_tpu.groups import host as gh
@@ -451,7 +459,10 @@ def phase_digest(args, dev) -> None:
         digests = [ce._fold_digest_device(cfg, *(r[i] for r in rows)) for i in range(k)]
         rho = np.stack([ce.fiat_shamir_rho(cfg, d, reqs[0].rho_bits) for d in digests])
         legs[leg] = (digests, rho)
-    served_rho = engine.derive_rho_convoy(cfg, *tensors, reqs[0].rho_bits)
+    # the served leg: the device arrays as deal left them
+    fetched = _round1_host_bytes()
+    served_rho = engine.derive_rho_convoy(cfg, fl.a, fl.e, fl.s, fl.r, reqs[0].rho_bits)
+    fetched = _round1_host_bytes() - fetched
     same_digest = legs["device"][0] == legs["host"][0]
     same_rho = bool(
         np.array_equal(legs["device"][1], legs["host"][1])
@@ -474,11 +485,17 @@ def phase_digest(args, dev) -> None:
             "transcript_digests": [d.hex() for d in legs["device"][0]],
             "device_leg_digests_equal_host_leg": same_digest,
             "served_rho_equals_host_leg": same_rho,
+            "served_leg": dh.digest_dispatch(),
+            "round1_host_bytes_served_leg": fetched,
         }
     )
     _require(all(o.status == "done" for o in outs) and all(masters), "a convoy master is wrong")
     _require(same_digest, "transcript digest: device leg differs from host leg")
     _require(same_rho, "rho: device leg differs from host leg")
+    _require(
+        args.rehearse or (dh.digest_dispatch() == "device" and fetched == 0),
+        f"the served digest leg left the device: leg {dh.digest_dispatch()}, fetched {fetched} bytes",
+    )
 
 
 def phase_mesh(args, dev) -> None:

@@ -52,6 +52,9 @@ leg.
 
 from __future__ import annotations
 
+import functools
+import math
+
 import numpy as np
 
 import jax
@@ -205,10 +208,10 @@ def tree_digest(tensor, domain: int = 0, dispatch: str | None = None):
 
         return blake2s.tree_digest_np(np.asarray(tensor), domain)
     words = jnp.asarray(tensor, jnp.uint32).reshape(-1)
-    return _tree_from_words(words[None, :], domain)[0]
+    return _tree_from_words((words[None, :],), domain)[0]
 
 
-def row_digests(tensor, domain: int = 0, dispatch: str | None = None):
+def row_digests(tensor, domain: int = 0, dispatch: str | None = None, lead: int = 1):
     """Independent Merkle digest per row: (R, ...) -> (R, 8) uint32.
 
     Each row's digest depends only on that row (and the shared shape),
@@ -217,31 +220,50 @@ def row_digests(tensor, domain: int = 0, dispatch: str | None = None):
     transcript hashing requires.  Backend-dispatched like
     :func:`tree_digest`; the host leg returns numpy, the device leg a
     jax array (every consumer folds through ``np.asarray`` anyway).
+
+    ``tensor`` is one array or a tuple of arrays; the first ``lead``
+    axes (shared by all of them) are the row axes and flatten to
+    R = their product, the rest of each array is its share of the row's
+    words, the arrays' shares in the tuple's order.  On the device leg
+    that flattening, the ``uint32`` cast and the joining happen inside
+    the one jitted program: a call is ONE dispatch whatever it is handed,
+    device arrays are read where they are, and numpy arrays go in as the
+    program's arguments.
     """
+    parts = tuple(tensor) if isinstance(tensor, (tuple, list)) else (tensor,)
     if dispatch is None:
         dispatch = digest_dispatch()
     if dispatch == "host":
         from . import blake2s
 
-        t = np.asarray(tensor)
-        return blake2s.row_digests_np(t.reshape(t.shape[0], -1), domain)
-    t = jnp.asarray(tensor, jnp.uint32)
-    return _tree_from_words(t.reshape(t.shape[0], -1), domain)
+        rows = math.prod(np.shape(parts[0])[:lead])
+        words = [np.asarray(p).reshape(rows, -1) for p in parts]
+        return blake2s.row_digests_np(
+            words[0] if len(words) == 1 else np.concatenate(words, axis=-1), domain
+        )
+    return _tree_from_words(parts, domain, lead)
 
 
-def _tree_from_words(words: jax.Array, domain: int) -> jax.Array:
-    """Jit entry for the device tree: one compiled program per (R, W)
-    shape, shared across domains (the domain tag rides in as a traced
-    scalar, so the rows_a/rows_e calls of ``_dealer_rows_device`` — same
-    shape, different domain — reuse one executable)."""
-    return _tree_from_words_jit(
-        jnp.asarray(words, jnp.uint32), jnp.uint32(int(domain) & MASK32)
+def _tree_from_words(parts: tuple, domain: int, lead: int = 1) -> jax.Array:
+    """Jit entry for the device tree: one compiled program per tuple of
+    shapes, shared across domains (the domain tag rides in as a traced
+    scalar, handed over as a numpy scalar so that no device array is
+    made for it first: the rows_a/rows_e calls of
+    ``_dealer_rows_device`` — same shape, different domain — reuse one
+    executable).  ``parts`` is a tuple as :func:`row_digests` takes it;
+    what is not a device array yet goes in as ``uint32`` numpy."""
+    parts = tuple(
+        p if isinstance(p, jax.Array) else np.asarray(p, np.uint32) for p in parts
     )
+    return _tree_from_words_jit(parts, np.uint32(int(domain) & MASK32), lead)
 
 
-@jax.jit
-def _tree_from_words_jit(words: jax.Array, domain: jax.Array) -> jax.Array:
-    r, w = words.shape
+@functools.partial(jax.jit, static_argnums=2)
+def _tree_from_words_jit(parts: tuple, domain: jax.Array, lead: int) -> jax.Array:
+    r = math.prod(parts[0].shape[:lead])
+    flat = [p.astype(jnp.uint32).reshape(r, -1) for p in parts]
+    words = flat[0] if len(flat) == 1 else jnp.concatenate(flat, axis=-1)
+    w = words.shape[-1]
     blocks = _pad_blocks(words)  # (R, NL, 16)
     nl = blocks.shape[-2]
     t_leaf = jnp.arange(nl, dtype=jnp.uint32) * 64

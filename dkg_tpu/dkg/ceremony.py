@@ -34,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
+import math
 import time
 
 import jax
@@ -684,27 +685,41 @@ _DIGEST_LEG_SEEN: set = set()
 def _dealer_rows_device(cfg: CeremonyConfig, a_comm, e_comm, shares, hidings,
                         dispatch: str | None = None):
     """Per-dealer BLAKE2s row digests of all four round-1 tensors:
-    (k, ...) local-dealer slices -> three (k, 8) uint32 arrays.
+    (k, ...) local-dealer slices -> three (k, 8) uint32 arrays.  A
+    convoy's stacks go in as they are, (c, n, ...): every axis before a
+    dealer's own ((t+1, C, L) of a commitment, (n, L) of a share row) is
+    a dealer axis, and the rows come back flat, (c * n, 8), ceremony
+    after ceremony.
 
     Every array is row-digested along the dealer axis (never tree-hashed
     flat), so EVERY part of the transcript is shard-foldable — a mesh
     that keeps commitments dealer-sharded (no allgather) still derives
     the canonical digest by exchanging 3 x 32 bytes per dealer.
 
-    Backend-dispatched (``device_hash.digest_dispatch``): the device leg
-    canonicalises and Merkle-hashes on device (one jitted program per
-    tensor shape); the host leg moves the tensors once and runs the
-    big-int canonicalisation (``gd.affine_canon_host``) plus the batched
-    numpy tree — on CPU that replaces the XLA per-op-overhead path that
-    made fiat_shamir the slowest ceremony phase.  Both legs produce the
-    SAME three row-digest arrays bit for bit.
+    Backend-dispatched (``device_hash.digest_dispatch``).  **The device
+    leg reads the tensors where they are**: five jitted dispatches and
+    nothing else, ``gd.affine_canon`` on ``a_comm`` and on ``e_comm`` in
+    the shape they have and ``_tree_from_words_jit`` three times, the
+    rows' flattening, the casts and the joining of a dealer's share and
+    hiding rows inside that program.  Device arrays (deal's outputs,
+    finished or not) never leave the device and nothing is dispatched
+    between the programs; numpy arrays go in as the programs' arguments.
+    **The host leg fetches the four tensors itself** (``np.asarray``:
+    for device arrays the one trip to the host; their bytes are booked
+    in ``round1_host_bytes_total``, which therefore stands still while
+    the device leg serves) and runs the big-int canonicalisation
+    (``gd.affine_canon_host``) plus the batched numpy tree — on CPU that
+    replaces the XLA per-op-overhead path that made fiat_shamir the
+    slowest ceremony phase.  Both legs produce the SAME three row-digest
+    arrays bit for bit.
     """
     from ..crypto import device_hash as dh
 
     if dispatch is None:
         dispatch = dh.digest_dispatch()
-    k = shares.shape[0]
-    shape = "x".join(str(d) for d in np.shape(e_comm)[:2])
+    lead = np.ndim(shares) - 2
+    k = math.prod(np.shape(shares)[:lead])
+    shape = f"{k}x{np.shape(e_comm)[lead]}"
     first = dispatch != "host" and (cfg.curve, shape) not in _DIGEST_LEG_SEEN
     if first:
         # the device leg is jitted outside the executable store: a
@@ -718,28 +733,21 @@ def _dealer_rows_device(cfg: CeremonyConfig, a_comm, e_comm, shares, hidings,
     # must be a function of the logical transcript, not of which kernel
     # computed it (gd.affine_canon's docstring has the full argument).
     if dispatch == "host":
-        a_canon = gd.affine_canon_host(cfg.cs, np.asarray(a_comm))
-        e_canon = gd.affine_canon_host(cfg.cs, np.asarray(e_comm))
-        sr = np.concatenate(
-            [
-                np.asarray(shares).reshape(k, -1),
-                np.asarray(hidings).reshape(k, -1),
-            ],
-            axis=-1,
+        a_comm, e_comm, shares, hidings = (
+            np.asarray(x) for x in (a_comm, e_comm, shares, hidings)
         )
+        REGISTRY.inc(
+            "round1_host_bytes_total",
+            a_comm.nbytes + e_comm.nbytes + shares.nbytes + hidings.nbytes,
+        )
+        a_canon = gd.affine_canon_host(cfg.cs, a_comm)
+        e_canon = gd.affine_canon_host(cfg.cs, e_comm)
     else:
-        a_canon = gd.affine_canon(cfg.cs, jnp.asarray(a_comm))
-        e_canon = gd.affine_canon(cfg.cs, jnp.asarray(e_comm))
-        sr = jnp.concatenate(
-            [
-                jnp.asarray(shares, jnp.uint32).reshape(k, -1),
-                jnp.asarray(hidings, jnp.uint32).reshape(k, -1),
-            ],
-            axis=-1,
-        )
-    rows_a = dh.row_digests(a_canon.reshape(k, -1), domain=1, dispatch=dispatch)
-    rows_e = dh.row_digests(e_canon.reshape(k, -1), domain=2, dispatch=dispatch)
-    rows_sr = dh.row_digests(sr, domain=3, dispatch=dispatch)
+        a_canon = gd.affine_canon(cfg.cs, a_comm)
+        e_canon = gd.affine_canon(cfg.cs, e_comm)
+    rows_a = dh.row_digests(a_canon, domain=1, dispatch=dispatch, lead=lead)
+    rows_e = dh.row_digests(e_canon, domain=2, dispatch=dispatch, lead=lead)
+    rows_sr = dh.row_digests((shares, hidings), domain=3, dispatch=dispatch, lead=lead)
     if first:
         REGISTRY.observe(
             "digest_leg_first_call_seconds",

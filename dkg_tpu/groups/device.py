@@ -940,6 +940,12 @@ def _affine_canon_traced(cs: CurveSpec, path: str, pts: jax.Array) -> jax.Array:
     if path != _canon_path():
         raise RuntimeError(f"affine_canon keyed {path!r}, traces {_canon_path()!r}")
     f = cs.field
+    # a convoy's (c, n, t+1, C, L) stack is computed as the (c * n, t+1, C, L)
+    # batch it is: a bitcast under the trace, and the program the chip was
+    # measured on (PERF.md section 6, PR 43: five axes cost it a tenth)
+    lead = pts.shape[:-3]
+    if len(lead) > 1:
+        pts = pts.reshape((-1,) + pts.shape[-3:])
     z = pts[..., 2, :]
     z_is_zero = fd.is_zero(z)
     z_safe = fd.select(z_is_zero, jnp.broadcast_to(fd.ones(f), z.shape), z)
@@ -961,9 +967,10 @@ def _affine_canon_traced(cs: CurveSpec, path: str, pts: jax.Array) -> jax.Array:
     else:
         out = jnp.stack([x_a, y_a, one], axis=-2)
     ident = identity(cs)
-    return jnp.where(
+    out = jnp.where(
         z_is_zero[..., None, None], jnp.broadcast_to(ident, out.shape), out
     )
+    return out.reshape(lead + out.shape[-3:]) if len(lead) > 1 else out
 
 
 # the XLA module keeps the public name: device traces and the

@@ -39,8 +39,10 @@ the table): a host event ``dkg/convoy.<stage>`` on the profiler's clock,
 a ``dkg_phase_seconds{phase="convoy.<stage>"}`` observation and an entry
 of the convoy's :class:`~dkg_tpu.utils.tracing.CeremonyTrace`.  A device
 step is two stages, ``*_dispatch`` (host work up to the asynchronous
-dispatch's return) and ``*_wait`` (blocked in ``np.asarray``), so host
-time and waiting never share a number.
+dispatch's return) and ``*_wait`` (blocked until the step's results are
+there: ``np.asarray`` of what the host needs, and for deal, whose
+tensors stay on the device, ``block_until_ready``), so host time and
+waiting never share a number.
 """
 
 from __future__ import annotations
@@ -403,21 +405,27 @@ def derive_rho_convoy(
     other workers off the chip (the figures: PERF.md section 6, PR 37).
     This is the digest's share of the dispatch amortization that makes
     the stacked lane pay: per-ceremony digest calls were ~40% of a small
-    convoy's wall clock.  A width-1 convoy takes the same path: its
-    (1*n, ...) rows are ``derive_rho``'s own shapes, so no program is
-    added, and its stages carry the same names.
+    convoy's wall clock.
+
+    The tensors are taken as they are, device arrays or numpy, with
+    their ceremony axis.  ``digest_dispatch`` is
+    :func:`dkg_tpu.dkg.ceremony._dealer_rows_device` up to its return:
+    on the device leg five asynchronous dispatches on the arrays where
+    deal left them (nothing is fetched, no eager operation between the
+    programs), on the host leg the whole computation, the tensors' fetch
+    included (``round1_host_bytes_total``).  ``digest_wait`` fetches the
+    three (k * n, 8) row-digest arrays, 96 bytes a dealer, the only part
+    of the transcript the device leg brings to the host: the three
+    copies are started together and waited for once.  A width-1 convoy
+    takes the same path and its stages carry the same names.
     """
-    k, n = s.shape[0], s.shape[1]
+    k = s.shape[0]
     with _stage(trace, "digest_dispatch"):
-        rows = ce._dealer_rows_device(
-            cfg,
-            np.reshape(a, (k * n,) + a.shape[2:]),
-            np.reshape(e, (k * n,) + e.shape[2:]),
-            np.reshape(s, (k * n,) + s.shape[2:]),
-            np.reshape(r, (k * n,) + r.shape[2:]),
-        )
+        rows = ce._dealer_rows_device(cfg, a, e, s, r)
     with _stage(trace, "digest_wait"):
-        rows_a, rows_e, rows_sr = (np.asarray(x).reshape(k, n, -1) for x in rows)
+        rows_a, rows_e, rows_sr = (
+            np.asarray(x).reshape(k, cfg.n, -1) for x in jax.device_get(rows)
+        )
     with _stage(trace, "rho_fold"):
         return np.stack(
             [
@@ -438,7 +446,11 @@ def derive_rho_convoy(
 
 @dataclasses.dataclass
 class InFlight:
-    """A dispatched convoy: device round-1 tensors not yet consumed."""
+    """A dispatched convoy.  The four round-1 tensors are deal's outputs
+    and **live on the device from deal to finalise**: the digest leg,
+    verify and finalise read them there, and nothing on the served path
+    copies them to the host (``wire_broadcasts`` does, for the wire
+    format's bytes)."""
 
     reqs: list
     ids: list
@@ -513,9 +525,15 @@ def start_convoy(
 
 
 def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
-    """Host transcript work + stacked verify/finalise for a dispatched
-    convoy.  The first ``np.asarray`` blocks on the deal dispatched by
-    :func:`start_convoy` — everything before this call overlaps it."""
+    """Transcript digest + stacked verify/finalise for a dispatched
+    convoy.
+
+    ``convoy.deal_wait`` only waits for deal's outputs to exist
+    (``block_until_ready``: it copies nothing, and after a hold it
+    returns at once) — everything before this call overlaps deal.
+    :func:`derive_rho_convoy` then digests ``fl.a``, ``fl.e``, ``fl.s``,
+    ``fl.r`` as the device arrays they are; verify and finalise read
+    them on the device too."""
     del runtime  # tables travel on the InFlight
     cfg_pad = fl.cfg_pad
     trace = fl.trace
@@ -523,9 +541,8 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
     n_pad = cfg_pad.n
     rho_bits = fl.reqs[0].rho_bits
     with _stage(trace, "deal_wait"):
-        a_h, e_h = np.asarray(fl.a), np.asarray(fl.e)
-        s_h, r_h = np.asarray(fl.s), np.asarray(fl.r)
-    rho = derive_rho_convoy(cfg_pad, a_h, e_h, s_h, r_h, rho_bits, trace)
+        jax.block_until_ready((fl.a, fl.e, fl.s, fl.r))
+    rho = derive_rho_convoy(cfg_pad, fl.a, fl.e, fl.s, fl.r, rho_bits, trace)
     curve = fl.reqs[0].curve
     with _stage(trace, "verify_dispatch"):
         if k == 1:

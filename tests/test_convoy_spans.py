@@ -18,6 +18,7 @@ import pathlib
 import sys
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -211,6 +212,89 @@ def test_stacked_rho_is_derive_rho_on_every_lane(runtime):
     for i in range(k):
         assert np.array_equal(got[i], want[i]), i
     assert not np.array_equal(got[0], got[1])  # two transcripts, two rhos
+
+
+def _host_bytes() -> float:
+    return REGISTRY.snapshot()["counters"].get("round1_host_bytes_total", 0)
+
+
+@pytest.fixture(scope="module")
+def device_leg_run(runtime, run):
+    """Three of the run's requests again with the digest's device leg forced (the
+    chip's leg: `DKG_TPU_DIGEST`, read by `device_hash.digest_dispatch()`), through one
+    worker: a convoy of width 1 and one of width 2, on the fixture's programs."""
+    reqs = _requests()[:3]
+    mp = pytest.MonkeyPatch()
+    mp.setenv("DKG_TPU_DIGEST", "device")
+    before = _host_bytes()
+    earlier = len(tracing.TIMELINE.snapshot())  # the ids are the run's own again: tell the records by their place
+    try:
+        sch = CeremonyScheduler(concurrency=1, batch_max=2, runtime=runtime)
+        try:
+            first = sch.submit(reqs[0])
+            deadline = time.monotonic() + 60
+            while sch.poll(first) == "queued" and time.monotonic() < deadline:
+                time.sleep(0.002)
+            with sch._cond:
+                rest = [sch.submit(r) for r in reqs[1:]]
+            outs = [sch.result(cid, timeout=300) for cid in [first] + rest]
+        finally:
+            sch.close()
+    finally:
+        mp.undo()
+    return {
+        "outs": outs,
+        "fetched": _host_bytes() - before,
+        "timeline": tracing.TIMELINE.snapshot()[earlier:],
+    }
+
+
+def test_on_the_device_leg_every_stage_runs_once_a_convoy_in_the_same_order(device_leg_run):
+    """One placement for both legs: `CONVOY_STAGES`' order, `digest_dispatch` after
+    `deal_wait`, whichever leg digests."""
+    records = device_leg_run["timeline"]
+    assert sorted(r["width"] for r in records) == [1, 2]
+    for r in records:
+        assert [p for p, _, _ in r["spans"]] == [f"convoy.{s}" for s in HONEST_STAGES]
+        at = {p.removeprefix("convoy."): (a, b) for p, a, b in r["spans"]}
+        assert at["hold"][1] <= at["deal_wait"][0] <= at["deal_wait"][1] <= at["digest_dispatch"][0]
+        assert at["digest_dispatch"][1] <= at["digest_wait"][0] <= at["digest_wait"][1] <= at["rho_fold"][0]
+
+
+def test_the_device_leg_serves_the_same_keys_and_fetches_no_round1_tensor(device_leg_run, run):
+    for got, want in zip(device_leg_run["outs"], run["outs"]):
+        assert got.status == "done" and got.master == want.master
+        assert np.array_equal(got.final_shares, want.final_shares)
+    assert device_leg_run["fetched"] == 0
+
+
+def test_the_host_leg_books_the_four_tensors_bytes(runtime):
+    before = _host_bytes()
+    fl = engine.start_convoy(runtime, _requests()[:2])
+    engine.finish_convoy(runtime, fl)
+    assert _host_bytes() - before == sum(x.nbytes for x in (fl.a, fl.e, fl.s, fl.r))
+
+
+@pytest.mark.parametrize("k", [1, 2])
+def test_finish_convoy_digests_deals_own_device_arrays(runtime, monkeypatch, device_leg_run, k):
+    """The digest leg is handed `fl.a`, `fl.e`, `fl.s`, `fl.r` themselves, not copies
+    on the host, and the rho it folds is `derive_rho`'s, ceremony by ceremony."""
+    monkeypatch.setenv("DKG_TPU_DIGEST", "device")
+    handed, rhos = [], []
+    rows_fn, rho_fn = ce._dealer_rows_device, engine.derive_rho_convoy
+    monkeypatch.setattr(ce, "_dealer_rows_device", lambda cfg, *t, **kw: handed.append(t) or rows_fn(cfg, *t, **kw))
+    monkeypatch.setattr(engine, "derive_rho_convoy", lambda *a, **kw: rhos.append(rho_fn(*a, **kw)) or rhos[-1])
+    before = _host_bytes()
+    fl = engine.start_convoy(runtime, _requests()[:k])
+    outs = engine.finish_convoy(runtime, fl)
+    assert [o.status for o in outs] == ["done"] * k and _host_bytes() == before
+    ((a, e, s, r),) = handed
+    assert a is fl.a and e is fl.e and s is fl.s and r is fl.r
+    assert all(isinstance(x, jax.Array) for x in (a, e, s, r))
+    (rho,) = rhos
+    for i in range(k):
+        alone = ce.derive_rho(fl.cfg_pad, fl.a[i], fl.e[i], fl.s[i], fl.r[i], 128)
+        assert np.array_equal(rho[i], alone), (k, i)
 
 
 def test_an_engine_stand_in_without_a_trace_is_served(monkeypatch):

@@ -297,3 +297,106 @@ def test_affine_canon_path_keys_the_compiled_program(monkeypatch):
         "affine_canon_lanes_total": 3,
         'pallas_calls_total{kernel="mod_pow_const"}': 1,
     }
+
+
+# --- the device leg reads the round-1 tensors where deal left them (ISSUE 43) ---
+
+CURVES = ["secp256k1", "ristretto255", "bls12_381_g1"]
+
+
+def _round1(curve: str, k: int, n: int, t: int, seed: int):
+    """A convoy's four round-1 tensors as numpy, (k, n, ...): projective points with
+    identities among them and limbs of 16 bits; nothing is dealt, so nothing compiles
+    but the digest leg."""
+    from dkg_tpu.groups import device as gd
+
+    cs = gd.ALL_CURVES[curve]
+    rng = np.random.default_rng(seed)
+    a = projective_batch(cs, (k, n, t + 1), random.Random(seed))
+    e = projective_batch(cs, (k, n, t + 1), random.Random(seed + 1))
+    s, r = (rng.integers(0, 1 << 16, (k, n, n, cs.scalar.limbs), dtype=np.uint32) for _ in range(2))
+    return ce.CeremonyConfig(curve, n, t), (a, e, s, r)
+
+
+def _host_bytes() -> float:
+    return REGISTRY.snapshot()["counters"].get("round1_host_bytes_total", 0)
+
+
+@pytest.mark.parametrize("curve", CURVES)
+def test_convoy_rho_on_device_arrays_on_numpy_and_alone_is_one_rho(curve, monkeypatch):
+    """Width 4: `derive_rho_convoy` on device arrays, on numpy arrays, and
+    `derive_rho` ceremony by ceremony, on both legs: one rho.  `round1_host_bytes_total`
+    stands still on the device leg; the host leg books the four tensors' bytes."""
+    from dkg_tpu.service import engine
+
+    cfg, host = _round1(curve, 4, 4, 1, 0x43)
+    dev = tuple(jnp.asarray(x) for x in host)
+    tensors = sum(x.nbytes for x in host)
+    rhos = []
+    for leg in ("device", "host"):
+        monkeypatch.setenv("DKG_TPU_DIGEST", leg)
+        before = _host_bytes()
+        rhos.append(engine.derive_rho_convoy(cfg, *dev, 128))
+        assert _host_bytes() - before == (0 if leg == "device" else tensors)
+        rhos.append(engine.derive_rho_convoy(cfg, *host, 128))
+        rhos.append(np.stack([ce.derive_rho(cfg, *(x[i] for x in dev), 128) for i in range(4)]))
+    assert rhos[0].shape == (4, 4, cfg.cs.scalar.limbs)
+    for other in rhos[1:]:
+        np.testing.assert_array_equal(rhos[0], other)
+    assert len({rhos[0][i].tobytes() for i in range(4)}) == 4  # four transcripts, four rhos
+
+
+def _jit_calls(fn, *args) -> list:
+    """What `fn(*args)` dispatches, traced: the name of every jitted call at the top
+    level, or the primitive's name for an operation outside one.  The arguments are
+    tracers, so a fetch to the host (`np.asarray`) raises."""
+    return [
+        eqn.params["name"] if eqn.primitive.name == "jit" else f"eager:{eqn.primitive.name}"
+        for eqn in jax.make_jaxpr(fn)(*args).eqns
+    ]
+
+
+@pytest.mark.parametrize("k", [None, 4], ids=["derive_rho", "convoy"])
+def test_device_leg_is_five_jitted_dispatches_and_nothing_between(k):
+    """No eager reshape, cast, concatenate or copy between the programs, with or without
+    a ceremony axis: two `affine_canon` and three `_tree_from_words_jit`, whose names the
+    device trace (the benchmark's `digest_time_share`) finds them by."""
+    cfg, host = _round1("secp256k1", k or 1, 4, 1, 0x44)
+    if k is None:
+        host = tuple(x[0] for x in host)
+    dev = tuple(jnp.asarray(x) for x in host)
+    leg = lambda *t: ce._dealer_rows_device(cfg, *t, dispatch="device")
+    assert _jit_calls(leg, *dev) == ["affine_canon"] * 2 + ["_tree_from_words_jit"] * 3
+    with pytest.raises(jax.errors.TracerArrayConversionError):
+        _jit_calls(lambda *t: ce._dealer_rows_device(cfg, *t, dispatch="host"), *dev)  # the host leg fetches
+
+
+def test_device_leg_hands_the_programs_deals_own_arrays(monkeypatch):
+    """The run itself: five calls, the tensors handed over as the very arrays that
+    came in, the domain as a numpy scalar (no device array is made for it)."""
+    from dkg_tpu.groups import device as gd
+
+    cfg, host = _round1("secp256k1", 4, 4, 1, 0x45)
+    a, e, s, r = dev = tuple(jnp.asarray(x) for x in host)
+    want = [np.asarray(x) for x in ce._dealer_rows_device(cfg, *host, dispatch="host")]
+    canon, tree = [], []
+    canon_jit, tree_jit = gd._affine_canon_jit, dh._tree_from_words_jit
+    monkeypatch.setattr(gd, "_affine_canon_jit", lambda cs, path, pts: canon.append(pts) or canon_jit(cs, path, pts))
+    monkeypatch.setattr(
+        dh, "_tree_from_words_jit", lambda parts, domain, lead: tree.append((parts, domain, lead)) or tree_jit(parts, domain, lead)
+    )
+    rows = ce._dealer_rows_device(cfg, *dev, dispatch="device")
+    assert len(canon) == 2 and canon[0] is a and canon[1] is e
+    assert [(len(p), type(d), int(d), lead) for p, d, lead in tree] == [
+        (1, np.uint32, 1, 2), (1, np.uint32, 2, 2), (2, np.uint32, 3, 2),
+    ]
+    assert tree[2][0][0] is s and tree[2][0][1] is r
+    assert [x.shape for x in rows] == [(16, 8)] * 3
+    for got, exp in zip(rows, want):
+        np.testing.assert_array_equal(np.asarray(got), exp)
+
+
+def test_the_tree_keeps_its_module_name_for_the_trace():
+    spec = jax.ShapeDtypeStruct((2, 4, 4, 16), jnp.uint32)
+    text = dh._tree_from_words_jit.lower((spec, spec), np.uint32(3), 2).as_text()
+    assert "module @jit__tree_from_words_jit " in text

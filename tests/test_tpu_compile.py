@@ -128,14 +128,16 @@ def test_multi_op_kernel_compiles_for_v5e(one_chip, name, curve, monkeypatch):
     _compiles_to_a_tpu_kernel(one_chip, curve, name)
 
 
-@pytest.mark.parametrize("shape", [(128, 6, 3, 16), (8, 3, 16)], ids=str)
+@pytest.mark.parametrize("shape", [(128, 6, 3, 16), (8, 3, 16), (8, 16, 6, 3, 16)], ids=str)
 def test_affine_canon_lowers_to_the_kernel_for_v5e(one_chip, shape, monkeypatch):
-    """The digest leg's canonicalisation at a width-8 (16,5) convoy's two
-    shapes (the commitment tensors, the master keys), as the chip traces
-    it: one module, the inversion one Mosaic launch (one row, so no
-    Montgomery scan, and the window chain loops inside the kernel), and
-    no XLA ``while`` but the carry ripples of the closing X/Z, Y/Z
-    multiply.  The trace-time dispatch is steered here, in the test:
+    """The digest leg's canonicalisation at a width-8 (16,5) convoy's
+    shapes (the commitment tensors flat, the master keys, and the
+    commitment tensors with their ceremony axis, as the served leg hands
+    them over since PR 43: flattened under the trace, so the first
+    shape's program), as the chip traces it: one module, the inversion
+    one Mosaic launch (one row, so no Montgomery scan, and the window
+    chain loops inside the kernel), and no XLA ``while`` but the carry
+    ripples of the closing X/Z, Y/Z multiply.  The trace-time dispatch is steered here, in the test:
     this process's backend is the CPU.  The steered path is part of the
     jitted program's key, so this trace and the eager CPU ones of the
     parity tests never answer for each other, in either order."""
@@ -155,6 +157,24 @@ def test_affine_canon_lowers_to_the_kernel_for_v5e(one_chip, shape, monkeypatch)
     zi = jax.ShapeDtypeStruct(shape[:-2] + (1, 16), jnp.uint32, sharding=one_chip)
     closing = jax.jit(lambda a, b: fd.mul(cs.field, a, b)).lower(xy, zi).compile().as_text()
     assert text.count(" while(") == closing.count(" while(") > 0
+
+
+@pytest.mark.parametrize("lead", [(8, 16), (16,)], ids=["convoy", "width1"])
+def test_the_hash_tree_takes_the_round1_tensors_as_they_are_for_v5e(one_chip, lead):
+    """The three tree programs of a (16,5) convoy's digest leg, handed
+    deal's outputs in their own shapes (a commitment tensor; the share and
+    hiding matrices as a pair): the flattening to rows, the cast and the
+    joining compile inside the one module the device trace knows."""
+    from dkg_tpu.crypto import device_hash as dh
+
+    def spec(*tail):
+        return jax.ShapeDtypeStruct(lead + tail, jnp.uint32, sharding=one_chip)
+
+    for parts in ((spec(6, 3, 16),), (spec(16, 16), spec(16, 16))):
+        compiled = dh._tree_from_words_jit.lower(parts, np.uint32(3), len(lead)).compile()
+        assert "jit__tree_from_words_jit" in compiled.as_text()
+        (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+        assert out.shape == (int(np.prod(lead)), 8)
 
 
 def _layout_changes(text: str, min_elems: int) -> list[tuple[str, str]]:
