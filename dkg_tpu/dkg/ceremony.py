@@ -160,8 +160,15 @@ def _deal_chunk_default(cfg: CeremonyConfig, m: int | None = None) -> int:
 
     Since PR 35 the fused path gathers a window's entries as C·L-word
     rows (512 B a lane) and carries lane blocks, so the 4 KiB a lane
-    this rule budgets for is no longer written there; its numbers are
-    left as they are until the chip re-derives them (ROADMAP S6/B3).
+    this rule budgets for is no longer written there.  Re-derived at
+    PR 44 for the shape a cell now runs, (4096,1365) over four devices
+    (m = 1024 dealers a shard): the rule gives 1024, so the shard deals
+    in one piece, and lowered for ``v5e:2x2`` that piece takes 1.07 GB
+    of temps beside 0.58 GB of arguments and 0.54 GB of outputs — a
+    third of what 4 KiB a lane reckons (5.7 GB), and far inside the
+    device.  The rule therefore errs on the safe side wherever it
+    bites (m > 1024 at this t); its numbers stay until a shape that
+    chunks is measured on the chip (PERF.md section 6, PR 44).
     """
     if m is None:
         m = cfg.n
@@ -181,8 +188,7 @@ def _deal_chunk_default(cfg: CeremonyConfig, m: int | None = None) -> int:
 
 def _env_chunk(name: str) -> int | None:
     """A validated chunk-size env knob: None when unset, else an int >= 0
-    (0 disables chunking).  Shared by DKG_TPU_RLC_CHUNK here and
-    DKG_TPU_VERIFY_CHUNK (parallel/mesh)."""
+    (0 disables chunking): DKG_TPU_RLC_CHUNK's reader."""
     from ..utils import envknobs
 
     return envknobs.nonneg_int(name, "0 disables chunking")
@@ -229,6 +235,11 @@ def _shares_chunk_default(cfg: CeremonyConfig, m: int) -> int:
     commitment tensors left RESIDENT by the first deal program — the
     whole point of the two-program split is that the commitment scan's
     temps are freed by then, so only real state is charged.
+
+    At (4096,1365) over four devices (m = 1024) the rule gives 1024: no
+    chunk, and lowered for ``v5e:2x2`` the program takes 3.55 GB of
+    temps for 0.54 GB of outputs — the sharded cell's fullest moment on
+    a chip (PERF.md section 6, PR 44), a quarter of the device.
     """
     cs = cfg.cs
     pt_bytes = cs.ncoords * cs.field.limbs * 4
@@ -758,6 +769,20 @@ def _dealer_rows_device(cfg: CeremonyConfig, a_comm, e_comm, shares, hidings,
     return rows_a, rows_e, rows_sr
 
 
+def dealer_rows_traced(cfg: CeremonyConfig, a_comm, e_comm, shares, hidings):
+    """The device leg of :func:`_dealer_rows_device` with nothing of the
+    host in it, for a caller that traces it into a program of its own
+    (the mesh's ``mesh_digest_rows``, a shard's dealers each): the same
+    canonicalisation and the same trees over (k, ...) dealer slices, so
+    the same three (k, 8) row-digest arrays bit for bit.  Books nothing."""
+    from ..crypto import device_hash as dh
+
+    rows_a = dh.row_digests(gd.affine_canon(cfg.cs, a_comm), domain=1, dispatch="device")
+    rows_e = dh.row_digests(gd.affine_canon(cfg.cs, e_comm), domain=2, dispatch="device")
+    rows_sr = dh.row_digests((shares, hidings), domain=3, dispatch="device")
+    return rows_a, rows_e, rows_sr
+
+
 def transcript_digest_device(
     cfg: CeremonyConfig, a_comm, e_comm, shares, hidings
 ) -> bytes:
@@ -808,15 +833,28 @@ def sharded_transcript_digest(cfg: CeremonyConfig, a, e, s, r) -> bytes:
     """transcript_digest_device over mesh-sharded round-1 output.
 
     ALL FOUR tensors are dealer-sharded (the scalable mesh layout never
-    replicates the commitments).  Each process Merkle-hashes its local
-    dealer rows ON DEVICE; only 3 x 32 bytes per dealer cross process
-    boundaries, so this works on multi-host meshes where
-    ``np.asarray(s)`` would fail (shards on non-addressable devices).
-    Bit-identical to ``transcript_digest_device`` on the unsharded
-    arrays — the sharded and single-chip engines derive the SAME rho
-    from the same transcript.  All four tensors must share ONE dealer
-    layout: either all dealer-sharded identically or all replicated
-    (mixed layouts fail the identical-sharding assertion).
+    replicates the commitments).  Bit-identical to
+    ``transcript_digest_device`` on the unsharded arrays — the sharded
+    and single-chip engines derive the SAME rho from the same
+    transcript: :func:`sharded_dealer_rows` folded as the flat digest
+    folds its rows.
+    """
+    return _fold_digest_device(cfg, *sharded_dealer_rows(cfg, a, e, s, r))
+
+
+def sharded_dealer_rows(cfg: CeremonyConfig, a, e, s, r) -> list[np.ndarray]:
+    """The three (n, 8) per-dealer row-digest arrays of dealer-sharded
+    round-1 tensors, on the host, shard by shard through
+    :func:`_dealer_rows_device` (so on whichever leg the backend takes).
+
+    Each process digests its local dealer rows; only 3 x 32 bytes per
+    dealer cross process boundaries, so this works on multi-host meshes
+    where ``np.asarray(s)`` would fail (shards on non-addressable
+    devices).  Every shard's programs are dispatched before any shard's
+    rows are fetched, so the shards' devices work at once.  All four
+    tensors must share ONE dealer layout: either all dealer-sharded
+    identically or all replicated (mixed layouts fail the
+    identical-sharding check).
     """
     rows = [np.zeros((cfg.n, 8), np.uint32) for _ in range(3)]
     per = []
@@ -826,6 +864,7 @@ def sharded_transcript_digest(cfg: CeremonyConfig, a, e, s, r) -> bytes:
         )
         per.append(shards)
     seen = set()
+    pending = []
     for sh_a, sh_e, sh_s, sh_r in zip(*per):
         sl = sh_s.index[0]
         if not (sh_r.index[0] == sl and sh_a.index[0] == sl and sh_e.index[0] == sl):
@@ -842,10 +881,11 @@ def sharded_transcript_digest(cfg: CeremonyConfig, a, e, s, r) -> bytes:
         if (sl.start, sl.stop) in seen:  # replicated shard copy
             continue
         seen.add((sl.start, sl.stop))
-        ra, re, rsr = _dealer_rows_device(
-            cfg, sh_a.data, sh_e.data, sh_s.data, sh_r.data
+        pending.append(
+            (sl, _dealer_rows_device(cfg, sh_a.data, sh_e.data, sh_s.data, sh_r.data))
         )
-        for dst, src in zip(rows, (ra, re, rsr)):
+    for sl, shard_rows in pending:
+        for dst, src in zip(rows, shard_rows):
             dst[sl] = np.asarray(src)
     if jax.process_count() > 1:  # pragma: no cover — single-process CI
         from jax.experimental import multihost_utils as mhu
@@ -853,7 +893,7 @@ def sharded_transcript_digest(cfg: CeremonyConfig, a, e, s, r) -> bytes:
         gathered = np.asarray(mhu.process_allgather(jnp.asarray(np.stack(rows))))
         # each dealer row is owned by exactly one process; others are 0
         rows = list(np.bitwise_or.reduce(gathered, axis=0))
-    return _fold_digest_device(cfg, *rows)
+    return rows
 
 
 def fiat_shamir_rho(cfg: CeremonyConfig, transcript: bytes, rho_bits: int) -> np.ndarray:

@@ -21,6 +21,7 @@ it to the caller.
 from __future__ import annotations
 
 import functools
+import logging
 import time
 
 import jax
@@ -46,6 +47,31 @@ from jax import lax
 
 PARTY_AXIS = "parties"
 
+#: The mesh programs can be served from the executable store: every
+#: phase function below takes ``run``, how its program is run, and the
+#: served route passes the store's seam (``service/engine.py``
+#: ``stored_mesh_program``), so a process that finds the programs there
+#: traces and compiles nothing and a sharded bucket can be prepared
+#: inside a serving process's set-up.  The benchmark's sharded cell asks
+#: for this attribute before it builds anything.
+SERVED_FROM_STORE = True
+
+_LOG = logging.getLogger(__name__)
+
+_SHARDED, _REPLICATED = P(PARTY_AXIS), P()
+
+#: Each mesh program's operand layout, by the name its XLA module and its
+#: store key carry: what its ``shard_map`` is given as ``in_specs``, and
+#: what a stored executable's operands have to be placed under.
+IN_SPECS = {
+    "mesh_deal_commitments": (_SHARDED, _SHARDED, _REPLICATED, _REPLICATED),
+    "mesh_deal_shares": (_SHARDED, _SHARDED),
+    "mesh_digest_rows": (_SHARDED,) * 4,
+    "mesh_verify_finalise": (_SHARDED,) * 4 + (_REPLICATED,) * 3,
+    "mesh_finalise": (_SHARDED, _SHARDED, _REPLICATED),
+    "mesh_blame": (_SHARDED,) * 3 + (_REPLICATED,) * 2,
+}
+
 # The memoized program builders below put envknobs.program_shape() —
 # the knobs read at TRACE time and baked into the compiled sharded
 # programs — into their cache key, so flipping a knob between calls
@@ -55,12 +81,6 @@ PARTY_AXIS = "parties"
 # cache the north-star warm run cost the same as the cold one
 # (NORTHSTAR r01 measured warm 135.6 s vs cold 126.0 s at (16, 5) on
 # the CPU mesh — pure retrace).
-
-
-def _verify_env_chunk() -> int | None:
-    """DKG_TPU_VERIFY_CHUNK (0 disables), validated by the shared knob
-    parser in ceremony."""
-    return ce._env_chunk("DKG_TPU_VERIFY_CHUNK")
 
 
 def _verify_chunk_default(cfg: ce.CeremonyConfig, block: int) -> int:
@@ -78,12 +98,41 @@ def _verify_chunk_default(cfg: ce.CeremonyConfig, block: int) -> int:
 
     Budget: recv buffer n * w * L * 4 B <= 128 MiB, floored to a power
     of two so full chunks share one program, clamped to [1, block].
+
+    At (4096,1365) over four devices (block 1024, the sharded cell's
+    shape) that is 512: two turns of the loop, two ``all_to_all`` of
+    134 MB each a turn.  Lowered for ``v5e:2x2`` the program takes
+    1.36 GB of temps so, 1.25 GB unchunked: at this size the rule bounds
+    nothing — it is the n=16384 shapes it was made for.  The rule is the
+    one width a program is traced at: there is no switch beside it
+    (``DKG_TPU_VERIFY_CHUNK`` went at PR 44; a test that wants another
+    width patches this function).  >= block means unchunked.
     """
     fs = cfg.cs.scalar
     per_recipient = cfg.n * fs.limbs * 4
     w = max(1, (128 << 20) // per_recipient)
     w = 1 << max(0, w.bit_length() - 1)
     return min(w, block)
+
+
+def _run_program(run, kind: str, cfg: ce.CeremonyConfig, mesh: Mesh, rho_bits: int, prog, args):
+    """One mesh program on ``args``: the jitted ``prog`` itself, or, where
+    the caller passed ``run``, through it —
+    ``run(kind, cfg, mesh, rho_bits, prog, args)``, the served route's
+    seam to the executable store (``service/engine.py``
+    ``stored_mesh_program``).  This module knows no store."""
+    return prog(*args) if run is None else run(kind, cfg, mesh, rho_bits, prog, args)
+
+
+def book_phase(op: str, seconds: float, registry=None) -> None:
+    """``mesh_collective_seconds{op}``: one sharded phase's wall clock,
+    the same series from :func:`run_sharded_ceremony` and from the served
+    route (``service/engine.py``)."""
+    if registry is None:
+        from ..utils import metrics as _metrics
+
+        registry = _metrics.REGISTRY
+    registry.observe("mesh_collective_seconds", seconds, op=op)
 
 
 def make_mesh(n_devices: int | None = None) -> Mesh:
@@ -114,6 +163,7 @@ def sharded_deal(
     coeffs_b: jax.Array,
     g_table: jax.Array,  # replicated
     h_table: jax.Array,
+    run=None,
 ):
     """Round 1 over the mesh: local dealing, EVERYTHING dealer-sharded.
 
@@ -127,8 +177,8 @@ def sharded_deal(
     exchanged as 32-byte per-dealer row digests
     (ce.sharded_transcript_digest) — both O(t + n), not O(n*t).
     """
-    a, e = sharded_deal_commitments(cfg, mesh, coeffs_a, coeffs_b, g_table, h_table)
-    s, r = sharded_deal_shares(cfg, mesh, coeffs_a, coeffs_b)
+    a, e = sharded_deal_commitments(cfg, mesh, coeffs_a, coeffs_b, g_table, h_table, run)
+    s, r = sharded_deal_shares(cfg, mesh, coeffs_a, coeffs_b, run)
     return a, e, s, r
 
 
@@ -139,6 +189,7 @@ def sharded_deal_commitments(
     coeffs_b: jax.Array,
     g_table: jax.Array,
     h_table: jax.Array,
+    run=None,
 ):
     """Round-1 commitment program: (A, E), dealer-sharded.
 
@@ -152,8 +203,11 @@ def sharded_deal_commitments(
     in one outer jit — that fuses them back into one program.
     """
     _check_mesh(cfg, mesh)
-    step = _deal_commitments_prog(cfg, mesh, envknobs.program_shape())
-    return step(coeffs_a, coeffs_b, g_table, h_table)
+    return _run_program(
+        run, "mesh_deal_commitments", cfg, mesh, 0,
+        _deal_commitments_prog(cfg, mesh, envknobs.program_shape()),
+        (coeffs_a, coeffs_b, g_table, h_table),
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -166,16 +220,16 @@ def _deal_commitments_prog(cfg: ce.CeremonyConfig, mesh: Mesh, knobs: tuple):
     @functools.partial(
         _shard_map_nocheck,
         mesh=mesh,
-        in_specs=(P(PARTY_AXIS), P(PARTY_AXIS), P(), P()),
+        in_specs=IN_SPECS["mesh_deal_commitments"],
         out_specs=(P(PARTY_AXIS), P(PARTY_AXIS)),
     )
-    def step(ca, cb, gt, ht):
+    def mesh_deal_commitments(ca, cb, gt, ht):
         # chunked in-trace (lax.map) so the fixed-base scan's padded
         # carry stays bounded per shard — the AOT TPU compile of the
         # one-shot body at BLS n=16384/8 devices was rejected at 21.3 GB
         return ce.deal_commitments_traced_chunked(cfg, ca, cb, gt, ht)
 
-    return step
+    return mesh_deal_commitments
 
 
 def sharded_deal_shares(
@@ -183,11 +237,16 @@ def sharded_deal_shares(
     mesh: Mesh,
     coeffs_a: jax.Array,
     coeffs_b: jax.Array,
+    run=None,
 ):
     """Round-1 share program: (s, r), dealer-sharded (second of the two
     sequential deal programs; see :func:`sharded_deal_commitments`)."""
     _check_mesh(cfg, mesh)
-    return _deal_shares_prog(cfg, mesh, envknobs.program_shape())(coeffs_a, coeffs_b)
+    return _run_program(
+        run, "mesh_deal_shares", cfg, mesh, 0,
+        _deal_shares_prog(cfg, mesh, envknobs.program_shape()),
+        (coeffs_a, coeffs_b),
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -198,13 +257,13 @@ def _deal_shares_prog(cfg: ce.CeremonyConfig, mesh: Mesh, knobs: tuple):
     @functools.partial(
         _shard_map_nocheck,
         mesh=mesh,
-        in_specs=(P(PARTY_AXIS), P(PARTY_AXIS)),
+        in_specs=IN_SPECS["mesh_deal_shares"],
         out_specs=(P(PARTY_AXIS), P(PARTY_AXIS)),
     )
-    def step(ca, cb):
+    def mesh_deal_shares(ca, cb):
         return ce.deal_shares_traced_chunked(cfg, ca, cb)
 
-    return step
+    return mesh_deal_shares
 
 
 def sharded_verify_finalise(
@@ -218,6 +277,7 @@ def sharded_verify_finalise(
     h_table: jax.Array,
     rho: jax.Array,  # (n, L) replicated Fiat-Shamir randomizers
     rho_bits: int,
+    run=None,
 ):
     """Round 2 + finalise over the mesh, commitments never replicated.
 
@@ -246,8 +306,11 @@ def sharded_verify_finalise(
     recipient-sharded, master replicated.
     """
     _check_mesh(cfg, mesh)
-    step = _verify_finalise_prog(cfg, mesh, rho_bits, envknobs.program_shape())
-    return step(a0, e, s, r, g_table, h_table, rho)
+    return _run_program(
+        run, "mesh_verify_finalise", cfg, mesh, rho_bits,
+        _verify_finalise_prog(cfg, mesh, rho_bits, envknobs.program_shape()),
+        (a0, e, s, r, g_table, h_table, rho),
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -262,10 +325,10 @@ def _verify_finalise_prog(
     @functools.partial(
         _shard_map_nocheck,
         mesh=mesh,
-        in_specs=(P(PARTY_AXIS), P(PARTY_AXIS), P(PARTY_AXIS), P(PARTY_AXIS), P(), P(), P()),
+        in_specs=IN_SPECS["mesh_verify_finalise"],
         out_specs=(P(PARTY_AXIS), P(PARTY_AXIS), P()),
     )
-    def step(a0_sh, e_sh, s_sh, r_sh, gt, ht, rho_all):
+    def mesh_verify_finalise(a0_sh, e_sh, s_sh, r_sh, gt, ht, rho_all):
         shard = lax.axis_index(PARTY_AXIS)
         block = cfg.n // n_dev
         first = shard * block + 1
@@ -288,7 +351,7 @@ def _verify_finalise_prog(
         master = _master_shardlocal(cfg, n_dev, a0_sh, qual, shard, block)
         return ok, finals, master
 
-    return step
+    return mesh_verify_finalise
 
 
 def _master_shardlocal(cfg, n_dev, a0_sh, qual, shard, block):
@@ -306,15 +369,6 @@ def _master_shardlocal(cfg, n_dev, a0_sh, qual, shard, block):
     m_part = gd._tree_reduce(cs, a0, block)  # (C, L)
     m_all = lax.all_gather(m_part, PARTY_AXIS)  # (ndev, C, L)
     return gd._tree_reduce(cs, m_all, n_dev)
-
-
-def _recipient_chunk(cfg, block: int) -> int:
-    """Resolved recipient-chunk width: env override else budget default;
-    0 / >= block means unchunked."""
-    chunk = _verify_env_chunk()
-    if chunk is None:
-        chunk = _verify_chunk_default(cfg, block)
-    return chunk
 
 
 def _chunked_recipient_loop(n_dev, block: int, chunk: int, run, tensors):
@@ -375,7 +429,7 @@ def _verify_aggregate_chunked(
         )
         return gd.eq(cs, lhs, rhs), ce.aggregate_shares(cfg, s_recv, qual)
 
-    chunk = _recipient_chunk(cfg, block)
+    chunk = _verify_chunk_default(cfg, block)
     return _chunked_recipient_loop(n_dev, block, chunk, run, (s_sh, r_sh))
 
 
@@ -387,7 +441,7 @@ def _aggregate_chunked(cfg, n_dev, s_sh, qual, block):
         s_recv = lax.all_to_all(sc, PARTY_AXIS, split_axis=1, concat_axis=0, tiled=True)
         return (ce.aggregate_shares(cfg, s_recv, qual),)
 
-    chunk = _recipient_chunk(cfg, block)
+    chunk = _verify_chunk_default(cfg, block)
     (finals,) = _chunked_recipient_loop(n_dev, block, chunk, run, (s_sh,))
     return finals
 
@@ -398,12 +452,17 @@ def sharded_finalise(
     a0: jax.Array,  # (n, C, L) dealer-sharded bare first columns
     s: jax.Array,  # (n, n, L) dealer-sharded
     qualified: jax.Array,  # (n,) replicated dealer mask
+    run=None,
 ):
     """Aggregation + master key only, over an adjudicated qualified set
     (the blame path re-finalise: no verification work — the pairwise
     checks already determined exactly which dealers are out)."""
     _check_mesh(cfg, mesh)
-    return _finalise_prog(cfg, mesh, envknobs.program_shape())(a0, s, qualified)
+    return _run_program(
+        run, "mesh_finalise", cfg, mesh, 0,
+        _finalise_prog(cfg, mesh, envknobs.program_shape()),
+        (a0, s, qualified),
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -415,17 +474,17 @@ def _finalise_prog(cfg: ce.CeremonyConfig, mesh: Mesh, knobs: tuple):
     @functools.partial(
         _shard_map_nocheck,
         mesh=mesh,
-        in_specs=(P(PARTY_AXIS), P(PARTY_AXIS), P()),
+        in_specs=IN_SPECS["mesh_finalise"],
         out_specs=(P(PARTY_AXIS), P()),
     )
-    def step(a0_sh, s_sh, qual):
+    def mesh_finalise(a0_sh, s_sh, qual):
         shard = lax.axis_index(PARTY_AXIS)
         block = cfg.n // n_dev
         finals = _aggregate_chunked(cfg, n_dev, s_sh, qual, block)
         master = _master_shardlocal(cfg, n_dev, a0_sh, qual, shard, block)
         return finals, master
 
-    return step
+    return mesh_finalise
 
 
 def sharded_blame(
@@ -436,6 +495,7 @@ def sharded_blame(
     r: jax.Array,
     g_table: jax.Array,
     h_table: jax.Array,
+    run=None,
 ):
     """Pairwise blame assignment on the mesh -> replicated (n, n) bools.
 
@@ -449,7 +509,11 @@ def sharded_blame(
     mults per shard.
     """
     _check_mesh(cfg, mesh)
-    return _blame_prog(cfg, mesh, envknobs.program_shape())(e, s, r, g_table, h_table)
+    return _run_program(
+        run, "mesh_blame", cfg, mesh, 0,
+        _blame_prog(cfg, mesh, envknobs.program_shape()),
+        (e, s, r, g_table, h_table),
+    )
 
 
 @functools.lru_cache(maxsize=None)
@@ -460,14 +524,143 @@ def _blame_prog(cfg: ce.CeremonyConfig, mesh: Mesh, knobs: tuple):
     @functools.partial(
         _shard_map_nocheck,
         mesh=mesh,
-        in_specs=(P(PARTY_AXIS), P(PARTY_AXIS), P(PARTY_AXIS), P(), P()),
+        in_specs=IN_SPECS["mesh_blame"],
         out_specs=P(),
     )
-    def step(e_sh, s_sh, r_sh, gt, ht):
+    def mesh_blame(e_sh, s_sh, r_sh, gt, ht):
         pw = ce.verify_pairwise(cfg, e_sh, s_sh, r_sh, gt, ht)  # (block, n)
         return lax.all_gather(pw, PARTY_AXIS, tiled=True)  # (n, n)
 
-    return step
+    return mesh_blame
+
+
+def transcript_rows(cfg: ce.CeremonyConfig, mesh: Mesh, a, e, s, r, run=None):
+    """Phase 2, dispatched: the per-dealer row digests of the four
+    dealer-sharded round-1 tensors, three (n, 8) uint32 arrays.
+
+    On the device leg (``crypto.device_hash.digest_dispatch``: a TPU, or
+    ``DKG_TPU_DIGEST=device``) ONE ``shard_map`` program,
+    ``mesh_digest_rows``, run like the others: every
+    shard canonicalises and tree-hashes its own dealers where deal left
+    them (``ce.dealer_rows_traced``, PR 43's leg a shard each) and the
+    rows come back dealer-sharded — no round-1 tensor crosses to the
+    host, ``round1_host_bytes_total`` stands still, and what
+    :func:`rho_from_rows` fetches is 96 bytes a dealer.  Returns at
+    dispatch.
+
+    Two callers need the other leg, ``ce.sharded_dealer_rows`` (numpy on
+    the host, shard by shard), and so it stays: a backend whose digest
+    leg is the host's (every CPU run: XLA:CPU's tree hash was the
+    slowest phase of a ceremony there, the reason ``digest_dispatch``
+    exists), and a mesh that spans processes, where a process can fetch
+    only its own shards' rows and the loop's ``process_allgather`` brings
+    the others'.  Either way the same rows bit for bit
+    (``tests/test_sharded_route.py`` holds the two legs to each other),
+    so the same digest and rho as the one-device engine's.
+    """
+    from ..crypto import device_hash as dh
+
+    if dh.digest_dispatch() == "host" or jax.process_count() > 1:
+        return ce.sharded_dealer_rows(cfg, a, e, s, r)
+    return _run_program(
+        run, "mesh_digest_rows", cfg, mesh, 0,
+        _digest_rows_prog(cfg, mesh, envknobs.program_shape()),
+        (a, e, s, r),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _digest_rows_prog(cfg: ce.CeremonyConfig, mesh: Mesh, knobs: tuple):
+    del knobs
+
+    @jax.jit
+    @functools.partial(
+        _shard_map_nocheck,
+        mesh=mesh,
+        in_specs=IN_SPECS["mesh_digest_rows"],
+        out_specs=(P(PARTY_AXIS),) * 3,
+    )
+    def mesh_digest_rows(a_sh, e_sh, s_sh, r_sh):
+        from ..utils.scanchunk import map_chunked
+
+        def call(off, w):
+            part = [lax.dynamic_slice_in_dim(x, off, w, 0) for x in (a_sh, e_sh, s_sh, r_sh)]
+            return ce.dealer_rows_traced(cfg, *part)
+
+        block = int(a_sh.shape[0])
+        return map_chunked(block, _digest_chunk_default(cfg, block), call)
+
+    return mesh_digest_rows
+
+
+#: Commitment lanes one pass of the digest canonicalises at once: what
+#: the one-device leg passes at (1024,341), 350,208, measured there
+#: (PERF.md section 5), rounded up to a power of two.
+_DIGEST_CHUNK_LANES = 1 << 19
+
+
+def _digest_chunk_default(cfg: ce.CeremonyConfig, block: int) -> int:
+    """Dealer-axis chunk of a shard's digest: rows are per dealer, so a
+    chunk changes no bit, and the canonicalisation's temps (a lane's
+    (C, L) words tile-padded, the Montgomery rows) go with the lanes it
+    passes at once — lowered for v5e:2x2 at (4096,1365) over four devices
+    the unchunked body took 7.2 GB of temps beside 1.1 GB of arguments
+    (PERF.md section 6, PR 44).  A power of two of dealers whose
+    (t + 1) lanes each stay within :data:`_DIGEST_CHUNK_LANES`."""
+    w = max(1, _DIGEST_CHUNK_LANES // (cfg.t + 1))
+    return min(1 << (w.bit_length() - 1), block)
+
+
+def rho_from_rows(cfg: ce.CeremonyConfig, rows, rho_bits: int) -> np.ndarray:
+    """Phase 2, collected: :func:`transcript_rows`'s arrays fetched (one
+    wait for the three), folded as ``transcript_digest_device`` folds
+    them and expanded into the (n, L) Fiat-Shamir randomizers."""
+    rows = [np.asarray(x) for x in jax.device_get(list(rows))]
+    return ce.fiat_shamir_rho(cfg, ce._fold_digest_device(cfg, *rows), rho_bits)
+
+
+def adjudicate(
+    cfg: ce.CeremonyConfig, mesh: Mesh, a0, e, s, r, g_table, h_table, real=None, run=None
+):
+    """The failed batch check's path: ``sharded_blame``, the guilty
+    dealers out, ``sharded_finalise`` over the rest (aggregation + master
+    key only — the pairwise checks already adjudicated, so no
+    verification is repeated), mirroring BatchedCeremony.run's flow.
+
+    ``real`` is the ceremony's own (n, t) where ``cfg`` is a padded
+    bucket's (the served route): guilt and the threshold are the real
+    dealers', and a phantom dealer is never qualified, as on the
+    one-device route.
+
+    Returns (pw, qualified, finals, master): the replicated (n, n)
+    pairwise verdicts and the dealer mask, both numpy.  ``finals`` and
+    ``master`` are None when more than t dealers are out
+    (committee.rs:340-347: proceeding would yield a key backed by fewer
+    than t+1 honest dealers): nothing is finalised then, and the caller
+    still has the verdicts to report (:func:`higher_threshold` is the
+    error the tuple APIs raise)."""
+    n, t = real if real is not None else (cfg.n, cfg.t)
+    # pw is replicated (out_specs P()), so plain asarray is
+    # multihost-safe: every process holds a full copy
+    pw = np.asarray(sharded_blame(cfg, mesh, e, s, r, g_table, h_table, run))
+    qualified = np.zeros((cfg.n,), bool)
+    qualified[:n] = pw[:n, :n].all(axis=1)
+    if n - int(qualified[:n].sum()) > t:
+        return pw, qualified, None, None
+    finals, master = sharded_finalise(cfg, mesh, a0, s, jnp.asarray(qualified), run)
+    return pw, qualified, finals, master
+
+
+def higher_threshold(qualified: np.ndarray):
+    """``DkgError(MISBEHAVIOUR_HIGHER_THRESHOLD)`` naming the dealers
+    :func:`adjudicate` found guilty."""
+    from ..dkg.errors import DkgError, DkgErrorKind
+
+    return DkgError(
+        DkgErrorKind.MISBEHAVIOUR_HIGHER_THRESHOLD,
+        detail="guilty dealers (1-based): "
+        + ", ".join(str(j + 1) for j in np.nonzero(~qualified)[0]),
+    )
 
 
 def sharded_ceremony(
@@ -479,6 +672,7 @@ def sharded_ceremony(
     h_table: jax.Array,
     rho_bits: int = 128,
     tamper=None,
+    run=None,
 ):
     """Full ceremony, parties sharded over the mesh — blame included.
 
@@ -487,53 +681,37 @@ def sharded_ceremony(
     (commitments + delivered shares), never from a fixed string, so the
     batch check is sound against an adaptive dealer and publicly
     recomputable.  If the batch check fails anywhere, the engine drops
-    to ``sharded_blame``, disqualifies guilty dealers, and re-finalises
-    over the qualified set with ``sharded_finalise`` (aggregation +
-    master key only — the pairwise checks already adjudicated, so no
-    verification is repeated), mirroring BatchedCeremony.run's flow.
+    to :func:`adjudicate`.
 
     Returns (ok, finals, master, qualified): ``ok`` is the
     PRE-adjudication per-recipient batch check (failures show which
     recipients received bad shares); ``qualified`` the final dealer
     mask.  Raises ``DkgError(MISBEHAVIOUR_HIGHER_THRESHOLD)`` when more
-    than t dealers are disqualified (committee.rs:340-347 — the tuple
-    API has no error slot, and proceeding would yield a key backed by
-    fewer than t+1 honest dealers).  ``tamper(a, e, s, r) -> same`` is
-    the fault-injection hook (arrays must keep their shardings);
-    jit-compiled over the mesh; the driver's ``dryrun_multichip`` runs
-    this on a virtual CPU mesh.
+    than t dealers are disqualified (the tuple API has no error slot).
+    ``tamper(a, e, s, r) -> same`` is the fault-injection hook (arrays
+    must keep their shardings); jit-compiled over the mesh; the driver's
+    ``dryrun_multichip`` runs this on a virtual CPU mesh.
     """
-    from ..dkg.errors import DkgError, DkgErrorKind
-
-    a, e, s, r = sharded_deal(cfg, mesh, coeffs_a, coeffs_b, g_table, h_table)
+    a, e, s, r = sharded_deal(cfg, mesh, coeffs_a, coeffs_b, g_table, h_table, run)
     if tamper is not None:
         a, e, s, r = tamper(a, e, s, r)
     jax.block_until_ready(e)
     # multihost-safe: only 32-byte row digests cross process boundaries
-    digest = ce.sharded_transcript_digest(cfg, a, e, s, r)
-    rho = jnp.asarray(ce.fiat_shamir_rho(cfg, digest, rho_bits))
+    rho = jnp.asarray(rho_from_rows(cfg, transcript_rows(cfg, mesh, a, e, s, r, run), rho_bits))
     # After the digest only the BARE FIRST COLUMNS are ever read (the
     # master key); dropping the full bare tensor here returns its HBM
     # (3.22 G at BLS n=16384) before the round-2 program runs.
     a0 = a[:, 0]
     del a
     ok, finals, master = sharded_verify_finalise(
-        cfg, mesh, a0, e, s, r, g_table, h_table, rho, rho_bits
+        cfg, mesh, a0, e, s, r, g_table, h_table, rho, rho_bits, run
     )
     qualified = jnp.ones((cfg.n,), bool)
     if not bool(_host_global(ok).all()):
-        # pw is replicated (out_specs P()), so plain asarray is
-        # multihost-safe: every process holds a full copy
-        pw = np.asarray(sharded_blame(cfg, mesh, e, s, r, g_table, h_table))
-        guilty = ~pw.all(axis=1)
-        if int(guilty.sum()) > cfg.t:
-            raise DkgError(
-                DkgErrorKind.MISBEHAVIOUR_HIGHER_THRESHOLD,
-                detail="guilty dealers (1-based): "
-                + ", ".join(str(j + 1) for j in np.nonzero(guilty)[0]),
-            )
-        qualified = jnp.asarray(~guilty)
-        finals, master = sharded_finalise(cfg, mesh, a0, s, qualified)
+        _, qual_h, finals, master = adjudicate(cfg, mesh, a0, e, s, r, g_table, h_table, run=run)
+        if finals is None:
+            raise higher_threshold(qual_h)
+        qualified = jnp.asarray(qual_h)
     return ok, finals, master, qualified
 
 
@@ -556,6 +734,32 @@ def place_sharded(mesh: Mesh, x, spec: P | None = None) -> jax.Array:
     )
 
 
+def place_replicated(mesh: Mesh, x) -> jax.Array:
+    """:func:`place_sharded` under ``P()``: a whole copy on every device
+    of the mesh (the fixed-base tables, rho)."""
+    return place_sharded(mesh, x, P())
+
+
+def place_coeffs(mesh: Mesh, coeffs_a, coeffs_b, registry=None):
+    """A request's two coefficient tensors from the host onto the mesh,
+    dealer-sharded, and waited for: what a sharded request moves to the
+    devices.  Books ``mesh_place_seconds`` and ``mesh_place_bytes_total``
+    (nothing moves, and nothing is booked as moved, for operands that
+    already lie so)."""
+    if registry is None:
+        from ..utils import metrics as _metrics
+
+        registry = _metrics.REGISTRY
+    moved = sum(int(x.nbytes) for x in (coeffs_a, coeffs_b) if not isinstance(x, jax.Array))
+    t0 = time.perf_counter()
+    placed = jax.block_until_ready(
+        (place_sharded(mesh, coeffs_a), place_sharded(mesh, coeffs_b))
+    )
+    registry.observe("mesh_place_seconds", time.perf_counter() - t0)
+    registry.inc("mesh_place_bytes_total", moved)
+    return placed
+
+
 def run_sharded_ceremony(
     cfg: ce.CeremonyConfig,
     mesh: Mesh,
@@ -568,20 +772,31 @@ def run_sharded_ceremony(
     seal=None,
     ceremony_id: str = "sharded",
     registry=None,
+    run=None,
 ):
     """BatchedCeremony.run's mesh twin: the full instrumented ceremony,
     inputs placed with explicit PartitionSpecs, every phase timed and
     attributed per shard.
 
     The device flow is exactly :func:`sharded_ceremony`'s (bit-identical
-    results — pinned by tests/test_parallel.py's subprocess oracle);
-    what this driver adds is the operational envelope the north-star
-    run publishes:
+    results — pinned by tests/test_parallel.py's subprocess oracle), and
+    its phases are the functions the served route drives too
+    (``service/engine.py``: :func:`place_coeffs`,
+    :func:`sharded_deal_commitments`, :func:`sharded_deal_shares`,
+    :func:`transcript_rows`, :func:`rho_from_rows`,
+    :func:`sharded_verify_finalise`, :func:`adjudicate`), each program
+    run through ``run`` where given (the served route's store seam;
+    ``chip_smoke.py --mesh`` passes it too); what this
+    driver adds is the operational envelope the north-star run
+    publishes:
 
     * input placement via :func:`place_sharded` (coefficients
       dealer-sharded, tables replicated) so phase 0 starts aligned;
     * per-phase wall clocks -> ``phases_s`` and the
       ``mesh_collective_seconds{op}`` histogram;
+    * **a line of the log as each phase is entered** (logger
+      ``dkg_tpu.parallel.mesh``, INFO), so a run that does not return
+      names the phase it is in;
     * per-shard readiness events in obslog's ``round_head`` /
       ``publish`` / ``round_tail`` schema (party = shard index), so
       ``obslog.critical_path`` decomposes a sharded barrier exactly the
@@ -607,7 +822,6 @@ def run_sharded_ceremony(
     ``DkgError(MISBEHAVIOUR_HIGHER_THRESHOLD)`` past t disqualified
     dealers, like the tuple API.
     """
-    from ..dkg.errors import DkgError, DkgErrorKind
     from ..utils import metrics as _metrics
     from ..utils import obslog
 
@@ -617,7 +831,11 @@ def run_sharded_ceremony(
     events: list[dict] = []
     phases: dict[str, float] = {}
 
-    def _head(rd: int) -> float:
+    def _head(rd: int, op: str) -> float:
+        _LOG.info(
+            "sharded ceremony %s (%s n=%d t=%d, %d devices): entering phase %d %s",
+            ceremony_id, cfg.curve, cfg.n, cfg.t, n_dev, rd, op,
+        )
         now = time.time()
         events.append(
             {"kind": "round_head", "ceremony_id": ceremony_id, "round": rd, "ts": now}
@@ -671,20 +889,20 @@ def run_sharded_ceremony(
             present=n_dev,
         )
         phases[op] = phases.get(op, 0.0) + (now - t_open)
-        reg.observe("mesh_collective_seconds", now - t_open, op=op)
+        book_phase(op, now - t_open, reg)
 
-    ca = place_sharded(mesh, coeffs_a)
-    cb = place_sharded(mesh, coeffs_b)
+    _LOG.info("sharded ceremony %s: placing inputs on %d devices", ceremony_id, n_dev)
+    ca, cb = place_coeffs(mesh, coeffs_a, coeffs_b, reg)
     gt = place_sharded(mesh, g_table, P())
     ht = place_sharded(mesh, h_table, P())
 
-    t0 = _head(0)
-    a, e = sharded_deal_commitments(cfg, mesh, ca, cb, gt, ht)
+    t0 = _head(0, "deal_commitments")
+    a, e = sharded_deal_commitments(cfg, mesh, ca, cb, gt, ht, run)
     _publish_shards(0, e)
     _tail(0, "deal_commitments", t0)
 
-    t0 = _head(1)
-    s, r = sharded_deal_shares(cfg, mesh, ca, cb)
+    t0 = _head(1, "deal_shares")
+    s, r = sharded_deal_shares(cfg, mesh, ca, cb, run)
     _publish_shards(1, s)
     _tail(1, "deal_shares", t0)
 
@@ -696,18 +914,16 @@ def run_sharded_ceremony(
         from ..dkg import hybrid_batch as hb
 
         group, pks_dev, r_enc = seal
+        _LOG.info("sharded ceremony %s: entering seal_transport", ceremony_id)
         t0 = time.time()
         broadcasts = hb.seal_shares_mesh(
             group, cfg, mesh, s, r, pks_dev, r_enc, gt
         )
         phases["seal_transport"] = time.time() - t0
-        reg.observe(
-            "mesh_collective_seconds", phases["seal_transport"], op="seal_transport"
-        )
+        book_phase("seal_transport", phases["seal_transport"], reg)
 
-    t0 = _head(2)
-    digest = ce.sharded_transcript_digest(cfg, a, e, s, r)
-    rho = jnp.asarray(ce.fiat_shamir_rho(cfg, digest, rho_bits))
+    t0 = _head(2, "transcript_digest")
+    rho = jnp.asarray(rho_from_rows(cfg, transcript_rows(cfg, mesh, a, e, s, r, run), rho_bits))
     _publish_shards(2, rho)
     _tail(2, "transcript_digest", t0)
 
@@ -717,29 +933,24 @@ def run_sharded_ceremony(
     a0 = a[:, 0]
     del a
 
-    t0 = _head(3)
+    t0 = _head(3, "verify_finalise")
     ok, finals, master = sharded_verify_finalise(
-        cfg, mesh, a0, e, s, r, g_table=gt, h_table=ht, rho=rho, rho_bits=rho_bits
+        cfg, mesh, a0, e, s, r, g_table=gt, h_table=ht, rho=rho, rho_bits=rho_bits, run=run
     )
     _publish_shards(3, finals)
     _tail(3, "verify_finalise", t0)
 
     qualified = jnp.ones((cfg.n,), bool)
     if not bool(_host_global(ok).all()):
-        t0 = _head(4)
-        pw = np.asarray(sharded_blame(cfg, mesh, e, s, r, gt, ht))
-        guilty = ~pw.all(axis=1)
-        if int(guilty.sum()) > cfg.t:
+        t0 = _head(4, "blame")
+        _, qual_h, finals, master = adjudicate(cfg, mesh, a0, e, s, r, gt, ht, run=run)
+        if finals is None:
             _tail(4, "blame", t0)
-            raise DkgError(
-                DkgErrorKind.MISBEHAVIOUR_HIGHER_THRESHOLD,
-                detail="guilty dealers (1-based): "
-                + ", ".join(str(j + 1) for j in np.nonzero(guilty)[0]),
-            )
-        qualified = jnp.asarray(~guilty)
-        finals, master = sharded_finalise(cfg, mesh, a0, s, qualified)
+            raise higher_threshold(qual_h)
+        qualified = jnp.asarray(qual_h)
         _publish_shards(4, finals)
         _tail(4, "blame", t0)
+    _LOG.info("sharded ceremony %s: done, phases_s %s", ceremony_id, phases)
 
     return {
         "ok": ok,

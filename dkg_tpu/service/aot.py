@@ -33,7 +33,11 @@ a pickle: the digest check guards *integrity*, not *trust* — point
 compilation cache.
 
 Key shape: ``(kind, curve, n, t, width, rho_bits, specsig)`` for
-ceremony programs, ``("sign_folded", curve, rung, specsig)`` for the
+ceremony programs, ``(kind, curve, n, t, rho_bits, mesh shape, device
+kind, device ids, specsig)`` for the ``shard_map`` programs of a
+sharded bucket (``service/engine.py`` ``stored_mesh_program``; an executable
+over several devices loads back onto the same ones),
+``("sign_folded", curve, rung, specsig)`` for the
 steady sign lane's folded ladder rungs — ``specsig`` pins every operand
 shape/dtype (tables included, so a fixed-base window change keys new
 artifacts).  :func:`preload` deserializes every valid artifact in the
@@ -73,6 +77,7 @@ _FORMAT_VERSION = 3
 _TRACED_SOURCES = (
     "dkg/ceremony.py",
     "service/engine.py",
+    "parallel/mesh.py",
     "utils/scanchunk.py",
     "fields",
     "groups",

@@ -58,6 +58,20 @@ WIDTHS = (8, 4, 2, 1)
 WIDTH_CAP_N = 64
 
 
+#: Sharding crossover: a bucket at or above this ``n`` is served by the
+#: ``shard_map`` programs of :mod:`dkg_tpu.parallel.mesh` over every
+#: local device, where the process has at least
+#: :data:`SHARD_MIN_DEVICES` and their count divides ``n``
+#: (:func:`shard_devices`).  Below it one chip serves a request in
+#: seconds ((1024,341): 2.1 s) and sharding it would buy little; at
+#: (4096,1365) one chip's programs reckon to over 30 s a request and the
+#: round-1 tensors to 4.3 GB (PERF.md section 4).
+SHARD_MIN_N = 2048
+
+#: Fewest local devices worth a mesh (one v5e host has four).
+SHARD_MIN_DEVICES = 4
+
+
 @dataclasses.dataclass(frozen=True)
 class Bucket:
     """One canonical padded shape.  Hashable — used as a compile/convoy
@@ -110,6 +124,24 @@ def width_cap(b: Bucket) -> int:
     loss (see :data:`WIDTH_CAP_N`).
     """
     return 1 if b.n >= WIDTH_CAP_N else WIDTHS[0]
+
+
+def _local_device_count() -> int:
+    import jax
+
+    return jax.local_device_count()
+
+
+def shard_devices(b: Bucket) -> int:
+    """How many local devices bucket ``b`` is sharded over; 0 for the
+    one-device route.  A constant and a function of the bucket and the
+    process's devices, nothing else: no switch, no scheduler argument.
+    A bucket under :data:`SHARD_MIN_N` never asks for the devices, so
+    what its process can see does not touch it."""
+    if b.n < SHARD_MIN_N:
+        return 0
+    devices = _local_device_count()
+    return devices if devices >= SHARD_MIN_DEVICES and b.n % devices == 0 else 0
 
 
 #: Message-count rungs for the sign lane (descending).  Only these

@@ -53,6 +53,7 @@ import hashlib
 import itertools
 import random
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +65,7 @@ from ..fields import host as fh
 from ..groups import device as gd
 from ..groups import host as gh
 from ..groups import precompute as gp
+from ..parallel import mesh as pm
 from ..utils import tracing
 from ..utils.metrics import REGISTRY
 from . import aot, buckets
@@ -167,6 +169,7 @@ class WarmRuntime:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._ck: dict = {}
+        self._mesh: dict = {}
 
     def commitment(self, curve: str, shared_string: bytes):
         """(CommitmentKey, g_table, h_table) for a ceremony environment,
@@ -192,6 +195,30 @@ class WarmRuntime:
         # on: the trace-time counters stay zero where programs are loaded
         REGISTRY.set_gauge("point_kernel_tier", 1, curve=curve, **gd.point_kernel_tier())
         return entry
+
+    def mesh_route(self, curve: str, shared_string: bytes, b: buckets.Bucket, n_dev: int):
+        """(mesh, g_table, h_table) of a sharded bucket's route
+        (``buckets.shard_devices``): the party mesh over the first
+        ``n_dev`` local devices and the two fixed-base tables replicated
+        on it — placed once a process, so a request moves only its own
+        coefficients to the devices.  Books the gauge
+        ``mesh_route{bucket,devices}`` the first time a bucket takes the
+        route."""
+        key = (curve, shared_string, n_dev)
+        with self._lock:
+            hit = self._mesh.get(key)
+        if hit is None:
+            _, g_table, h_table = self.commitment(curve, shared_string)
+            mesh = pm.make_mesh(n_dev)
+            hit = (
+                mesh,
+                pm.place_replicated(mesh, g_table),
+                pm.place_replicated(mesh, h_table),
+            )
+            with self._lock:
+                hit = self._mesh.setdefault(key, hit)
+        REGISTRY.set_gauge("mesh_route", 1, bucket=b.label, devices=str(n_dev))
+        return hit
 
     def warmup(self, req: CeremonyRequest, widths: tuple = (1,)) -> None:
         """Compile the request's bucket programs ahead of traffic by
@@ -265,6 +292,36 @@ def _aot_dispatch(key_prefix: tuple, args: tuple, trace, fallback):
     except Exception as exc:
         aot.note_error(exc, f"dispatch {key_prefix[0]}")
         return fallback()
+
+
+def stored_mesh_program(kind: str, cfg: ce.CeremonyConfig, mesh, rho_bits: int, prog, args):
+    """The ``run`` that the sharded route hands ``parallel/mesh.py``'s
+    phase functions: one ``shard_map`` program through the same seam as
+    the one-device programs (:func:`_aot_dispatch`: its stored executable
+    where the store is on, the jitted ``prog`` otherwise and after a
+    store failure, counted).
+
+    The key binds what such a program is compiled for beside the
+    one-device programs' fields: the mesh's shape, its devices' kind and
+    ids (an executable runs on the devices it was compiled for).
+    Operands are placed under the program's ``pm.IN_SPECS`` first (no
+    copy where they already lie so): a stored executable takes its
+    arguments only in the layout it was compiled for."""
+    if not aot.enabled():
+        return prog(*args)
+    args = tuple(pm.place_sharded(mesh, x, spec) for x, spec in zip(args, pm.IN_SPECS[kind]))
+    devs = list(mesh.devices.flat)
+    return _aot_dispatch(
+        (
+            kind, cfg.curve, cfg.n, cfg.t, rho_bits, tuple(mesh.devices.shape),
+            devs[0].device_kind, tuple(d.id for d in devs),
+        ),
+        args,
+        lambda _: prog.trace(
+            *(jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding) for x in args)
+        ),
+        lambda: prog(*args),
+    )
 
 
 def aot_sign_folded(curve: str, sigma_limbs: np.ndarray, h_dev):
@@ -467,6 +524,12 @@ class InFlight:
     trace: tracing.CeremonyTrace = dataclasses.field(
         default_factory=tracing.CeremonyTrace
     )
+    #: the sharded route only (``buckets.shard_devices``): the party mesh
+    #: the four tensors are dealer-sharded over — they then carry no
+    #: ceremony axis, the convoy is one request — and when deal was
+    #: dispatched (``time.perf_counter``)
+    mesh: object | None = None
+    dispatched_at: float = 0.0
 
 
 def start_convoy(
@@ -502,6 +565,23 @@ def start_convoy(
             ca.append(pad_coeffs(a_real, b.n, b.t))
             cb.append(pad_coeffs(b_real, b.n, b.t))
         ca_h, cb_h = (ca[0], cb[0]) if k == 1 else (np.stack(ca), np.stack(cb))
+    n_dev = buckets.shard_devices(b) if k == 1 else 0
+    if n_dev:
+        # the sharded route: the same draw and pad, then the mesh's deal
+        with _stage(trace, "deal_dispatch"):
+            mesh, g_mesh, h_mesh = runtime.mesh_route(
+                req0.curve, req0.shared_string, b, n_dev
+            )
+            ca_d, cb_d = pm.place_coeffs(mesh, ca_h, cb_h)
+            t_deal = time.perf_counter()
+            a, e, s, r = pm.sharded_deal(
+                cfg_pad, mesh, ca_d, cb_d, g_mesh, h_mesh, stored_mesh_program
+            )
+        REGISTRY.inc("mesh_requests_total", devices=str(n_dev))
+        return InFlight(
+            list(reqs), list(ids), cfg_pad, g_mesh, h_mesh, a, e, s, r, trace,
+            mesh=mesh, dispatched_at=t_deal,
+        )
     with _stage(trace, "deal_dispatch"):
         args = (jnp.asarray(ca_h), jnp.asarray(cb_h), g_table, h_table)
         if k == 1:
@@ -535,6 +615,8 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
     ``fl.r`` as the device arrays they are; verify and finalise read
     them on the device too."""
     del runtime  # tables travel on the InFlight
+    if fl.mesh is not None:
+        return _finish_sharded(fl)
     cfg_pad = fl.cfg_pad
     trace = fl.trace
     k = len(fl.reqs)
@@ -651,6 +733,95 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
                 )
             )
     return out
+
+
+def _finish_sharded(fl: InFlight) -> list[CeremonyOutcome]:
+    """:func:`finish_convoy` for a request on the sharded route: the
+    phases of ``parallel.mesh.run_sharded_ceremony`` (its own functions,
+    not copies) under the convoy's stage spans, and an outcome laid out
+    as the one-device route's, a failed batch check's included.
+
+    Stage by stage: ``deal_wait`` waits for the two deal programs
+    (commitments, then shares; ``mesh_collective_seconds{op}`` as
+    ``run_sharded_ceremony`` books it, here from deal's dispatch, so
+    after a hold it includes the hold); ``digest_dispatch`` is
+    ``mesh_digest_rows``'s dispatch (on the host leg the whole numpy
+    digest, shard by shard), ``digest_wait`` the fetch of its
+    three (n, 8) row arrays, ``rho_fold`` the fold and the n lanes of
+    rho; ``verify_dispatch`` takes the bare first columns, places rho
+    and dispatches ``mesh_verify_finalise``, which verifies AND
+    aggregates, so ``finalise_dispatch`` has nothing left to dispatch
+    and ``finalise_wait`` fetches the final shares and the master key.
+    ``blame`` is ``parallel.mesh.adjudicate`` over the request's own
+    (n, t): the complaints and the guilty dealers are reported whether
+    or not enough dealers are left to finalise."""
+    (req,) = fl.reqs
+    cfg, mesh, trace = fl.cfg_pad, fl.mesh, fl.trace
+    with _stage(trace, "deal_wait"):
+        jax.block_until_ready((fl.a, fl.e))
+        t_e = time.perf_counter()
+        jax.block_until_ready((fl.s, fl.r))
+        t_s = time.perf_counter()
+    pm.book_phase("deal_commitments", t_e - fl.dispatched_at)
+    pm.book_phase("deal_shares", t_s - t_e)
+    with _stage(trace, "digest_dispatch"):
+        rows = pm.transcript_rows(cfg, mesh, fl.a, fl.e, fl.s, fl.r, stored_mesh_program)
+    with _stage(trace, "digest_wait"):
+        rows = jax.device_get(list(rows))
+    with _stage(trace, "rho_fold"):
+        rho = pm.rho_from_rows(cfg, rows, req.rho_bits)
+    t_rho = time.perf_counter()
+    pm.book_phase("transcript_digest", t_rho - t_s)
+    with _stage(trace, "verify_dispatch"):
+        # only the bare first columns survive the digest
+        a0 = fl.a[:, 0]
+        fl.a = None
+        ok, final_shares, master = pm.sharded_verify_finalise(
+            cfg, mesh, a0, fl.e, fl.s, fl.r, fl.g_table, fl.h_table,
+            pm.place_replicated(mesh, rho), req.rho_bits, stored_mesh_program,
+        )
+    with _stage(trace, "verify_wait"):
+        ok_h = np.asarray(ok)
+    pm.book_phase("verify_finalise", time.perf_counter() - t_rho)
+    qualified = np.ones((req.n,), bool)
+    complaints: list[tuple[int, int]] = []
+    if not ok_h[: req.n].all():
+        with _stage(trace, "blame"):
+            t_blame = time.perf_counter()
+            pw, qual_h, final_shares, master = pm.adjudicate(
+                cfg, mesh, a0, fl.e, fl.s, fl.r, fl.g_table, fl.h_table,
+                real=(req.n, req.t), run=stored_mesh_program,
+            )
+            complaints = [
+                (int(rcp) + 1, int(dlr) + 1)
+                for dlr, rcp in zip(*np.nonzero(~pw[: req.n, : req.n]))
+            ]
+            qualified = qual_h[: req.n]
+            pm.book_phase("blame", time.perf_counter() - t_blame)
+    failed = final_shares is None  # more than t dealers out: nothing was finalised
+    with _stage(trace, "finalise_dispatch"):
+        pass  # the mesh's verify program (or blame's re-finalise) aggregated already
+    with _stage(trace, "finalise_wait"):
+        if not failed:
+            shares_h = np.asarray(final_shares)
+            master_h = np.asarray(master)
+    with _stage(trace, "encode"):
+        return [
+            CeremonyOutcome(
+                ceremony_id=fl.ids[0],
+                status="failed" if failed else "done",
+                curve=req.curve,
+                n=req.n,
+                t=req.t,
+                bucket_n=cfg.n,
+                bucket_t=cfg.t,
+                master=b"" if failed else gd.encode_batch(cfg.cs, master_h[None])[0].tobytes(),
+                qualified=tuple(bool(q) for q in qualified),
+                complaints=tuple(complaints),
+                error="MISBEHAVIOUR_HIGHER_THRESHOLD" if failed else "",
+                final_shares=None if failed else shares_h[: req.n],
+            )
+        ]
 
 
 def run_convoy(runtime: WarmRuntime, reqs: list) -> list[CeremonyOutcome]:

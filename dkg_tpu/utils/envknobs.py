@@ -91,7 +91,6 @@ PROGRAM_SHAPING = (
     "DKG_TPU_MSM",  # groups.device: MSM algorithm
     "DKG_TPU_RLC",  # dkg.ceremony._point_rlc schedule
     "DKG_TPU_RLC_CHUNK",  # dkg.ceremony._point_rlc column chunk
-    "DKG_TPU_VERIFY_CHUNK",  # parallel.mesh: recipient-axis chunk
     "DKG_TPU_DIGEST",  # crypto.device_hash.digest_dispatch
 )
 

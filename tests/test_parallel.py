@@ -88,7 +88,7 @@ def test_sharded_deal_matches_single_device_transcript():
 
 @pytest.mark.slow
 def test_sharded_verify_finalise_chunked_matches_oneshot(monkeypatch):
-    """The recipient-chunked round-2 body (DKG_TPU_VERIFY_CHUNK, the
+    """The recipient-chunked round-2 body (``pm._verify_chunk_default``, the
     n=16384 HBM fix: per-chunk all_to_all + verify + aggregate through
     lax.map with a ragged tail) is bit-identical to the one-shot body.
 
@@ -108,31 +108,34 @@ def test_sharded_verify_finalise_chunked_matches_oneshot(monkeypatch):
     digest = ce.sharded_transcript_digest(c.cfg, a, e, s, r)
     rho = jnp.asarray(ce.fiat_shamir_rho(c.cfg, digest, rho_bits))
 
+    def chunk(width):
+        # the rule is the one width a program is traced at: patch it, and
+        # drop the memoized programs traced at the other
+        monkeypatch.setattr(pm, "_verify_chunk_default", lambda cfg, block: min(width, block))
+        pm._verify_finalise_prog.cache_clear()
+        pm._finalise_prog.cache_clear()
+
     def run_once():
         ok, finals, master = pm.sharded_verify_finalise(
             c.cfg, mesh, a[:, 0], e, s, r, c.g_table, c.h_table, rho, rho_bits
         )
         return np.asarray(ok), np.asarray(finals), np.asarray(master)
 
-    monkeypatch.setenv("DKG_TPU_VERIFY_CHUNK", "0")
+    chunk(n)  # >= block: unchunked
     ok_ref, fin_ref, m_ref = run_once()
-    monkeypatch.setenv("DKG_TPU_VERIFY_CHUNK", "2")
+    chunk(2)
     ok_ch, fin_ch, m_ch = run_once()
     assert ok_ref.all() and ok_ch.all()
     np.testing.assert_array_equal(fin_ch, fin_ref)
     np.testing.assert_array_equal(m_ch, m_ref)
 
     qual = jnp.asarray([i % 5 != 0 for i in range(n)])
-    monkeypatch.setenv("DKG_TPU_VERIFY_CHUNK", "0")
+    chunk(n)
     fin2_ref, m2_ref = map(np.asarray, pm.sharded_finalise(c.cfg, mesh, a[:, 0], s, qual))
-    monkeypatch.setenv("DKG_TPU_VERIFY_CHUNK", "2")
+    chunk(2)
     fin2_ch, m2_ch = map(np.asarray, pm.sharded_finalise(c.cfg, mesh, a[:, 0], s, qual))
     np.testing.assert_array_equal(fin2_ch, fin2_ref)
     np.testing.assert_array_equal(m2_ch, m2_ref)
-
-    monkeypatch.setenv("DKG_TPU_VERIFY_CHUNK", "banana")
-    with pytest.raises(ValueError, match="DKG_TPU_VERIFY_CHUNK"):
-        run_once()
 
 
 def test_mesh_shapes():
