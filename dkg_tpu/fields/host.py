@@ -13,6 +13,7 @@ Python ints; batching helpers convert between ints and limb arrays.
 from __future__ import annotations
 
 import math
+import os
 import random
 
 import numpy as np
@@ -92,58 +93,142 @@ def encode(fs: FieldSpec, values) -> np.ndarray:
     return out
 
 
-#: attempts one bulk read asks its generator for at most (32 MB of
-#: stream at 256 bits): bounds the big int and keeps ``getrandbits``'s
-#: argument inside a C int at any committee size
-_DRAW_ATTEMPTS_PER_READ = 1 << 20
+#: scalars in one draw from which the block path takes it.  Under it the
+#: generator's state costs more to carry into numpy and back (624 words
+#: each way, 0.6 ms a call on the v5e's host) than the draw saves: there
+#: the two paths cross between 16,384 and 22,016 scalars (the sweep:
+#: PERF.md section 6, PR 45)
+BLOCK_MIN_SCALARS = 1 << 14
+
+#: attempts one chunk of the block path reads: 2 MB of words at 256
+#: bits, so a chunk's words, its comparison and its halves stay in cache
+_BLOCK_ROWS = 1 << 16
 
 
-def draw_limbs(fs: FieldSpec, rng, shape) -> np.ndarray:
+def _block_reader(rng, words: int):
+    """``(read, done)`` of the block path: ``read(m)`` gives the words of
+    the generator's next ``m`` attempts as an ``(m, words)`` uint32
+    array, without a Python int in between, and ``done()``, where there
+    is a stream, leaves ``rng`` where those reads left it.
+
+    ``random.Random``: its Mersenne state (624 words and a position)
+    carried into a ``numpy.random.MT19937``, whose ``integers(0, 2**32,
+    dtype=uint32)`` are exactly the successive 32-bit outputs that
+    ``getrandbits`` concatenates (``tests/test_fields.py`` pins numpy to
+    that); ``done`` carries the state back.  ``random.SystemRandom``:
+    ``os.urandom``, no state and no ``done``."""
+    if type(rng) is random.SystemRandom:
+        return lambda m: np.frombuffer(os.urandom(4 * words * m), "<u4").reshape(m, words), None
+    version, internal, gauss_next = rng.getstate()
+    bit_gen = np.random.MT19937()
+    bit_gen.state = {
+        "bit_generator": "MT19937",
+        "state": {"key": np.array(internal[:-1], np.uint32), "pos": internal[-1]},
+    }
+    gen = np.random.Generator(bit_gen)
+
+    def done():
+        state = bit_gen.state["state"]
+        rng.setstate((version, (*state["key"].tolist(), int(state["pos"])), gauss_next))
+
+    return lambda m: gen.integers(0, 1 << 32, size=(m, words), dtype=np.uint32), done
+
+
+def _store_rows(out: np.ndarray, at: int, halves: np.ndarray) -> None:
+    """``halves`` (k, L) to the positions ``at .. at + k`` of ``out``'s
+    leading two axes in row-major order, for an ``out`` (R, C, L) that
+    may be a strided view (a padded tensor's real lanes): the rest of a
+    row begun, whole rows, the head of the next."""
+    cols, limbs = out.shape[1:]
+    k = halves.shape[0]
+    r, c = divmod(at, cols)
+    i = 0
+    if c:
+        i = min(cols - c, k)
+        out[r, c : c + i] = halves[:i]
+        r += 1
+    whole = (k - i) // cols
+    if whole:
+        out[r : r + whole] = halves[i : i + whole * cols].reshape(whole, cols, limbs)
+        i += whole * cols
+        r += whole
+    if i < k:
+        out[r, : k - i] = halves[i:]
+
+
+def draw_limbs(fs: FieldSpec, rng, shape, out: np.ndarray | None = None) -> np.ndarray:
     """``prod(shape)`` uniform field elements as a uint32 limb array
     ``(*shape, L)`` — THE definition of a coefficient draw: bit for bit
     ``encode(fs, [fs.rand_int(rng) for _ in range(prod(shape))])
     .reshape(*shape, L)``, with ``rng`` left in the state that loop
-    leaves it in.
+    leaves it in.  Written into ``out`` where given (uint32, ``(*shape,
+    L)``, C-contiguous or, for a two-axis ``shape``, any strided view:
+    the real lanes of a padded tensor), else into a fresh array.
 
     ``random.Random.getrandbits(k)`` for ``k > 32`` concatenates
     successive 32-bit Mersenne outputs, low word first, and shifts only
-    the LAST word right by ``32 * words - k``.  So one read of
-    ``32 * words * m`` bits is the words of ``m`` successive
+    the LAST word right by ``32 * words - k``.  So ``32 * words * m``
+    bits of the stream are the words of ``m`` successive
     ``getrandbits(fs.bits)`` attempts, but for each attempt's top word,
     which is shifted here.  Attempts ``>= modulus`` are dropped as
-    ``rand_int`` drops them, and each round reads exactly as many
-    attempts as scalars are still missing (never more), so the attempts
-    are the sequential loop's attempts in order and the stream is never
+    ``rand_int`` drops them, and each read asks for at most as many
+    attempts as scalars are still missing, so the attempts are the
+    sequential loop's attempts in order and the stream is never
     overshot.
 
-    Which generators take the bulk read is observed from their type:
+    Which generators take such reads is observed from their type:
     ``random.Random`` (its stream is defined, above) and
     ``random.SystemRandom`` (``os.urandom`` bits: any slicing of them is
     uniform, no stream to preserve).  Anything else (a subclass, a stub
-    with its own ``getrandbits``) takes the ``rand_int`` loop.  Books
-    ``coeff_draw_scalars_total{path}`` (scalars delivered) and, on the
-    bulk path, ``coeff_draw_rejected_total`` (attempts thrown away),
-    once a call.
+    with its own ``getrandbits``) takes the ``rand_int`` loop.  How the
+    words are read is observed from the draw's size: under
+    :data:`BLOCK_MIN_SCALARS` one ``getrandbits`` a round through a
+    Python int (``bulk``), from it on :func:`_block_reader`'s chunks of
+    :data:`_BLOCK_ROWS` attempts (``block``); the stream, and so every
+    value, is the same on both sides.  Books
+    ``coeff_draw_scalars_total{path}`` (scalars delivered) and, except on
+    the loop, ``coeff_draw_rejected_total`` (attempts thrown away), once
+    a call.
     """
     from ..utils import metrics  # utils imports dkg, which imports this module
 
+    shape = tuple(shape)
     need = math.prod(shape)
+    if out is None:
+        out = np.empty((*shape, fs.limbs), np.uint32)
+    elif out.shape != (*shape, fs.limbs) or out.dtype != np.uint32:
+        raise ValueError(f"draw_limbs: out is {out.dtype}{out.shape}, not uint32{(*shape, fs.limbs)}")
     if type(rng) not in (random.Random, random.SystemRandom):
-        out = encode(fs, [fs.rand_int(rng) for _ in range(need)])
+        out[...] = encode(fs, [fs.rand_int(rng) for _ in range(need)]).reshape(out.shape)
         metrics.REGISTRY.inc("coeff_draw_scalars_total", need, path="sequential")
-        return out.reshape(*shape, fs.limbs)
+        return out
 
+    if out.flags.c_contiguous:
+        lanes = out.reshape(1, need, fs.limbs)  # one row of all the scalars
+    elif len(shape) == 2:
+        lanes = out
+    else:
+        raise ValueError("draw_limbs: a strided out needs a two-axis shape")
     words = (fs.bits + 31) // 32
     shift = 32 * words - fs.bits
     mod_words = np.frombuffer(fs.modulus.to_bytes(4 * words, "little"), "<u4")
-    out = np.empty((need, fs.limbs), np.uint32)
+    if need >= BLOCK_MIN_SCALARS:
+        path, chunk = "block", _BLOCK_ROWS
+        read, done = _block_reader(rng, words)
+    else:
+        path, chunk, done = "bulk", need, None
+
+        def read(m):
+            buf = rng.getrandbits(32 * words * m).to_bytes(4 * words * m, "little")
+            return np.frombuffer(buf, "<u4").reshape(m, words)
+
     filled = rejected = 0
     while filled < need:
-        m = min(need - filled, _DRAW_ATTEMPTS_PER_READ)
-        buf = rng.getrandbits(32 * words * m).to_bytes(4 * words * m, "little")
-        rows = np.frombuffer(buf, "<u4").reshape(m, words)
+        m = min(need - filled, chunk)
+        rows = read(m)
         if shift:
-            rows = rows.copy()
+            if not rows.flags.writeable:  # a view of bytes
+                rows = rows.copy()
             rows[:, -1] >>= shift
         # lexicographic rows < modulus, decided by the top word alone
         # wherever it differs from the modulus's (all but 2**-32 of
@@ -166,11 +251,13 @@ def draw_limbs(fs: FieldSpec, rng, shape) -> np.ndarray:
         # little-endian uint32 words -> 16-bit limbs in uint32; a
         # FieldSpec's modulus fills its top limb, so 2 * words >= L and
         # the halves past L are zero in every row kept
-        out[filled : filled + kept] = rows.view("<u2")[:, : fs.limbs]
+        _store_rows(lanes, filled, rows.view("<u2")[:, : fs.limbs])
         filled += kept
-    metrics.REGISTRY.inc("coeff_draw_scalars_total", need, path="bulk")
+    if done is not None:
+        done()
+    metrics.REGISTRY.inc("coeff_draw_scalars_total", need, path=path)
     metrics.REGISTRY.inc("coeff_draw_rejected_total", rejected)
-    return out.reshape(*shape, fs.limbs)
+    return out
 
 
 def decode(fs: FieldSpec, limbs) -> np.ndarray:

@@ -160,16 +160,90 @@ class CeremonyOutcome:
     )
 
 
+#: host bytes of coefficient tensors a runtime keeps while no convoy
+#: has them: two pairs at the sharded shape (4096, 1366), 716 MB each —
+#: a two-deep worker's rotation — and every pair of the smaller buckets
+STAGING_KEEP_BYTES = 2 << 30
+
+
+@dataclasses.dataclass
+class StagedCoeffs:
+    """A pair of padded coefficient tensors ``(n_pad, t_pad+1, L)`` that
+    outlives the request that filled it, and ``real``, the ``(n, t+1)``
+    lanes that request wrote: everything outside them is zero."""
+
+    a: np.ndarray
+    b: np.ndarray
+    real: tuple = (0, 0)
+
+    def lanes(self, n: int, tc: int) -> tuple[np.ndarray, np.ndarray]:
+        """The ``[:n, :tc]`` views a draw writes, after zeroing what the
+        request before wrote outside them (another real ``(n, t)`` of the
+        bucket): the pad lanes are zero whoever had the tensors."""
+        pn, ptc = self.real
+        for x in (self.a, self.b):
+            x[n:pn, :ptc] = 0
+            x[:n, tc:ptc] = 0
+        self.real = (n, tc)
+        return self.a[:n, :tc], self.b[:n, :tc]
+
+
+class CoeffStaging:
+    """The coefficient tensors a runtime keeps from request to request,
+    so that a large draw writes mapped pages instead of 0.1-0.7 GB of
+    fresh ones (glibc hands allocations of that size back to the kernel
+    when they are freed; the first touch was the larger half of the
+    sharded request's ``draw``: PERF.md section 6, PR 45).
+
+    A pair is lent to one convoy (:meth:`lend`) and comes back
+    (:meth:`give_back`) once the outputs of the deal that read it are
+    ready — until then a backend may still read the host memory (a
+    transfer in flight; the CPU backend may alias it outright), so a
+    pair is never rewritten under a live deal and **two convoys in
+    flight never share one**.  A two-deep worker so turns two pairs a
+    bucket, as many as it has convoys, and waits for nothing it did not
+    wait for before.  Idle pairs are bounded by
+    :data:`STAGING_KEEP_BYTES` (what comes back beyond it is dropped); a
+    pair whose convoy failed before its finish is simply not returned.
+    Books ``coeff_staging_total{event="alloc"|"reuse"}``."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._idle: dict[tuple, list[StagedCoeffs]] = {}
+        self._idle_bytes = 0
+
+    def lend(self, shape: tuple) -> StagedCoeffs:
+        with self._lock:
+            idle = self._idle.get(shape)
+            pair = idle.pop() if idle else None
+            if pair is not None:
+                self._idle_bytes -= pair.a.nbytes + pair.b.nbytes
+        REGISTRY.inc("coeff_staging_total", event="alloc" if pair is None else "reuse")
+        if pair is None:
+            pair = StagedCoeffs(np.zeros(shape, np.uint32), np.zeros(shape, np.uint32))
+        return pair
+
+    def give_back(self, pair: StagedCoeffs) -> None:
+        nbytes = pair.a.nbytes + pair.b.nbytes
+        with self._lock:
+            if self._idle_bytes + nbytes <= STAGING_KEEP_BYTES:
+                self._idle.setdefault(pair.a.shape, []).append(pair)
+                self._idle_bytes += nbytes
+
+
 class WarmRuntime:
     """Shared warm state for all ceremonies in a process: fixed-base
     tables (via groups.precompute's process+disk cache) and per
-    ``(curve, shared_string)`` commitment keys.  Thread-safe; every
-    scheduler worker holds one reference."""
+    ``(curve, shared_string)`` commitment keys, and the coefficient
+    tensors kept for large draws (:class:`CoeffStaging`, released with
+    the runtime).  Thread-safe; every scheduler worker holds one
+    reference."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._ck: dict = {}
         self._mesh: dict = {}
+        self.staging = CoeffStaging()
 
     def commitment(self, curve: str, shared_string: bytes):
         """(CommitmentKey, g_table, h_table) for a ceremony environment,
@@ -397,16 +471,19 @@ def _finalise_stack(cfg, a_comm, shares, qualified):
 # ---------------------------------------------------------------------------
 
 
-def draw_coeffs(cfg: ce.CeremonyConfig, rng) -> tuple[np.ndarray, np.ndarray]:
+def draw_coeffs(
+    cfg: ce.CeremonyConfig, rng, out: tuple = (None, None)
+) -> tuple[np.ndarray, np.ndarray]:
     """The REAL coefficient tensors, drawn in exactly
     :class:`~dkg_tpu.dkg.ceremony.BatchedCeremony`'s order (both call
     :func:`~dkg_tpu.fields.host.draw_limbs`, ``a`` wholly before ``b``)
     so a seeded service ceremony and a fresh single-ceremony run of the
-    same seed deal byte-identical polynomials."""
+    same seed deal byte-identical polynomials.  Written into the pair
+    ``out`` where given (the real lanes of kept, padded tensors)."""
     fs = cfg.cs.scalar
     shape = (cfg.n, cfg.t + 1)
-    a = fh.draw_limbs(fs, rng, shape)
-    b = fh.draw_limbs(fs, rng, shape)
+    a = fh.draw_limbs(fs, rng, shape, out=out[0])
+    b = fh.draw_limbs(fs, rng, shape, out=out[1])
     return a, b
 
 
@@ -530,6 +607,10 @@ class InFlight:
     #: dispatched (``time.perf_counter``)
     mesh: object | None = None
     dispatched_at: float = 0.0
+    #: the kept coefficient tensors deal reads (a width-1 convoy whose
+    #: draw is large: :class:`CoeffStaging`), the runtime's again once
+    #: ``deal_wait`` is over
+    staged: StagedCoeffs | None = None
 
 
 def start_convoy(
@@ -557,14 +638,26 @@ def start_convoy(
             "ceremonies": list(ids),
         }
     )
+    staged = None
     with _stage(trace, "draw"):
-        ca, cb = [], []
-        for req in reqs:
-            cfg_real = ce.CeremonyConfig(req.curve, req.n, req.t)
-            a_real, b_real = draw_coeffs(cfg_real, rng_for(req))
-            ca.append(pad_coeffs(a_real, b.n, b.t))
-            cb.append(pad_coeffs(b_real, b.n, b.t))
-        ca_h, cb_h = (ca[0], cb[0]) if k == 1 else (np.stack(ca), np.stack(cb))
+        if k == 1 and req0.n * (req0.t + 1) >= fh.BLOCK_MIN_SCALARS:
+            # a large draw goes straight into the padded tensors, and
+            # those are kept ones: no copy, no fresh pages
+            staged = runtime.staging.lend((b.n, b.t + 1, cfg_pad.cs.scalar.limbs))
+            draw_coeffs(
+                ce.CeremonyConfig(req0.curve, req0.n, req0.t),
+                rng_for(req0),
+                out=staged.lanes(req0.n, req0.t + 1),
+            )
+            ca_h, cb_h = staged.a, staged.b
+        else:
+            ca, cb = [], []
+            for req in reqs:
+                cfg_real = ce.CeremonyConfig(req.curve, req.n, req.t)
+                a_real, b_real = draw_coeffs(cfg_real, rng_for(req))
+                ca.append(pad_coeffs(a_real, b.n, b.t))
+                cb.append(pad_coeffs(b_real, b.n, b.t))
+            ca_h, cb_h = (ca[0], cb[0]) if k == 1 else (np.stack(ca), np.stack(cb))
     n_dev = buckets.shard_devices(b) if k == 1 else 0
     if n_dev:
         # the sharded route: the same draw and pad, then the mesh's deal
@@ -580,7 +673,7 @@ def start_convoy(
         REGISTRY.inc("mesh_requests_total", devices=str(n_dev))
         return InFlight(
             list(reqs), list(ids), cfg_pad, g_mesh, h_mesh, a, e, s, r, trace,
-            mesh=mesh, dispatched_at=t_deal,
+            mesh=mesh, dispatched_at=t_deal, staged=staged,
         )
     with _stage(trace, "deal_dispatch"):
         args = (jnp.asarray(ca_h), jnp.asarray(cb_h), g_table, h_table)
@@ -600,7 +693,8 @@ def start_convoy(
                 lambda: _deal_stack(cfg_pad, *args),
             )
     return InFlight(
-        list(reqs), list(ids), cfg_pad, g_table, h_table, a, e, s, r, trace
+        list(reqs), list(ids), cfg_pad, g_table, h_table, a, e, s, r, trace,
+        staged=staged,
     )
 
 
@@ -614,9 +708,8 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
     :func:`derive_rho_convoy` then digests ``fl.a``, ``fl.e``, ``fl.s``,
     ``fl.r`` as the device arrays they are; verify and finalise read
     them on the device too."""
-    del runtime  # tables travel on the InFlight
     if fl.mesh is not None:
-        return _finish_sharded(fl)
+        return _finish_sharded(runtime, fl)
     cfg_pad = fl.cfg_pad
     trace = fl.trace
     k = len(fl.reqs)
@@ -624,6 +717,7 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
     rho_bits = fl.reqs[0].rho_bits
     with _stage(trace, "deal_wait"):
         jax.block_until_ready((fl.a, fl.e, fl.s, fl.r))
+    _release_staged(runtime, fl)
     rho = derive_rho_convoy(cfg_pad, fl.a, fl.e, fl.s, fl.r, rho_bits, trace)
     curve = fl.reqs[0].curve
     with _stage(trace, "verify_dispatch"):
@@ -735,7 +829,15 @@ def finish_convoy(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
     return out
 
 
-def _finish_sharded(fl: InFlight) -> list[CeremonyOutcome]:
+def _release_staged(runtime: WarmRuntime, fl: InFlight) -> None:
+    """Deal has run: nothing reads the convoy's kept coefficient tensors
+    any more, and the next draw may overwrite them."""
+    if fl.staged is not None:
+        runtime.staging.give_back(fl.staged)
+        fl.staged = None
+
+
+def _finish_sharded(runtime: WarmRuntime, fl: InFlight) -> list[CeremonyOutcome]:
     """:func:`finish_convoy` for a request on the sharded route: the
     phases of ``parallel.mesh.run_sharded_ceremony`` (its own functions,
     not copies) under the convoy's stage spans, and an outcome laid out
@@ -762,6 +864,7 @@ def _finish_sharded(fl: InFlight) -> list[CeremonyOutcome]:
         t_e = time.perf_counter()
         jax.block_until_ready((fl.s, fl.r))
         t_s = time.perf_counter()
+    _release_staged(runtime, fl)
     pm.book_phase("deal_commitments", t_e - fl.dispatched_at)
     pm.book_phase("deal_shares", t_s - t_e)
     with _stage(trace, "digest_dispatch"):

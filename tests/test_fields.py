@@ -214,6 +214,7 @@ def _draw_counts():
     c = metrics.REGISTRY.snapshot()["counters"]
     return {
         "bulk": c.get('coeff_draw_scalars_total{path="bulk"}', 0),
+        "block": c.get('coeff_draw_scalars_total{path="block"}', 0),
         "sequential": c.get('coeff_draw_scalars_total{path="sequential"}', 0),
         "rejected": c.get("coeff_draw_rejected_total", 0),
     }
@@ -221,7 +222,7 @@ def _draw_counts():
 
 def _delta(before):
     after = _draw_counts()
-    return {k: after[k] - before[k] for k in after}
+    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
 
 
 def _sequential(fs, rng, count):
@@ -235,6 +236,11 @@ def _sequential(fs, rng, count):
         else:
             rejected += 1
     return fh.encode(fs, vals).reshape(count, fs.limbs), rejected
+
+
+def _booked(path, count, rejected=0):
+    want = {path: count, "rejected": rejected}
+    return {k: v for k, v in want.items() if v}
 
 
 @pytest.mark.parametrize("seed", [0, 0xD1C6, 2**31 + 5])
@@ -258,19 +264,103 @@ def test_draw_limbs_under_rejection(fs, count):
     want, rejected = _sequential(fs, loop, count)
     np.testing.assert_array_equal(got, want)
     assert bulk.getrandbits(64) == loop.getrandbits(64)
-    assert _delta(before) == {"bulk": count, "sequential": 0, "rejected": rejected}
+    path = "block" if count >= fh.BLOCK_MIN_SCALARS else "bulk"
+    assert _delta(before) == _booked(path, count, rejected)
     assert count < 7 or rejected > 0
 
 
 def test_draw_limbs_reads_in_rounds(monkeypatch):
     """More scalars than one read asks for: the rounds' attempts are
     still the loop's attempts in order."""
-    monkeypatch.setattr(fh, "_DRAW_ATTEMPTS_PER_READ", 64)
+    monkeypatch.setattr(fh, "BLOCK_MIN_SCALARS", 0)
+    monkeypatch.setattr(fh, "_BLOCK_ROWS", 64)
     bulk, loop = random.Random(8), random.Random(8)
     got = fh.draw_limbs(JUST_OVER, bulk, (3, 100))
     want, _ = _sequential(JUST_OVER, loop, 300)
     np.testing.assert_array_equal(got.reshape(300, -1), want)
     assert bulk.getrandbits(64) == loop.getrandbits(64)
+
+
+#: the block path's threshold and chunk, set down for the cases below
+_T, _CHUNK = 12, 30
+
+
+@pytest.mark.parametrize(
+    "shape",
+    [(11, 1), (3, 4), (1, 13), (29, 1), (8, 12)],
+    ids=["T-1", "T", "T+1", "chunk-1", "chunks_and_rest"],
+)
+@pytest.mark.parametrize(
+    "fs",
+    [gd.ALL_CURVES[c].scalar for c in sorted(gd.ALL_CURVES)] + [JUST_OVER],
+    ids=lambda fs: fs.name,
+)
+def test_block_path_is_the_loop_and_the_bulk_path(monkeypatch, fs, shape):
+    """From ``BLOCK_MIN_SCALARS`` on the words come from numpy's Mersenne
+    generator in chunks, into a caller's array: the same scalars as the
+    ``rand_int`` loop and as the bulk read, the same generator state
+    after (from a position inside the 624-word block), the same refusals
+    booked; the array's other lanes untouched."""
+    monkeypatch.setattr(fh, "_BLOCK_ROWS", _CHUNK)
+    need = shape[0] * shape[1]
+    block, bulk, loop = (random.Random(need) for _ in range(3))
+    for rng in (block, bulk, loop):
+        for _ in range(7):
+            rng.getrandbits(32)
+    want, rejected = _sequential(fs, loop, need)
+
+    monkeypatch.setattr(fh, "BLOCK_MIN_SCALARS", 1 << 40)
+    before = _draw_counts()
+    got_bulk = fh.draw_limbs(fs, bulk, shape)
+    assert _delta(before) == _booked("bulk", need, rejected)
+
+    monkeypatch.setattr(fh, "BLOCK_MIN_SCALARS", _T)
+    padded = np.zeros((shape[0] + 2, shape[1] + 3, fs.limbs), np.uint32)
+    real = padded[: shape[0], : shape[1]]
+    before = _draw_counts()
+    assert fh.draw_limbs(fs, block, shape, out=real) is real
+    assert _delta(before) == _booked("block" if need >= _T else "bulk", need, rejected)
+
+    np.testing.assert_array_equal(real.reshape(need, -1), want)
+    np.testing.assert_array_equal(got_bulk.reshape(need, -1), want)
+    assert not padded[shape[0] :].any() and not padded[:, shape[1] :].any()
+    assert block.getrandbits(64) == bulk.getrandbits(64) == loop.getrandbits(64)
+
+
+def test_numpy_continues_the_mersenne_stream():
+    """The contract the block path holds numpy to: ``MT19937`` with
+    ``random.Random``'s state carried in yields its ``getrandbits(32)``
+    words through ``integers(0, 2**32, dtype=uint32)``, and its state
+    carried back continues the stream — from a fresh block, from inside
+    one, and across a block's end."""
+    for advance, count in ((0, 5), (7, 700), (623, 3), (624, 1300)):
+        ours, theirs = random.Random(0xD1C6), random.Random(0xD1C6)
+        for rng in (ours, theirs):
+            for _ in range(advance):
+                rng.getrandbits(32)
+        version, internal, gauss_next = ours.getstate()
+        assert version == 3 and len(internal) == 625
+        bit_gen = np.random.MT19937()
+        bit_gen.state = {
+            "bit_generator": "MT19937",
+            "state": {"key": np.array(internal[:-1], np.uint32), "pos": internal[-1]},
+        }
+        words = np.random.Generator(bit_gen).integers(0, 1 << 32, size=count, dtype=np.uint32)
+        assert words.tolist() == [theirs.getrandbits(32) for _ in range(count)]
+        state = bit_gen.state["state"]
+        ours.setstate((version, (*state["key"].tolist(), int(state["pos"])), gauss_next))
+        assert ours.getstate() == theirs.getstate()
+        assert ours.getrandbits(256) == theirs.getrandbits(256)
+
+
+def test_draw_limbs_refuses_an_out_of_another_shape():
+    fs = L25519
+    with pytest.raises(ValueError, match="out is"):
+        fh.draw_limbs(fs, random.Random(1), (4, 5), out=np.zeros((4, 6, fs.limbs), np.uint32))
+    with pytest.raises(ValueError, match="out is"):
+        fh.draw_limbs(fs, random.Random(1), (4, 5), out=np.zeros((4, 5, fs.limbs), np.int64))
+    with pytest.raises(ValueError, match="two-axis"):
+        fh.draw_limbs(fs, random.Random(1), (20,), out=np.zeros((40, fs.limbs), np.uint32)[::2])
 
 
 class _SubclassedRandom(random.Random):
@@ -295,11 +385,16 @@ def test_draw_limbs_other_generators_take_the_loop(make):
     loop = make(21)
     want = fh.encode(fs, [[fs.rand_int(loop) for _ in range(5)] for _ in range(4)])
     np.testing.assert_array_equal(got, want)
-    assert _delta(before) == {"bulk": 0, "sequential": 20, "rejected": 0}
+    assert _delta(before) == {"sequential": 20}
 
 
+@pytest.mark.parametrize("path", ["bulk", "block"])
 @pytest.mark.parametrize("fs", [L25519, JUST_OVER], ids=lambda fs: fs.name)
-def test_draw_limbs_system_random(fs):
+def test_draw_limbs_system_random(monkeypatch, fs, path):
+    """``os.urandom``'s bits on both sides of the threshold (the block
+    path reads them without a Python int): range, distinctness, counters."""
+    monkeypatch.setattr(fh, "BLOCK_MIN_SCALARS", 0 if path == "block" else 1 << 40)
+    monkeypatch.setattr(fh, "_BLOCK_ROWS", 128)
     before = _draw_counts()
     got = fh.draw_limbs(fs, random.SystemRandom(), (6, 50))
     assert got.dtype == np.uint32 and got.shape == (6, 50, fs.limbs)
@@ -308,4 +403,4 @@ def test_draw_limbs_system_random(fs):
     assert all(0 <= v < fs.modulus for v in vals)
     assert len(set(vals)) == 300
     d = _delta(before)
-    assert (d["bulk"], d["sequential"]) == (300, 0) and d["rejected"] > 0
+    assert d[path] == 300 and d["rejected"] > 0 and set(d) == {path, "rejected"}

@@ -33,7 +33,7 @@ from dkg_tpu.service.durable import ServiceJournal
 from dkg_tpu.service.engine import CeremonyOutcome, CeremonyRequest
 from dkg_tpu.service.faultsvc import ServiceFaultPlan
 from dkg_tpu.service.scheduler import CeremonyScheduler, QueueFullError
-from dkg_tpu.utils.metrics import MetricsRegistry
+from dkg_tpu.utils.metrics import REGISTRY, MetricsRegistry
 
 CURVE = "ristretto255"
 N, T = 5, 2  # buckets to (8, 2): the smallest ladder rung
@@ -793,7 +793,156 @@ def test_padded_master_matches_fresh_single_run(convoy1):
     assert out.master == engine.run_single_reference(req)
 
 
-def test_scheduler_end_to_end_masters_match_references(runtime):
+# ---------------------------------------------------------------------------
+# kept coefficient tensors (engine.CoeffStaging): a large draw's width-1
+# convoy writes into tensors the runtime keeps.  The threshold is set
+# down to this bucket's 15 scalars a draw; the shapes are convoy1's.
+# ---------------------------------------------------------------------------
+
+
+def _staging_counts():
+    c = REGISTRY.snapshot()["counters"]
+    return tuple(c.get(f'coeff_staging_total{{event="{e}"}}', 0) for e in ("alloc", "reuse"))
+
+
+def _serve_one(runtime, req):
+    (out,) = engine.finish_convoy(runtime, engine.start_convoy(runtime, [req]))
+    return out
+
+
+def _oracle_faults(req, out):
+    """``benchmark/bench_oracle.py``'s verdict (Python ints and the host
+    group only): the counts of what differs, every limit 0."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "benchmark"))
+    import bench_support
+
+    bench_support.bench_run()  # puts benchmark/ on sys.path
+    import bench_oracle
+
+    plain = {"curve": req.curve, "n": req.n, "t": req.t, "seed": req.seed}
+    return bench_oracle.check_outcome(plain, out, list(range(1, req.n + 1)))
+
+
+def test_kept_tensors_are_rewritten_between_requests_and_not_under_them(monkeypatch):
+    """Two seeds back to back through one runtime, then the first again:
+    each outcome is the plain reference's and a fresh runtime's bit for
+    bit (on this backend a device array may alias the host tensor it was
+    made from), from ONE pair of tensors, allocated once."""
+    monkeypatch.setattr(engine.fh, "BLOCK_MIN_SCALARS", N * (T + 1))
+    reqs = [CeremonyRequest(CURVE, N, T, seed=2**33 + s, rho_bits=32) for s in (1, 2, 1)]
+    before = _staging_counts()
+    rt = engine.WarmRuntime()
+    outs = [_serve_one(rt, req) for req in reqs]
+    assert tuple(a - b for a, b in zip(_staging_counts(), before)) == (1, 2)
+    monkeypatch.setattr(engine.fh, "BLOCK_MIN_SCALARS", 1 << 40)
+    before = _staging_counts()
+    for req, out in zip(reqs, outs):
+        assert not any(_oracle_faults(req, out).values())
+        fresh = _serve_one(engine.WarmRuntime(), req)
+        assert out.status == fresh.status == "done" and out.master == fresh.master
+        np.testing.assert_array_equal(out.final_shares, fresh.final_shares)
+    assert _staging_counts() == before  # under the threshold nothing is kept
+    assert outs[0].master == outs[2].master != outs[1].master
+
+
+def test_kept_tensors_pad_lanes_are_zero_after_a_fuller_request(monkeypatch):
+    """A (5,2) request through the tensors a full-bucket (8,2) request
+    just used: the lanes it does not write read zero, and its outcome is
+    a fresh unpadded ceremony's."""
+    monkeypatch.setattr(engine.fh, "BLOCK_MIN_SCALARS", N * (T + 1))
+    rt = engine.WarmRuntime()
+    full = CeremonyRequest(CURVE, 8, 2, seed=77, rho_bits=32)
+    assert _serve_one(rt, full).master == engine.run_single_reference(full)
+    req = CeremonyRequest(CURVE, N, T, seed=78, rho_bits=32)
+    fl = engine.start_convoy(rt, [req])
+    staged = fl.staged
+    assert staged.a.shape == staged.b.shape == (8, 3, 16) and staged.real == (N, T + 1)
+    for x in (staged.a, staged.b):
+        assert x[:N].any() and not x[N:].any()
+    (out,) = engine.finish_convoy(rt, fl)
+    assert fl.staged is None and out.master == engine.run_single_reference(req)
+
+
+def test_staged_lanes_zero_what_the_last_request_wrote_outside_them():
+    pair = engine.StagedCoeffs(np.zeros((8, 4, 2), np.uint32), np.zeros((8, 4, 2), np.uint32))
+    for real in ((8, 4), (5, 3), (7, 2), (3, 4)):
+        for view in pair.lanes(*real):
+            assert view.shape == (*real, 2)
+            view[...] = 9
+        for x in (pair.a, pair.b):
+            assert (x[: real[0], : real[1]] == 9).all()
+            assert not x[real[0] :].any() and not x[:, real[1] :].any()
+
+
+def test_two_convoys_in_flight_never_share_kept_tensors(monkeypatch):
+    """A two-deep worker starts its next convoy while deal of the last
+    may still read its tensors: each convoy in flight has a pair of its
+    own, and a pair comes back when its convoy's deal is over."""
+    monkeypatch.setattr(engine.fh, "BLOCK_MIN_SCALARS", N * (T + 1))
+    rt = engine.WarmRuntime()
+    reqs = [CeremonyRequest(CURVE, N, T, seed=900 + i, rho_bits=32) for i in range(3)]
+    before = _staging_counts()
+    fl0 = engine.start_convoy(rt, [reqs[0]])
+    fl1 = engine.start_convoy(rt, [reqs[1]])
+    assert fl0.staged is not fl1.staged
+    assert not np.shares_memory(fl0.staged.a, fl1.staged.a)
+    assert not np.shares_memory(fl0.staged.b, fl1.staged.b)
+    first = fl0.staged
+    (out0,) = engine.finish_convoy(rt, fl0)
+    fl2 = engine.start_convoy(rt, [reqs[2]])  # the worker's next: the pair that came back
+    assert fl2.staged is first and fl2.staged is not fl1.staged
+    (out1,) = engine.finish_convoy(rt, fl1)
+    (out2,) = engine.finish_convoy(rt, fl2)
+    assert tuple(a - b for a, b in zip(_staging_counts(), before)) == (2, 1)
+    for req, out in zip(reqs, (out0, out1, out2)):
+        assert out.master == engine.run_single_reference(req)
+
+
+def test_staging_under_contending_workers(monkeypatch):
+    """More threads than cores lending and giving back: a pair is never
+    in two hands, and what the runtime keeps idle stays under its bound."""
+    import sys
+
+    shape = (4, 3, 2)
+    pair_bytes = 2 * 4 * 3 * 2 * 4
+    monkeypatch.setattr(engine, "STAGING_KEEP_BYTES", 3 * pair_bytes)
+    staging = engine.CoeffStaging()
+    clashes, rounds = [], 300
+
+    def worker(me):
+        for _ in range(rounds):
+            pair = staging.lend(shape)
+            pair.a[...] = me
+            time.sleep(0)
+            if (pair.a != me).any():
+                clashes.append(me)
+            staging.give_back(pair)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(i + 1,)) for i in range(24)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads) and not clashes
+    idle = staging._idle[shape]
+    assert 1 <= len(idle) <= 3 and staging._idle_bytes == len(idle) * pair_bytes
+    assert len({id(p) for p in idle}) == len(idle)
+
+
+@pytest.mark.parametrize("draws", ["fresh", "kept"])
+def test_scheduler_end_to_end_masters_match_references(runtime, monkeypatch, draws):
+    """Two two-deep workers; ``kept``: every draw into tensors the
+    runtime keeps, rewritten while other convoys are live."""
+    if draws == "kept":
+        monkeypatch.setattr(engine.fh, "BLOCK_MIN_SCALARS", N * (T + 1))
     reqs = [CeremonyRequest(CURVE, N, T, seed=500 + i, rho_bits=32) for i in range(3)]
     with CeremonyScheduler(
         concurrency=2, queue_depth=8, batch_max=1, runtime=runtime

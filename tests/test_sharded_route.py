@@ -7,10 +7,13 @@ on the sharded route (the four `shard_map` programs compile once, through the ex
 store, with the digest's device leg forced so that `mesh_digest_rows` serves), then the same
 request on the one-device route, then the request with a tampered share and with three
 cheating dealers on both routes (the blame branch: `mesh_blame` and `mesh_finalise` compile
-here, small at this size), and every assertion hangs on what it kept.  Not marked slow:
-nothing else compiles a mesh program in tier 1.
+here, small at this size), and every assertion hangs on what it kept.  The block draw's
+threshold is set down to the request's 24 scalars, so every request of the module draws
+into tensors the runtime keeps (`engine.CoeffStaging`).  Not marked slow: nothing else
+compiles a mesh program in tier 1.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -22,6 +25,7 @@ import pytest
 import jax
 
 from dkg_tpu.dkg import ceremony as ce
+from dkg_tpu.fields import host as fh
 from dkg_tpu.parallel import mesh as pm
 from dkg_tpu.service import CeremonyRequest, CeremonyScheduler, WarmRuntime, aot, buckets, engine
 from dkg_tpu.utils.metrics import REGISTRY
@@ -37,6 +41,11 @@ pytestmark = pytest.mark.usefixtures("free_compiled_programs")
 
 def _counter(name: str) -> float:
     return sum(v for k, v in REGISTRY.snapshot()["counters"].items() if k.split("{")[0] == name)
+
+
+def _staging_counts():
+    c = REGISTRY.snapshot()["counters"]
+    return tuple(c.get(f'coeff_staging_total{{event="{e}"}}', 0) for e in ("alloc", "reuse"))
 
 
 def _serve(runtime, req):
@@ -76,6 +85,7 @@ def served(request, tmp_path_factory):
     mp.setattr(buckets, "_local_device_count", lambda: DEVICES)
     mp.setenv("DKG_TPU_AOT_DIR", str(store))
     mp.setenv("DKG_TPU_DIGEST", "device")
+    mp.setattr(fh, "BLOCK_MIN_SCALARS", N * (T + 1))
     aot.reset()
     kept = {"curve": curve, "store": str(store)}
     try:
@@ -87,6 +97,12 @@ def served(request, tmp_path_factory):
         kept["round1_host_bytes"] = _counter("round1_host_bytes_total") - fetched
         kept["mesh_requests"] = _counter("mesh_requests_total") - served_before
         kept["aot"] = aot.stats()
+        # another seed, then the first again, through the tensors the first request left
+        staging = _staging_counts()
+        other = dataclasses.replace(req, seed=SEED + 1)
+        kept["other"] = (other, _serve(runtime, other), _serve(WarmRuntime(), other))
+        kept["again"] = _serve(runtime, req)
+        kept["staging"] = tuple(a - b for a, b in zip(_staging_counts(), staging))
         kept["snapshot"] = REGISTRY.snapshot()
         # the digest and rho of the same transcript, the mesh's leg beside the host leg
         mesh, g_mesh, h_mesh = runtime.mesh_route(curve, req.shared_string, req.bucket(), DEVICES)
@@ -159,6 +175,25 @@ def test_the_sharded_outcome_equals_the_plain_reference(served):
     assert not any(bad.values()), bad
     sums = bench_oracle.column_sums(served["curve"], N, T, SEED)
     assert served["sharded"].master == bench_oracle.master_bytes(served["curve"], sums)
+
+
+def test_kept_tensors_serve_seed_after_seed_on_the_sharded_route(served):
+    """The runtime's one pair of coefficient tensors, rewritten request after request while
+    `place_coeffs`' shards of the request before may alias it on this backend: another
+    seed is the plain reference's and a fresh runtime's outcome, the first seed again is
+    what it was."""
+    bench_support.bench_run()  # puts benchmark/ on sys.path
+    import bench_oracle
+
+    other, out, fresh = served["other"]
+    plain = {"curve": other.curve, "n": N, "t": T, "seed": other.seed}
+    assert not any(bench_oracle.check_outcome(plain, out, list(range(1, N + 1))).values())
+    for got, want in ((out, fresh), (served["again"], served["sharded"])):
+        assert got.status == want.status == "done" and got.master == want.master
+        np.testing.assert_array_equal(got.final_shares, want.final_shares)
+    assert out.master != served["sharded"].master
+    # the module's first request allocated the pair; these two and the fresh runtime's one
+    assert served["staging"] == (1, 2)
 
 
 def test_the_meshs_digest_and_rho_are_the_host_legs(served):
