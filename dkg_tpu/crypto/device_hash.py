@@ -37,11 +37,27 @@ multi-host fold reference.
 
 Dispatch: the public entry points (:func:`tree_digest`,
 :func:`row_digests`) are BACKEND-DISPATCHED.  The device leg runs the
-whole tree as ONE jitted program per (shape, domain-arity) — rounds
-roll up in a ``lax.fori_loop`` and the four column/diagonal G-calls of
-each half-round vectorize over a 4-wide lane axis, so the traced graph
-stays small and the per-op XLA dispatch that made the eager tree the
-ceremony's slowest phase (BENCH_r06: 5.5 s at n=64 on CPU) disappears.
+whole tree as ONE jitted program per (shape, domain-arity), so the
+per-op XLA dispatch that made the eager tree the ceremony's slowest
+phase (BENCH_r06: 5.5 s at n=64 on CPU) is gone, and it runs it
+WORD-MAJOR: the sixteen words of a message block are an array's LEADING
+axis and the batch its others, ``(16, nodes, rows)`` with a level's
+nodes major of the rows.  A level is ONE Pallas kernel
+(``ops.pallas_blake2s.blake2s_level``; interpret mode off the TPU): a
+step takes a block of nodes x rows, reads its sixteen message slabs once
+and keeps the sixteen state slabs on the chip through the ten rounds
+(a ``lax.fori_loop``, so a level traces one round's G-bodies).  Every add,
+xor and rotate is on whole vectors with the rows on the lanes; the
+message schedule picks slabs by ``SIGMA[round]`` on the leading axis
+(which slab, never which lane); the diagonal step renames four state
+words; and a level's pairs (left || right) are the even and the odd
+nodes of a major axis, so no lane is de-interleaved.  The words of a row
+arrive row-major, so there is one transposition on the way in; the
+``(rows, 8)`` digests come out as they always did.  (Until PR 46 the
+words were the LAST axis, the schedule a ``take`` under a traced index
+and the diagonals ``roll`` s of a 4-wide axis; what that cost on the
+chip, and what the same layout costs as plain ``jnp`` ops under a
+``fori_loop``, is in PERF.md section 6, PR 46.)
 The host leg (``crypto.blake2s``) is the same tree in batched numpy —
 on CPU backends XLA per-op overhead dominates the tiny uint32 ops
 exactly as it did for point encoding (``groups.device.encode_batch``),
@@ -88,85 +104,6 @@ SIGMA = (
 )
 
 MASK32 = 0xFFFFFFFF
-
-
-# ---------------------------------------------------------------------------
-# device (jnp) compression, batched over leading axes
-# ---------------------------------------------------------------------------
-
-
-def _ror(x, n):
-    return (x >> n) | (x << (32 - n))
-
-
-def _compress_dev(h, m, t, f0):
-    """Batched BLAKE2s compression: h (..., 8), m (..., 16), t (...,) or
-    scalar, f0 scalar -> (..., 8).  All uint32.
-
-    Trace-size discipline (this runs INSIDE the jitted tree): the ten
-    rounds roll up in a ``lax.fori_loop`` with the message schedule as a
-    gathered (10, 16) constant, and each half-round's four independent
-    G-calls run as ONE G over a 4-wide lane axis — the standard
-    column/diagonal formulation (diagonals are lane-rolls of the state
-    quarters).  The traced graph is ~2 G-bodies instead of 80, so a
-    whole Merkle level compiles in milliseconds while the compiled code
-    is identical arithmetic to the unrolled form."""
-    t = jnp.asarray(t, jnp.uint32)
-    batch = jnp.broadcast_shapes(h.shape[:-1], m.shape[:-1], t.shape)
-    h = jnp.broadcast_to(h, batch + (8,))
-    m = jnp.broadcast_to(m, batch + (16,))
-    iv = jnp.asarray(np.asarray(IV, np.uint32))
-    v = jnp.concatenate([h, jnp.broadcast_to(iv, h.shape)], axis=-1)
-    v = v.at[..., 12].set(v[..., 12] ^ jnp.broadcast_to(t, batch))
-    v = v.at[..., 14].set(v[..., 14] ^ jnp.uint32(f0))
-    sigma = jnp.asarray(np.asarray(SIGMA, np.int32))
-
-    def g(a, b, c, d, x, y):
-        a = a + b + x  # uint32 wraps mod 2^32 natively
-        d = _ror(d ^ a, 16)
-        c = c + d
-        b = _ror(b ^ c, 12)
-        a = a + b + y
-        d = _ror(d ^ a, 8)
-        c = c + d
-        b = _ror(b ^ c, 7)
-        return a, b, c, d
-
-    def round_body(rnd, v):
-        ms = jnp.take(m, sigma[rnd], axis=-1)
-        a, b, c, d = (v[..., 0:4], v[..., 4:8], v[..., 8:12], v[..., 12:16])
-        # columns: G(v0,v4,v8,v12) .. G(v3,v7,v11,v15)
-        a, b, c, d = g(a, b, c, d, ms[..., 0:8:2], ms[..., 1:8:2])
-        # diagonals: G(v0,v5,v10,v15) .. G(v3,v4,v9,v14) == lane rolls
-        b = jnp.roll(b, -1, axis=-1)
-        c = jnp.roll(c, -2, axis=-1)
-        d = jnp.roll(d, -3, axis=-1)
-        a, b, c, d = g(a, b, c, d, ms[..., 8:16:2], ms[..., 9:16:2])
-        b = jnp.roll(b, 1, axis=-1)
-        c = jnp.roll(c, 2, axis=-1)
-        d = jnp.roll(d, 3, axis=-1)
-        return jnp.concatenate([a, b, c, d], axis=-1)
-
-    v = lax.fori_loop(0, 10, round_body, v)
-    return h ^ v[..., 0:8] ^ v[..., 8:16]
-
-
-def _h_init(p3: int, batch: tuple) -> jax.Array:
-    h = np.asarray(IV, np.uint32).copy()
-    h[0] ^= np.uint32(P_WORD0)
-    h[3] ^= np.uint32(p3)
-    return jnp.broadcast_to(jnp.asarray(h), batch + (8,))
-
-
-def _pad_blocks(words: jax.Array) -> jax.Array:
-    """(..., W) words -> (..., NL, 16) blocks, NL a power of two."""
-    w = words.shape[-1]
-    nl = max(1, -(-w // 16))
-    nl_pow2 = 1 << (nl - 1).bit_length()
-    pad = nl_pow2 * 16 - w
-    if pad:
-        words = jnp.pad(words, [(0, 0)] * (words.ndim - 1) + [(0, pad)])
-    return words.reshape(words.shape[:-1] + (nl_pow2, 16))
 
 
 def digest_dispatch() -> str:
@@ -251,41 +188,41 @@ def _tree_from_words(parts: tuple, domain: int, lead: int = 1) -> jax.Array:
     made for it first: the rows_a/rows_e calls of
     ``_dealer_rows_device`` — same shape, different domain — reuse one
     executable).  ``parts`` is a tuple as :func:`row_digests` takes it;
-    what is not a device array yet goes in as ``uint32`` numpy."""
+    what is not a device array yet goes in as ``uint32`` numpy.  The
+    kernels run in interpret mode wherever the backend is not a TPU: a
+    static argument, so a program traced for one never serves the other."""
+    from ..fields import device as fd
+
     parts = tuple(
         p if isinstance(p, jax.Array) else np.asarray(p, np.uint32) for p in parts
     )
-    return _tree_from_words_jit(parts, np.uint32(int(domain) & MASK32), lead)
+    return _tree_from_words_jit(parts, np.uint32(int(domain) & MASK32), lead, not fd._on_tpu())
 
 
-@functools.partial(jax.jit, static_argnums=2)
-def _tree_from_words_jit(parts: tuple, domain: jax.Array, lead: int) -> jax.Array:
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _tree_from_words_jit(parts: tuple, domain: jax.Array, lead: int, interpret: bool) -> jax.Array:
+    from ..ops.pallas_blake2s import blake2s_level
+
     r = math.prod(parts[0].shape[:lead])
     flat = [p.astype(jnp.uint32).reshape(r, -1) for p in parts]
     words = flat[0] if len(flat) == 1 else jnp.concatenate(flat, axis=-1)
     w = words.shape[-1]
-    blocks = _pad_blocks(words)  # (R, NL, 16)
-    nl = blocks.shape[-2]
-    t_leaf = jnp.arange(nl, dtype=jnp.uint32) * 64
-    h = _compress_dev(_h_init(P3_LEAF, (r, nl)), blocks, t_leaf[None, :], MASK32)
+    nl = max(1, -(-w // 16))
+    nl = 1 << (nl - 1).bit_length()  # leaves: a power of two of 16-word blocks
+    if nl * 16 != w:
+        words = jnp.pad(words, ((0, 0), (0, nl * 16 - w)))
+    # the one transposition: word w of leaf i of row j at [w, i, j]
+    blocks = jnp.transpose(words.reshape(r, nl, 16), (2, 1, 0))
+    h = blake2s_level(blocks, P3_LEAF, True, 0, interpret=interpret)  # (8, nl, r)
     level = 1
-    while h.shape[-2] > 1:  # trace-time loop: log2(NL) compressions
-        pairs = h.reshape(r, h.shape[-2] // 2, 16)
-        h = _compress_dev(
-            _h_init(P3_NODE, pairs.shape[:-1]), pairs, jnp.uint32(level), MASK32
-        )
+    while h.shape[1] > 1:  # trace-time loop: log2(nl) levels
+        # children 2i, 2i+1 are neighbours on the major axis
+        pairs = jnp.concatenate([lax.slice_in_dim(h, k, None, stride=2, axis=1) for k in (0, 1)])
+        h = blake2s_level(pairs, P3_NODE, False, level, interpret=interpret)
         level += 1
-    tail = (
-        jnp.zeros((8,), jnp.uint32)
-        .at[0]
-        .set(jnp.uint32(w & MASK32))
-        .at[1]
-        .set(domain)
-    )
-    root_block = jnp.concatenate(
-        [h[:, 0, :], jnp.broadcast_to(tail, (r, 8))], axis=-1
-    )
-    return _compress_dev(_h_init(P3_NODE, (r,)), root_block, jnp.uint32(0), MASK32)
+    tail = [jnp.full((1, 1, r), x, jnp.uint32) for x in (w & MASK32, domain, 0, 0, 0, 0, 0, 0)]
+    root = blake2s_level(jnp.concatenate([h] + tail), P3_NODE, False, 0, interpret=interpret)  # (8, 1, r)
+    return jnp.transpose(root[:, 0, :])
 
 
 # ---------------------------------------------------------------------------

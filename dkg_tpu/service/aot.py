@@ -79,6 +79,7 @@ _TRACED_SOURCES = (
     "service/engine.py",
     "parallel/mesh.py",
     "utils/scanchunk.py",
+    "crypto/device_hash.py",
     "fields",
     "groups",
     "ops",

@@ -31,7 +31,7 @@ linter), so the committed baseline stays clean between CI runs:
         ``crypto.chacha.chacha20_xor_batch`` so n^2 pairs cost one
         vectorized pass, not n^2 host calls (docs/perf.md)
 * DKG004  (dkg_tpu/dkg/ only) eager transcript-digest entry point
-        (``_compress_dev`` / ``_tree_from_words``) called from protocol
+        (``blake2s_level`` / ``_tree_from_words``) called from protocol
         code — digests must go through ``device_hash.row_digests`` /
         ``tree_digest`` so every call is jitted and backend-dispatched
         (DKG_TPU_DIGEST); and, in the batch hot modules, a
@@ -216,7 +216,7 @@ _DEM_SCALAR_LEGS = {"seal_shares", "open_share"}
 # directly (DKG004): the public ``row_digests``/``tree_digest``
 # dispatchers are jitted and backend-dispatched (DKG_TPU_DIGEST); these
 # internals are neither.
-_DIGEST_EAGER_ENTRYPOINTS = {"_compress_dev", "_tree_from_words"}
+_DIGEST_EAGER_ENTRYPOINTS = {"blake2s_level", "_tree_from_words"}
 
 # Functions inside hot modules allowed to run hashlib.blake2b in a
 # loop (DKG004): the byte-level audit digest's per-dealer row hash —

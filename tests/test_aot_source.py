@@ -51,7 +51,54 @@ def test_fingerprint_is_of_the_traced_sources_and_in_every_header():
     for rel in aot._TRACED_SOURCES:
         assert os.path.exists(os.path.join(root, rel)), rel
     # what the four round programs and their kernels live in is covered
-    assert {"dkg/ceremony.py", "ops", "groups", "fields"} <= set(aot._TRACED_SOURCES)
+    assert {"dkg/ceremony.py", "ops", "groups", "fields", "crypto/device_hash.py"} <= set(aot._TRACED_SOURCES)
+
+
+def test_every_module_the_mesh_digest_traces_is_fingerprinted():
+    """``mesh_digest_rows`` is a stored program whose body is
+    ``dealer_rows_traced``: every module of the package that runs while
+    that is traced shapes the program, so its text has to be under
+    ``_TRACED_SOURCES`` or a store that outlives a checkout serves the
+    other checkout's digest (PR 46: ``crypto/device_hash.py`` was not).
+    Nothing is compiled: the body is traced to a jaxpr with the
+    interpreter's profile hook on."""
+    import sys
+
+    from dkg_tpu.dkg import ceremony as ce
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(aot.__file__)))
+    seen = set()
+
+    def hook(frame, event, arg):
+        if event == "call":
+            path = frame.f_code.co_filename
+            if path.startswith(root + os.sep):
+                seen.add(os.path.relpath(path, root).replace(os.sep, "/"))
+
+    # shapes no other test traces: a tree or a canon already in this process's
+    # jit caches would run no module at all (the first assert below says so)
+    cfg = ce.CeremonyConfig("secp256k1", 7, 2)
+    point = jax.ShapeDtypeStruct((7, 3, 3, 16), jnp.uint32)
+    rows = jax.ShapeDtypeStruct((7, 7, 16), jnp.uint32)
+    sys.setprofile(hook)
+    try:
+        jax.make_jaxpr(lambda *x: ce.dealer_rows_traced(cfg, *x))(point, point, rows, rows)
+    finally:
+        sys.setprofile(None)
+    assert {"dkg/ceremony.py", "crypto/device_hash.py", "groups/device.py", "fields/device.py"} <= seen, seen
+    # what books, logs or reads a switch shapes no program: the switches a
+    # tracer reads are the store's key by another road
+    # (envknobs.program_shape), and JAX's monitoring events reach the
+    # listeners an earlier test of the process installed (runtimeobs, obslog)
+    shapes_nothing = {
+        f"utils/{name}.py" for name in ("metrics", "envknobs", "runtimeobs", "obslog", "tracing")
+    }
+    covered = tuple(aot._TRACED_SOURCES)
+    loose = {
+        m for m in seen - shapes_nothing
+        if not any(m == src or m.startswith(src + "/") for src in covered)
+    }
+    assert not loose, loose
 
 
 def test_an_executable_baked_from_other_source_is_rebuilt_never_served(store, monkeypatch):

@@ -383,7 +383,7 @@ def test_device_leg_hands_the_programs_deals_own_arrays(monkeypatch):
     canon_jit, tree_jit = gd._affine_canon_jit, dh._tree_from_words_jit
     monkeypatch.setattr(gd, "_affine_canon_jit", lambda cs, path, pts: canon.append(pts) or canon_jit(cs, path, pts))
     monkeypatch.setattr(
-        dh, "_tree_from_words_jit", lambda parts, domain, lead: tree.append((parts, domain, lead)) or tree_jit(parts, domain, lead)
+        dh, "_tree_from_words_jit", lambda parts, domain, lead, interp: tree.append((parts, domain, lead)) or tree_jit(parts, domain, lead, interp)
     )
     rows = ce._dealer_rows_device(cfg, *dev, dispatch="device")
     assert len(canon) == 2 and canon[0] is a and canon[1] is e
@@ -398,5 +398,5 @@ def test_device_leg_hands_the_programs_deals_own_arrays(monkeypatch):
 
 def test_the_tree_keeps_its_module_name_for_the_trace():
     spec = jax.ShapeDtypeStruct((2, 4, 4, 16), jnp.uint32)
-    text = dh._tree_from_words_jit.lower((spec, spec), np.uint32(3), 2).as_text()
+    text = dh._tree_from_words_jit.lower((spec, spec), np.uint32(3), 2, True).as_text()
     assert "module @jit__tree_from_words_jit " in text

@@ -126,9 +126,10 @@ def test_every_knob_named_in_traced_sources_is_listed():
                     seen.add(node.value)
     assert seen - set(envknobs.PROGRAM_SHAPING) - NOT_SHAPING == set()
     # and the other way: a listed knob that nothing traced reads is a
-    # key that retraces for nothing (DIGEST is read in crypto/, which
-    # the sharded engine's digest leg traces)
-    assert set(envknobs.PROGRAM_SHAPING) - seen == {"DKG_TPU_DIGEST"}
+    # key that retraces for nothing (DIGEST is read in
+    # crypto/device_hash.py, which the sharded engine's digest leg
+    # traces and the store fingerprints since PR 46)
+    assert set(envknobs.PROGRAM_SHAPING) - seen == set()
     assert NOT_SHAPING <= seen
 
 

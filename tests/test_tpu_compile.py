@@ -164,17 +164,50 @@ def test_the_hash_tree_takes_the_round1_tensors_as_they_are_for_v5e(one_chip, le
     """The three tree programs of a (16,5) convoy's digest leg, handed
     deal's outputs in their own shapes (a commitment tensor; the share and
     hiding matrices as a pair): the flattening to rows, the cast and the
-    joining compile inside the one module the device trace knows."""
+    joining compile inside the one module the device trace knows, a level
+    one Mosaic kernel (``interpret=False``: the chip's compiler)."""
     from dkg_tpu.crypto import device_hash as dh
 
     def spec(*tail):
         return jax.ShapeDtypeStruct(lead + tail, jnp.uint32, sharding=one_chip)
 
-    for parts in ((spec(6, 3, 16),), (spec(16, 16), spec(16, 16))):
-        compiled = dh._tree_from_words_jit.lower(parts, np.uint32(3), len(lead)).compile()
-        assert "jit__tree_from_words_jit" in compiled.as_text()
+    for parts, leaves in (((spec(6, 3, 16),), 32), ((spec(16, 16), spec(16, 16)), 32)):
+        compiled = dh._tree_from_words_jit.lower(parts, np.uint32(3), len(lead), False).compile()
+        text = compiled.as_text()
+        assert "jit__tree_from_words_jit" in text
+        assert _tree_kernels(text) == leaves.bit_length() + 1  # the leaves, log2(leaves) levels, the root
         (out,) = jax.tree_util.tree_leaves(compiled.out_info)
         assert out.shape == (int(np.prod(lead)), 8)
+
+
+def _tree_kernels(text: str) -> int:
+    return len(re.findall(r"custom-call\(.*custom_call_target=\"tpu_custom_call\"", text))
+
+
+@pytest.mark.parametrize(
+    "shapes, leaves",
+    [
+        (((1024, 342, 2, 16),), 1024),  # secp256k1 n=1024: a dealer's commitments, 10,944 words
+        (((1024, 1024, 16), (1024, 1024, 16)), 2048),  # its share and hiding rows, 32,768 words
+        (((1024, 342, 2, 24),), 2048),  # BLS12-381: 16,416 words
+        (((256, 4096, 16), (256, 4096, 16)), 8192),  # the mesh's digest chunk of a (4096,1365) request
+        (((64, 64, 16), (64, 64, 16)), 128),  # rows 64: a width-1 (64,16) convoy
+        (((320, 64, 16), (320, 64, 16)), 128),  # rows 320: a block and a cut one
+    ],
+    ids=["a_n1024", "sr_n1024", "a_bls_n1024", "sr_mesh_chunk", "sr_rows64", "sr_rows320"],
+)
+def test_the_hash_tree_compiles_at_the_cells_shapes_for_v5e(one_chip, shapes, leaves):
+    """What Mosaic refuses no interpret-mode test sees, and rho is on no
+    outcome: every level of the tree at the shapes the benchmark's cells
+    send is a kernel the chip's compiler takes, and the program keeps no
+    copy of the padded words beside the word-major one."""
+    from dkg_tpu.crypto import device_hash as dh
+
+    parts = tuple(jax.ShapeDtypeStruct(s, jnp.uint32, sharding=one_chip) for s in shapes)
+    compiled = dh._tree_from_words_jit.lower(parts, np.uint32(3), 1, False).compile()
+    assert _tree_kernels(compiled.as_text()) == leaves.bit_length() + 1
+    words = shapes[0][0] * leaves * 16 * 4  # the padded leaves of every row, in bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * words
 
 
 def _layout_changes(text: str, min_elems: int) -> list[tuple[str, str]]:
